@@ -26,9 +26,7 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-
-
-from repro.compat import axis_size as _axis_size  # noqa: E402
+from jax.lax import axis_size as _axis_size
 
 
 def _log2(n: int) -> int:

@@ -65,8 +65,8 @@ Engines are selected by name through ``make_engine`` — the same names
 the ``--engine`` flag of ``benchmarks/run.py`` accepts:
 
     ``packet``   the packet-level reference;
-    ``flow``     fluid model, JAX solver when available (else numpy);
-    ``flow-np``  fluid model, numpy solver (forced).
+    ``flow``     fluid model, JAX device solver (raises without it);
+    ``flow-np``  fluid model, numpy reference solver.
 
 Fidelity note: the flow engines model serialization of the wire volume
 (payload + per-MTU header overhead) at the max-min fair tree rate, plus
@@ -812,8 +812,8 @@ class FlowEngine(_WorkloadStaging):
     transport stages one concurrent chunk-flow per relay edge and a
     *finalizer* applies the schedule's pipelined-round structure on the
     solved steady-state hop time (see ``_stage_overlay``).  ``run()``
-    hands the staged batch to the solver (JAX when
-    ``backend='jax'``/'auto' and available, numpy otherwise), then
+    hands the staged batch to the solver (JAX for
+    ``backend='jax'``/'auto', the numpy reference for 'np'), then
     back-fills the records: delivery time = flow completion + each
     receiver's path latency (propagation + per-hop store-and-forward of
     one segment); sender CQE = slowest delivery + the aggregated-ACK
@@ -866,18 +866,13 @@ class FlowEngine(_WorkloadStaging):
         self.relay_kw = dict(relay_kw or {})
         if backend not in ("auto", "jax", "np", "numpy"):
             raise ValueError(f"unknown flow backend {backend!r}")
-        use_jax = False
+        # the device solver, or the numpy reference when asked for by
+        # name — never the one in place of the other
         if backend in ("auto", "jax"):
-            try:
-                from repro.core.flowsim_jax import HAS_JAX, JaxFlowSim
-                use_jax = HAS_JAX
-            except ImportError:
-                use_jax = False
-            if backend == "jax" and not use_jax:
-                raise RuntimeError("flow backend 'jax' requested but JAX "
-                                   "is not importable")
-        self._sim_cls = JaxFlowSim if use_jax else FlowSim
-        self.name = "flow" if use_jax else "flow-np"
+            from repro.core.flowsim_jax import JaxFlowSim
+            self._sim_cls, self.name = JaxFlowSim, "flow"
+        else:
+            self._sim_cls, self.name = FlowSim, "flow-np"
         # ``staging_cache=False`` detaches this engine from the
         # topology's shared staging cache (private memos, no op-level
         # reuse, no batch pre-warm) — the scalar reference mode the

@@ -426,7 +426,7 @@ def static_maxmin(cap: np.ndarray, link_sets: Sequence[Sequence[int]]):
 def segment_loss_factor(cap: np.ndarray, link_sets, rates, lp) -> float:
     """Expected-value loss/DCQCN rate factor for the LAST flow of a
     solved segment problem — the scalar numpy twin of
-    ``kernels/ref.py:loss_factors_reference`` (same math as
+    ``kernels/maxmin.py:loss_factors`` (same math as
     ``FlowSim._apply_loss``, evaluated for one flow against the whole
     segment's solved rates).  Used by the batched dynamic-segment
     solver so churn-under-loss fairness snapshots are loss-native."""
@@ -472,7 +472,7 @@ class FlowSim(LinkMap):
     def _apply_loss(self, active: List[Flow]):
         """Scale solved rates by the expected-value loss/DCQCN factors.
 
-        The numpy twin of ``kernels/ref.py:loss_factors_reference``:
+        The numpy twin of ``kernels/maxmin.py:loss_factors``:
         identical math, applied to ``Flow.rate`` in place.
         """
         util = np.zeros(len(self.cap))

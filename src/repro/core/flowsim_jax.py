@@ -5,10 +5,10 @@ link-flow incidence; a Gleam multicast tree is ONE flow across the union
 of its tree links), but the whole simulation is dense-array loops:
 
 - **inner loop**: progressive-filling max-min fair allocation, one
-  *fused round* per iteration (``kernels/maxmin.py`` — a Pallas kernel
-  on TPU, its pure-jnp reference on CPU).  Each round scatter-adds the
-  unfrozen flows onto their links, computes every link's fair share,
-  gathers each flow's tightest share, and freezes the bottleneck group.
+  round per iteration (``kernels/maxmin.py``, jnp on every platform).
+  Each round scatter-adds the unfrozen flows onto their links, computes
+  every link's fair share, gathers each flow's tightest share, and
+  freezes the bottleneck group.
   Terminates in at most F rounds (whole bottleneck groups freeze
   together, so in practice a handful).
 - **outer loop** (``_simulate``): classic fluid event loop — advance
@@ -37,14 +37,13 @@ padded to a multicast tree's hop count.
 **Precision**: volumes and capacities solve in float32 until the
 largest staged volume exceeds the float32 safe-integer range (2^24
 bytes ~ 16MB); beyond that (the multi-GB fig12/13 replication regime)
-the solve auto-promotes to float64 under ``jax.experimental.enable_x64``
+the solve auto-promotes to float64 under a scoped ``jax.enable_x64``
 so completion times keep full precision.  ``solve_dtype`` records the
 choice.
 
-The module degrades gracefully: ``HAS_JAX`` is False when JAX is not
-importable and ``core.engine`` silently falls back to the numpy solver.
-Flows, link ids, and routing come from ``flowsim.LinkMap`` so the two
-flow backends are numerically interchangeable (tested to 0.1%).
+Flows, link ids, and routing come from ``flowsim.LinkMap`` so this
+solver and its numpy reference ``flowsim.FlowSim`` are numerically
+interchangeable (tested to 0.1%).
 """
 from __future__ import annotations
 
@@ -55,19 +54,14 @@ import threading
 import time
 from typing import List, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.fattree import Topology
-from repro.core.flowsim import Flow, LinkMap
-
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAS_JAX = True
-except Exception:                               # pragma: no cover - gated
-    HAS_JAX = False
+from repro.core.flowsim import DCQCN_MIN_RATE, DCQCN_RATE_NUM, Flow, LinkMap
+from repro.kernels.maxmin import loss_factors, maxmin_rates
 
 #: volumes above this lose integer precision in float32 (2^24 bytes)
 F32_SAFE_MAX = float(1 << 24)
@@ -97,30 +91,33 @@ def reset_solve_stats():
     SOLVE_STATS.update(solve_s=0.0, calls=0, shapes=[])
 
 
+#: the persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: a fixed ``.jax_cache/`` at the checkout's root (the path is
+#: part of the cache key, so a directory that moved would never hit)
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
 _CACHE_READY = False
 
 
-def _enable_persistent_cache():
-    """Point XLA's persistent compilation cache at a local directory
-    (once per process) so repeat sweeps skip compilation entirely.
+def compile_cache_dir() -> str:
+    """The directory the persistent compilation cache lives in."""
+    return jax.config.jax_compilation_cache_dir or CACHE_DIR
 
-    Honors an existing ``JAX_COMPILATION_CACHE_DIR``/config setting;
-    ``REPRO_JAX_CACHE=0`` opts out.  Best-effort: any failure (read-only
-    home, old jax) silently falls back to in-memory-only caching.
-    """
+
+def _enable_persistent_cache():
+    """Turn XLA's persistent compilation cache on (once per process) so
+    repeat sweeps skip compilation.  ``JAX_COMPILATION_CACHE_DIR``, when
+    set, places it and nothing here overrides it; otherwise it goes to
+    ``CACHE_DIR``."""
     global _CACHE_READY
-    if _CACHE_READY or os.environ.get("REPRO_JAX_CACHE", "1") == "0":
+    if _CACHE_READY:
         return
     _CACHE_READY = True
-    try:                                        # pragma: no cover - env
-        if jax.config.jax_compilation_cache_dir is None:
-            jax.config.update(
-                "jax_compilation_cache_dir",
-                os.path.expanduser("~/.cache/repro-jax"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.1)
-    except Exception:
-        pass
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 
 
 def _bucket(n: int, lo: int) -> int:
@@ -128,138 +125,121 @@ def _bucket(n: int, lo: int) -> int:
     return max(lo, 1 << max(int(n) - 1, 0).bit_length())
 
 
-if HAS_JAX:
+def _simulate(flow_links, cap, vol, loss=None, warm=True):
+    """Fluid event loop: completion times (F,) for every flow.
 
-    def _maxmin_rates(flow_links, cap, active, mode):
-        """Max-min fair rates for the active flows (progressive filling
-        over the fused round of ``kernels/maxmin.py``)."""
-        from repro.kernels.maxmin import maxmin_rates
-        return maxmin_rates(flow_links, cap, active, mode=mode)
+    ``warm`` compiles in the completion-epoch warm start: when an
+    epoch's completed flows are link-disjoint from every survivor,
+    the previous rate vector is reused and the filling skipped.
+    The batched (vmapped) solver sets ``warm=False``: under vmap
+    ``lax.cond`` lowers to a select that executes both branches, so
+    the skip can never fire and the dirty tracking would be pure
+    per-epoch overhead.
 
-    def _simulate(flow_links, cap, vol, loss=None, mode="auto", warm=True):
-        """Fluid event loop: completion times (F,) for every flow.
+    ``loss`` (a ``(q, wsq, wnd, ecn)`` tuple of (F,) arrays, or
+    None) compiles in the expected-value loss/DCQCN correction: the
+    solved max-min rates are scaled by ``kernels/maxmin.py``'s
+    ``loss_factors`` each epoch.  The loop state carries the
+    RAW max-min rates (so the warm start stays valid and factors
+    are never applied twice); only ``dt`` and the drained bytes use
+    the effective rates.  ``loss=None`` traces the exact lossless
+    graph — zero-loss results are bit-identical.
+    """
+    n_flows = flow_links.shape[0]
+    n_caps = cap.shape[0]
+    eps = vol * 1e-6 + 1.0                  # completion slack (bytes)
 
-        ``warm`` compiles in the completion-epoch warm start: when an
-        epoch's completed flows are link-disjoint from every survivor,
-        the previous rate vector is reused and the filling skipped.
-        The batched (vmapped) solver sets ``warm=False``: under vmap
-        ``lax.cond`` lowers to a select that executes both branches, so
-        the skip can never fire and the dirty tracking would be pure
-        per-epoch overhead.
+    def cond(st):
+        _, rem, _, _, _, it = st
+        return jnp.logical_and(jnp.any(rem > 0.0), it <= n_flows)
 
-        ``loss`` (a ``(q, wsq, wnd, ecn)`` tuple of (F,) arrays, or
-        None) compiles in the expected-value loss/DCQCN correction: the
-        solved max-min rates are scaled by ``kernels/maxmin.py``'s
-        fused ``loss_factors`` each epoch.  The loop state carries the
-        RAW max-min rates (so the warm start stays valid and factors
-        are never applied twice); only ``dt`` and the drained bytes use
-        the effective rates.  ``loss=None`` traces the exact lossless
-        graph — zero-loss results are bit-identical.
-        """
-        n_flows = flow_links.shape[0]
-        n_caps = cap.shape[0]
-        eps = vol * 1e-6 + 1.0                  # completion slack (bytes)
-        if loss is not None:
-            from repro.core.flowsim import DCQCN_MIN_RATE, DCQCN_RATE_NUM
-            from repro.kernels.maxmin import loss_factors
-            q, wsq, wnd, ecn = loss
-
-        def cond(st):
-            _, rem, _, _, _, it = st
-            return jnp.logical_and(jnp.any(rem > 0.0), it <= n_flows)
-
-        def body(st):
-            t, rem, done, rates, dirty, it = st
-            active = rem > 0.0
-            if warm:
-                rates = lax.cond(
-                    dirty,
-                    lambda r: _maxmin_rates(flow_links, cap, active,
-                                            mode),
-                    lambda r: r, rates)
-            else:
-                rates = _maxmin_rates(flow_links, cap, active, mode)
-            eff = rates
-            if loss is not None:
-                eff = rates * loss_factors(
-                    flow_links, rates, active.astype(cap.dtype), cap,
-                    q, wsq, wnd, ecn, dcqcn_num=DCQCN_RATE_NUM,
-                    dcqcn_min=DCQCN_MIN_RATE, mode=mode)
-            dt = jnp.min(jnp.where(active, rem / eff, jnp.inf))
-            t = t + dt
-            rem = jnp.where(active, rem - eff * dt, 0.0)
-            fin = active & (rem <= eps)
-            done = jnp.where(fin, t, done)
-            rem = jnp.where(fin, 0.0, rem)
-            if warm:
-                touched = jnp.zeros(n_caps, cap.dtype).at[flow_links].add(
-                    jnp.broadcast_to(fin.astype(cap.dtype)[:, None],
-                                     flow_links.shape))
-                touched = touched.at[-1].set(0.0)   # sentinel: no contention
-                survive = active & ~fin
-                dirty = jnp.any(
-                    survive & (jnp.max(touched[flow_links], axis=1) > 0.0))
-            return t, rem, done, rates, dirty, it + 1
-
-        zero = jnp.asarray(0.0, cap.dtype)
-        init = (zero, vol, jnp.zeros(n_flows, cap.dtype),
-                jnp.zeros(n_flows, cap.dtype), jnp.bool_(True),
-                jnp.int32(0))
-        _, _, done, _, _, _ = lax.while_loop(cond, body, init)
-        return done
-
-    def _solver(batched: bool, mode: str = "auto", lossy: bool = False):
-        """Jitted solver, one per (batched, kernel-mode, lossy) flavor.
-
-        ``mode`` is the resolved ``kernels/maxmin.py`` dispatch (part
-        of the jit cache key, so a ``REPRO_MAXMIN`` change takes effect
-        immediately instead of hitting a stale executable).  ``lossy``
-        selects the flavor that threads the per-flow loss arrays —
-        lossless solves keep their exact pre-existing executable.
-        """
-        # normalize BEFORE the lru_cache: positional and defaulted
-        # calls must land on the same memoized jit object (the
-        # cache-hit tests introspect it via the two-arg form)
-        return _solver_impl(bool(batched), mode, bool(lossy))
-
-    @functools.lru_cache(maxsize=None)
-    def _seg_solver(mode: str):
-        """Jitted, vmapped dynamic-segment solver, one per kernel mode.
-
-        One lane = one fairness-snapshot problem: a padded (F, H)
-        link-id matrix, its active-row mask, and the index of the OWN
-        flow.  The lane solves max-min rates under the numpy-matched
-        ``SEG_TOL``/``SEG_ROUNDS`` regime, applies the fused loss/DCQCN
-        factors (all-zero loss rows give factor exactly 1.0, so one
-        always-lossy executable covers lossless problems bit-exactly),
-        and returns the own flow's corrected rate.
-        """
-        from repro.core.flowsim import DCQCN_MIN_RATE, DCQCN_RATE_NUM
-        from repro.kernels.maxmin import loss_factors, maxmin_rates
-
-        def one(fl, active, own, cap, loss):
-            rates = maxmin_rates(fl, cap, active, mode=mode, tol=SEG_TOL,
-                                 max_rounds=SEG_ROUNDS)
-            fac = loss_factors(fl, rates, active, cap, *loss,
-                               dcqcn_num=DCQCN_RATE_NUM,
-                               dcqcn_min=DCQCN_MIN_RATE, mode=mode)
-            return rates[own] * fac[own]
-
-        return jax.jit(jax.vmap(one, in_axes=(0, 0, 0, None,
-                                              (0, 0, 0, 0))))
-
-    @functools.lru_cache(maxsize=None)
-    def _solver_impl(batched: bool, mode: str, lossy: bool):
-        """``donate_argnums`` hands the volume buffer back to XLA (a
-        no-op on backends without donation support, e.g. CPU)."""
-        sim = functools.partial(_simulate, mode=mode, warm=not batched)
-        if batched:
-            fn = jax.vmap(sim, in_axes=(0, None, 0, 0) if lossy
-                          else (0, None, 0))
+    def body(st):
+        t, rem, done, rates, dirty, it = st
+        active = rem > 0.0
+        if warm:
+            rates = lax.cond(
+                dirty,
+                lambda r: maxmin_rates(flow_links, cap, active),
+                lambda r: r, rates)
         else:
-            fn = sim
-        donate = (2,) if jax.default_backend() not in ("cpu",) else ()
-        return jax.jit(fn, donate_argnums=donate)
+            rates = maxmin_rates(flow_links, cap, active)
+        eff = rates
+        if loss is not None:
+            eff = rates * loss_factors(
+                flow_links, rates, active.astype(cap.dtype), cap, *loss,
+                dcqcn_num=DCQCN_RATE_NUM, dcqcn_min=DCQCN_MIN_RATE)
+        dt = jnp.min(jnp.where(active, rem / eff, jnp.inf))
+        t = t + dt
+        rem = jnp.where(active, rem - eff * dt, 0.0)
+        fin = active & (rem <= eps)
+        done = jnp.where(fin, t, done)
+        rem = jnp.where(fin, 0.0, rem)
+        if warm:
+            touched = jnp.zeros(n_caps, cap.dtype).at[flow_links].add(
+                jnp.broadcast_to(fin.astype(cap.dtype)[:, None],
+                                 flow_links.shape))
+            touched = touched.at[-1].set(0.0)   # sentinel: no contention
+            survive = active & ~fin
+            dirty = jnp.any(
+                survive & (jnp.max(touched[flow_links], axis=1) > 0.0))
+        return t, rem, done, rates, dirty, it + 1
+
+    zero = jnp.asarray(0.0, cap.dtype)
+    init = (zero, vol, jnp.zeros(n_flows, cap.dtype),
+            jnp.zeros(n_flows, cap.dtype), jnp.bool_(True),
+            jnp.int32(0))
+    _, _, done, _, _, _ = lax.while_loop(cond, body, init)
+    return done
+
+
+def _solver(batched: bool, lossy: bool = False):
+    """Jitted solver, one per (batched, lossy) flavor.
+
+    ``lossy`` selects the flavor that threads the per-flow loss arrays
+    — lossless solves keep their exact pre-existing executable.
+    """
+    # normalize BEFORE the lru_cache: positional and defaulted calls
+    # must land on the same memoized jit object (the cache-hit tests
+    # introspect it)
+    return _solver_impl(bool(batched), bool(lossy))
+
+
+@functools.lru_cache(maxsize=None)
+def _solver_impl(batched: bool, lossy: bool):
+    """``donate_argnums`` hands the volume buffer back to XLA (a
+    no-op on backends without donation support, e.g. CPU)."""
+    sim = functools.partial(_simulate, warm=not batched)
+    if batched:
+        fn = jax.vmap(sim, in_axes=(0, None, 0, 0) if lossy
+                      else (0, None, 0))
+    else:
+        fn = sim
+    donate = (2,) if jax.default_backend() not in ("cpu",) else ()
+    return jax.jit(fn, donate_argnums=donate)
+
+
+@functools.lru_cache(maxsize=None)
+def _seg_solver():
+    """Jitted, vmapped dynamic-segment solver.
+
+    One lane = one fairness-snapshot problem: a padded (F, H)
+    link-id matrix, its active-row mask, and the index of the OWN
+    flow.  The lane solves max-min rates under the numpy-matched
+    ``SEG_TOL``/``SEG_ROUNDS`` regime, applies the loss/DCQCN
+    factors (all-zero loss rows give factor exactly 1.0, so one
+    always-lossy executable covers lossless problems bit-exactly),
+    and returns the own flow's corrected rate.
+    """
+    def one(fl, active, own, cap, loss):
+        rates = maxmin_rates(fl, cap, active, tol=SEG_TOL,
+                             max_rounds=SEG_ROUNDS)
+        fac = loss_factors(fl, rates, active, cap, *loss,
+                           dcqcn_num=DCQCN_RATE_NUM,
+                           dcqcn_min=DCQCN_MIN_RATE)
+        return rates[own] * fac[own]
+
+    return jax.jit(jax.vmap(one, in_axes=(0, 0, 0, None,
+                                          (0, 0, 0, 0))))
 
 
 class JaxFlowSim(LinkMap):
@@ -268,7 +248,7 @@ class JaxFlowSim(LinkMap):
     ``add()`` stages flows; ``run()`` builds the padded link-id matrix
     once (bucketed — see module docstring) and solves every completion
     epoch on-device; ``solve_many()`` solves a list of INDEPENDENT flow
-    batches in one vmapped executable.  Requires ``HAS_JAX``.
+    batches in one vmapped executable.
     """
 
     #: class-level toggle so benchmarks can measure the unbucketed
@@ -278,8 +258,6 @@ class JaxFlowSim(LinkMap):
     H_BUCKET_MIN = 8
 
     def __init__(self, topo: Topology, shared_cache: bool = True):
-        if not HAS_JAX:
-            raise RuntimeError("JaxFlowSim needs jax; use flowsim.FlowSim")
         super().__init__(topo, shared_cache)
         _enable_persistent_cache()
         self.flows: List[Flow] = []
@@ -358,9 +336,8 @@ class JaxFlowSim(LinkMap):
         scope: without it enabled, float64 inputs silently downcast to
         float32 and the promotion is lost.
         """
-        from repro.kernels.maxmin import _resolve_mode
-        solve = _solver(batched, _resolve_mode(), loss is not None)
-        ctx = enable_x64() if dtype == np.float64 \
+        solve = _solver(batched, loss is not None)
+        ctx = jax.enable_x64(True) if dtype == np.float64 \
             else contextlib.nullcontext()
         t0 = time.perf_counter()
         with ctx:
@@ -509,7 +486,6 @@ class JaxFlowSim(LinkMap):
         out = [0.0] * len(problems)
         if not problems:
             return out
-        from repro.kernels.maxmin import _resolve_mode
         dtype = np.float64
         self.solve_dtype = dtype
         cap = self._cap_ext(dtype)
@@ -522,7 +498,7 @@ class JaxFlowSim(LinkMap):
                 if self.bucketing else (f, h)
         batches = self._plan_batches(problems, list(range(len(problems))),
                                      shapes)
-        solve = _seg_solver(_resolve_mode())
+        solve = _seg_solver()
         for batch in batches:
             f_pad = max(shapes[i][0] for i in batch)
             h_pad = max(shapes[i][1] for i in batch)
@@ -548,7 +524,7 @@ class JaxFlowSim(LinkMap):
                     lrows[r, :, n - 1] = (lp.q, lp.wsq, lp.wnd,
                                           1.0 if lp.ecn else 0.0)
             t0 = time.perf_counter()
-            with enable_x64():
+            with jax.enable_x64(True):
                 vals = np.asarray(solve(
                     jnp.asarray(fl), jnp.asarray(act), jnp.asarray(own),
                     jnp.asarray(cap),

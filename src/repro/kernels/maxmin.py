@@ -1,7 +1,7 @@
-"""Fused max-min progressive-filling round as a Pallas kernel.
+"""Max-min progressive filling and the loss/DCQCN factors, in jnp.
 
 One round of water-filling over the padded (F, H) flow->link matrix
-(see ``core/flowsim_jax.py``) needs four logical passes:
+(see ``core/flowsim_jax.py``) makes four logical passes:
 
 1. per-link demand  — scatter-add every unfrozen flow onto its links;
 2. fair share       — ``cap_remaining / demand`` per link;
@@ -10,188 +10,51 @@ One round of water-filling over the padded (F, H) flow->link matrix
 4. freeze mask      — flows at the bottleneck freeze at rate ``b`` and
    their bandwidth is subtracted from every link they cross.
 
-The reference solver (``kernels/ref.py:maxmin_round_reference``) builds
-each intermediate — the (L+1,) demand/share/used vectors and the (F,)
-tightest vector — as a separate device array per round.  This kernel
-fuses the whole round into a single ``pallas_call``: a (phase, tile)
-grid makes one tiled pass over the (F, H) matrix per phase while the
-demand counts, fair shares, per-flow tightest shares, subtracted
-bandwidth, and the bottleneck scalar all live in VMEM/SMEM scratch and
-never round-trip through HBM.
-
-Mode selection (``_resolve_mode``) is automatic:
-
-- ``ref``       — the pure-jnp oracle; the default on CPU (this
-  container), where XLA fuses the jnp ops well and Pallas interpret
-  mode would only add overhead;
-- ``pallas``    — the compiled kernel; the default on TPU;
-- ``interpret`` — the kernel under the Pallas interpreter; used by the
-  correctness tests so the kernel path is exercised on any backend.
-
-``REPRO_MAXMIN=ref|pallas|interpret`` overrides.  All three modes are
-bit-compatible in float32 up to reduction-order rounding (tested to
-0.1% against the numpy ``flowsim.FlowSim`` filling).
+This jnp formulation is the solver's path on every platform: XLA
+lowers its scatter-adds and gathers for the CPU and for the TPU alike
+(``tests/test_tpu_compile.py`` compiles it for a v5e chip).  The
+numpy ``flowsim.FlowSim`` filling is its reference.
 """
 from __future__ import annotations
 
-import functools
-import os
-
-import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.kernels.ref import loss_factors_reference, maxmin_round_reference
 
-try:  # pallas is optional at runtime: the ref path never imports it
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except Exception:                               # pragma: no cover - gated
-    HAS_PALLAS = False
+def maxmin_round(flow_links, frozen, rates, cap_rem, *, tol: float = 1e-6):
+    """One progressive-filling round of max-min fair allocation.
 
-MODES = ("auto", "ref", "pallas", "interpret")
-
-
-def _resolve_mode(mode=None) -> str:
-    mode = mode or os.environ.get("REPRO_MAXMIN", "auto")
-    if mode not in MODES:
-        raise ValueError(f"maxmin mode {mode!r}; choose from {MODES}")
-    if mode != "auto":
-        return mode
-    if not HAS_PALLAS or jax.default_backend() != "tpu":
-        return "ref"
-    return "pallas"
-
-
-# ------------------------------------------------------------- the kernel
-
-def _round_kernel(links_ref, frozen_ref, rates_ref, cap_ref,
-                  rates_out, frozen_out, cap_out,
-                  cnt_s, share_s, used_s, tight_s, b_s, *,
-                  tol: float = 1e-6):
-    """Grid (3, n_tiles): phase-major sequential passes over flow tiles.
-
-    Phase 0 accumulates per-link demand; phase 1 turns it into fair
-    shares (once) and each tile's tightest-share vector + the global
-    bottleneck; phase 2 freezes, writes rates, and subtracts the frozen
-    bandwidth.  All intermediates live in scratch.
+    flow_links (F, H) int32 link ids padded with the sentinel (last)
+    index of ``cap_rem``; frozen (F,) 0/1 mask in cap dtype (padding
+    rows enter frozen); rates (F,); cap_rem (L+1,) with cap_rem[-1]=inf.
+    ``tol`` is the relative freeze slack (1e-6 suits float32 solves;
+    the float64 dynamic-segment solver passes 1e-12 to mirror the numpy
+    ``flowsim.static_maxmin`` filling).  Returns the round's
+    (rates, frozen, cap_rem).
     """
-    phase = pl.program_id(0)
-    i = pl.program_id(1)
-    n_tiles = pl.num_programs(1)
-    tf = links_ref.shape[0]
-    dtype = cap_ref.dtype
-
-    @pl.when((phase == 0) & (i == 0))
-    def _init():
-        cnt_s[...] = jnp.zeros_like(cnt_s)
-        used_s[...] = jnp.zeros_like(used_s)
-        b_s[0] = jnp.asarray(jnp.inf, dtype)
-
-    @pl.when(phase == 0)
-    def _demand():
-        live = 1.0 - frozen_ref[...]
-        cnt_s[...] = cnt_s[...].at[links_ref[...]].add(
-            jnp.broadcast_to(live[:, None], links_ref.shape))
-
-    @pl.when((phase == 1) & (i == 0))
-    def _share():
-        cnt = cnt_s[...]
-        share_s[...] = jnp.where(cnt > 0.0,
-                                 cap_ref[...] / jnp.maximum(cnt, 1.0),
-                                 jnp.asarray(jnp.inf, dtype))
-
-    @pl.when(phase == 1)
-    def _tightest():
-        tight = jnp.min(share_s[...][links_ref[...]], axis=1)
-        tight_s[pl.ds(i * tf, tf)] = tight
-        limit = jnp.where(frozen_ref[...] > 0.5,
-                          jnp.asarray(jnp.inf, dtype), tight)
-        b_s[0] = jnp.minimum(b_s[0], jnp.min(limit))
-
-    @pl.when(phase == 2)
-    def _freeze():
-        b = b_s[0]
-        frozen = frozen_ref[...]
-        tight = tight_s[pl.ds(i * tf, tf)]
-        limit = jnp.where(frozen > 0.5, jnp.asarray(jnp.inf, dtype), tight)
-        newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
-        newf = newly.astype(dtype)
-        rates_out[...] = jnp.where(newly, b, rates_ref[...])
-        frozen_out[...] = jnp.minimum(frozen + newf, 1.0)
-        used_s[...] = used_s[...].at[links_ref[...]].add(
-            jnp.broadcast_to((newf * b)[:, None], links_ref.shape))
-
-        @pl.when(i == n_tiles - 1)
-        def _subtract():
-            cap_out[...] = jnp.maximum(cap_ref[...] - used_s[...], 0.0)
-
-
-def maxmin_round_pallas(flow_links, frozen, rates, cap_rem, *,
-                        block_f: int = 256, interpret: bool = False,
-                        tol: float = 1e-6):
-    """One fused progressive-filling round (see module docstring).
-
-    Pads F up to a multiple of ``block_f`` with pre-frozen sentinel
-    rows and slices back, so any F is accepted.  ``tol`` is the
-    compile-time freeze slack (see ``maxmin_round_reference``).
-    """
-    if not HAS_PALLAS:                          # pragma: no cover - gated
-        raise RuntimeError("pallas is not importable; use mode='ref'")
-    n_flows, n_hops = flow_links.shape
     n_caps = cap_rem.shape[0]
     dtype = cap_rem.dtype
-    tf = min(block_f, max(n_flows, 1))
-    pad = (-n_flows) % tf
-    if pad:
-        flow_links = jnp.concatenate(
-            [flow_links, jnp.full((pad, n_hops), n_caps - 1, jnp.int32)])
-        frozen = jnp.concatenate([frozen, jnp.ones(pad, dtype)])
-        rates = jnp.concatenate([rates, jnp.zeros(pad, dtype)])
-    f_pad = n_flows + pad
-    n_tiles = f_pad // tf
-
-    grid = (3, n_tiles)
-    tile_spec = lambda: pl.BlockSpec((tf, n_hops), lambda p, i: (i, 0))
-    vec_spec = lambda: pl.BlockSpec((tf,), lambda p, i: (i,))
-    cap_spec = lambda: pl.BlockSpec((n_caps,), lambda p, i: (0,))
-
-    rates_o, frozen_o, cap_o = pl.pallas_call(
-        functools.partial(_round_kernel, tol=tol),
-        grid=grid,
-        in_specs=[tile_spec(), vec_spec(), vec_spec(), cap_spec()],
-        out_specs=[vec_spec(), vec_spec(), cap_spec()],
-        out_shape=[jax.ShapeDtypeStruct((f_pad,), dtype),
-                   jax.ShapeDtypeStruct((f_pad,), dtype),
-                   jax.ShapeDtypeStruct((n_caps,), dtype)],
-        scratch_shapes=[pltpu.VMEM((n_caps,), dtype),    # demand counts
-                        pltpu.VMEM((n_caps,), dtype),    # fair shares
-                        pltpu.VMEM((n_caps,), dtype),    # frozen bandwidth
-                        pltpu.VMEM((f_pad,), dtype),     # tightest shares
-                        pltpu.SMEM((1,), dtype)],        # bottleneck b
-        interpret=interpret,
-    )(flow_links, frozen, rates, cap_rem)
-    return rates_o[:n_flows], frozen_o[:n_flows], cap_o
+    live = 1.0 - frozen
+    # per-link demand: scatter every live flow onto its links
+    cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
+        jnp.broadcast_to(live[:, None], flow_links.shape))
+    share = jnp.where(cnt > 0.0, cap_rem / jnp.maximum(cnt, 1.0), jnp.inf)
+    # each flow's tightest link share (sentinel gathers inf)
+    tightest = jnp.min(share[flow_links], axis=1)
+    limit = jnp.where(frozen > 0.5, jnp.inf, tightest)
+    b = jnp.min(limit)
+    newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
+    newf = newly.astype(dtype)
+    rates = jnp.where(newly, b, rates)
+    used = jnp.zeros(n_caps, dtype).at[flow_links].add(
+        jnp.broadcast_to((newf * b)[:, None], flow_links.shape))
+    cap_rem = jnp.maximum(cap_rem - used, 0.0)
+    return rates, jnp.minimum(frozen + newf, 1.0), cap_rem
 
 
-def maxmin_round(flow_links, frozen, rates, cap_rem, *, mode=None,
-                 block_f: int = 256, tol: float = 1e-6):
-    """Mode-dispatched fused round; returns (rates, frozen, cap_rem)."""
-    mode = _resolve_mode(mode)
-    if mode == "ref":
-        return maxmin_round_reference(flow_links, frozen, rates, cap_rem,
-                                      tol=tol)
-    return maxmin_round_pallas(flow_links, frozen, rates, cap_rem,
-                               block_f=block_f,
-                               interpret=(mode == "interpret"), tol=tol)
-
-
-# ------------------------------------------------------------- the solver
-
-def maxmin_rates(flow_links, cap, active, *, mode=None, block_f: int = 256,
-                 tol: float = 1e-6, max_rounds=None):
-    """Max-min fair rates by progressive filling over the fused round.
+def maxmin_rates(flow_links, cap, active, *, tol: float = 1e-6,
+                 max_rounds=None):
+    """Max-min fair rates by progressive filling over ``maxmin_round``.
 
     flow_links (F, H) int32 padded with the sentinel (last) index of
     ``cap``; cap (L+1,) bytes/s with cap[-1] = inf; active (F,) bool.
@@ -205,11 +68,8 @@ def maxmin_rates(flow_links, cap, active, *, mode=None, block_f: int = 256,
     max_rounds=64`` under float64 to mirror the numpy
     ``flowsim.static_maxmin`` filling round for round.
     """
-    mode = _resolve_mode(mode)
     n_flows = flow_links.shape[0]
     dtype = cap.dtype
-    step = functools.partial(maxmin_round, mode=mode, block_f=block_f,
-                             tol=tol)
     bound = n_flows if max_rounds is None else max_rounds - 1
 
     def cond(st):
@@ -218,7 +78,8 @@ def maxmin_rates(flow_links, cap, active, *, mode=None, block_f: int = 256,
 
     def body(st):
         rates, frozen, cap_rem, it = st
-        rates, frozen, cap_rem = step(flow_links, frozen, rates, cap_rem)
+        rates, frozen, cap_rem = maxmin_round(flow_links, frozen, rates,
+                                              cap_rem, tol=tol)
         return rates, frozen, cap_rem, it + 1
 
     init = (jnp.zeros(n_flows, dtype), 1.0 - active.astype(dtype),
@@ -227,109 +88,50 @@ def maxmin_rates(flow_links, cap, active, *, mode=None, block_f: int = 256,
     return jnp.maximum(rates, 1e-9)
 
 
-# -------------------------------------------------- the loss-factor kernel
+def loss_factors(flow_links, rates, active, cap, q, wsq, wnd, ecn, *,
+                 dcqcn_num: float, dcqcn_min: float,
+                 util_eps: float = 1e-3):
+    """Expected-value loss/DCQCN rate-correction factors, (F,) in (0, 1].
 
-def _loss_kernel(links_ref, rates_ref, active_ref, cap_ref, q_ref, wsq_ref,
-                 wnd_ref, ecn_ref, fac_out, util_s, cnt_s, *,
-                 dcqcn_num: float, dcqcn_min: float, util_eps: float):
-    """Grid (2, n_tiles): fused expected-value loss/DCQCN correction.
+    The per-flow multiplier the fluid solver applies to its max-min
+    rates so lossy go-back-N transfers slow down the way the packet
+    engine's do (see docs/ARCHITECTURE.md "Loss & congestion model";
+    ``flowsim.FlowSim._apply_loss`` is its numpy twin):
 
-    Phase 0 scatter-adds per-link utilization and active-flow counts
-    into VMEM scratch; phase 1 turns them into per-flow rate factors
-    (go-back-N goodput x DCQCN undershoot — the math documented on
-    ``ref.py:loss_factors_reference``) without materializing the hot-
-    link mask or any per-link intermediate in HBM.
+    - go-back-N replay: a loss costs ``W = min(sqrt(rate * wsq), wnd)``
+      replayed packets (``wsq`` pre-folds the calibrated replay window
+      and NACK-merge damping, so ``sqrt(rate * wsq)`` is the geometric
+      mean of the flow- and link-BDP in packets); the steady-state
+      goodput fraction is ``(1-q) / (1-q + q*W)``.
+    - DCQCN: flows crossing a *shared saturated* link (>= 2 active
+      flows, utilization at capacity) with ECN marking enabled sit on
+      the CNP/recovery sawtooth; the average undershoot is
+      ``alpha_eq / 4`` with ``alpha_eq = dcqcn_num / rate`` (clipped to
+      [0, 1]), floored so the effective rate never falls below the
+      DCQCN minimum rate — and never negative or above capacity, since
+      the returned factor is always in (0, 1].
+
+    flow_links (F, H) int32 padded with the sentinel (last) index of
+    ``cap``; rates (F,) solved max-min rates; active (F,) 0/1 mask in
+    cap dtype; cap (L+1,) with cap[-1] = inf (the sentinel can never be
+    saturated); q / wsq / wnd / ecn (F,) per-flow loss-model arrays
+    (all-zero rows — padding or lossless flows — get factor exactly 1).
     """
-    phase = pl.program_id(0)
-    i = pl.program_id(1)
-    dtype = cap_ref.dtype
-
-    @pl.when((phase == 0) & (i == 0))
-    def _init():
-        util_s[...] = jnp.zeros_like(util_s)
-        cnt_s[...] = jnp.zeros_like(cnt_s)
-
-    @pl.when(phase == 0)
-    def _scatter():
-        act = active_ref[...]
-        util_s[...] = util_s[...].at[links_ref[...]].add(
-            jnp.broadcast_to((act * rates_ref[...])[:, None],
-                             links_ref.shape))
-        cnt_s[...] = cnt_s[...].at[links_ref[...]].add(
-            jnp.broadcast_to(act[:, None], links_ref.shape))
-
-    @pl.when(phase == 1)
-    def _factors():
-        hot = ((cnt_s[...] >= 2.0) &
-               (util_s[...] >= cap_ref[...] * (1.0 - util_eps))).astype(dtype)
-        flow_hot = jnp.max(hot[links_ref[...]], axis=1)
-        rates = rates_ref[...]
-        q = q_ref[...]
-        w = jnp.minimum(jnp.sqrt(jnp.maximum(rates * wsq_ref[...], 0.0)),
-                        wnd_ref[...])
-        gbn = (1.0 - q) / jnp.maximum(1.0 - q + q * w, 1e-30)
-        alpha = jnp.clip(dcqcn_num / jnp.maximum(rates, 1e-30), 0.0, 1.0)
-        dc = 1.0 - 0.25 * alpha * ecn_ref[...] * flow_hot
-        floor = jnp.minimum(dcqcn_min / jnp.maximum(rates, 1e-30), 1.0)
-        fac_out[...] = jnp.clip(gbn * jnp.maximum(dc, floor), 1e-9, 1.0)
-
-
-def loss_factors_pallas(flow_links, rates, active, cap, q, wsq, wnd, ecn, *,
-                        dcqcn_num: float, dcqcn_min: float,
-                        util_eps: float = 1e-3, block_f: int = 256,
-                        interpret: bool = False):
-    """Fused loss/DCQCN factors; pads F with zero (factor-1) sentinel rows."""
-    if not HAS_PALLAS:                          # pragma: no cover - gated
-        raise RuntimeError("pallas is not importable; use mode='ref'")
-    n_flows, n_hops = flow_links.shape
     n_caps = cap.shape[0]
     dtype = cap.dtype
-    tf = min(block_f, max(n_flows, 1))
-    pad = (-n_flows) % tf
-    if pad:
-        flow_links = jnp.concatenate(
-            [flow_links, jnp.full((pad, n_hops), n_caps - 1, jnp.int32)])
-        zeros = jnp.zeros(pad, dtype)
-        rates, active, q, wsq, wnd, ecn = (
-            jnp.concatenate([v, zeros])
-            for v in (rates, active, q, wsq, wnd, ecn))
-    f_pad = n_flows + pad
-    n_tiles = f_pad // tf
-
-    tile_spec = lambda: pl.BlockSpec((tf, n_hops), lambda p, i: (i, 0))
-    vec_spec = lambda: pl.BlockSpec((tf,), lambda p, i: (i,))
-    cap_spec = lambda: pl.BlockSpec((n_caps,), lambda p, i: (0,))
-
-    fac = pl.pallas_call(
-        functools.partial(_loss_kernel, dcqcn_num=dcqcn_num,
-                          dcqcn_min=dcqcn_min, util_eps=util_eps),
-        grid=(2, n_tiles),
-        in_specs=[tile_spec(), vec_spec(), vec_spec(), cap_spec(),
-                  vec_spec(), vec_spec(), vec_spec(), vec_spec()],
-        out_specs=vec_spec(),
-        out_shape=jax.ShapeDtypeStruct((f_pad,), dtype),
-        scratch_shapes=[pltpu.VMEM((n_caps,), dtype),    # link utilization
-                        pltpu.VMEM((n_caps,), dtype)],   # active-flow count
-        interpret=interpret,
-    )(flow_links, rates, active, cap, q, wsq, wnd, ecn)
-    return fac[:n_flows]
-
-
-def loss_factors(flow_links, rates, active, cap, q, wsq, wnd, ecn, *,
-                 dcqcn_num: float, dcqcn_min: float, mode=None,
-                 block_f: int = 256):
-    """Mode-dispatched loss/DCQCN rate factors, (F,) in (0, 1].
-
-    Same mode contract as ``maxmin_round`` (ref / pallas / interpret,
-    ``REPRO_MAXMIN`` override); the oracle lives in
-    ``ref.py:loss_factors_reference``.
-    """
-    mode = _resolve_mode(mode)
-    if mode == "ref":
-        return loss_factors_reference(flow_links, rates, active, cap, q,
-                                      wsq, wnd, ecn, dcqcn_num=dcqcn_num,
-                                      dcqcn_min=dcqcn_min)
-    return loss_factors_pallas(flow_links, rates, active, cap, q, wsq, wnd,
-                               ecn, dcqcn_num=dcqcn_num, dcqcn_min=dcqcn_min,
-                               block_f=block_f,
-                               interpret=(mode == "interpret"))
+    # per-link utilization + active-flow count (one scatter each)
+    util = jnp.zeros(n_caps, dtype).at[flow_links].add(
+        jnp.broadcast_to((active * rates)[:, None], flow_links.shape))
+    cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
+        jnp.broadcast_to(active[:, None], flow_links.shape))
+    hot = ((cnt >= 2.0) & (util >= cap * (1.0 - util_eps))).astype(dtype)
+    flow_hot = jnp.max(hot[flow_links], axis=1)
+    # go-back-N: replay window in packets, then steady-state goodput
+    w = jnp.minimum(jnp.sqrt(jnp.maximum(rates * wsq, 0.0)), wnd)
+    gbn = (1.0 - q) / jnp.maximum(1.0 - q + q * w, 1e-30)
+    # DCQCN sawtooth undershoot on ECN-marked (shared, saturated) links
+    alpha = jnp.clip(dcqcn_num / jnp.maximum(rates, 1e-30), 0.0, 1.0)
+    dc = 1.0 - 0.25 * alpha * ecn * flow_hot
+    floor = jnp.minimum(dcqcn_min / jnp.maximum(rates, 1e-30), 1.0)
+    dc = jnp.maximum(dc, floor)
+    return jnp.clip(gbn * dc, 1e-9, 1.0)

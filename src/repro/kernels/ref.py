@@ -12,90 +12,6 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def maxmin_round_reference(flow_links, frozen, rates, cap_rem, *,
-                           tol: float = 1e-6):
-    """One progressive-filling round of max-min fair allocation.
-
-    The oracle for ``kernels/maxmin.py`` — plain jnp, materializing every
-    intermediate the fused kernel is allowed to keep on-chip.
-
-    flow_links (F, H) int32 link ids padded with the sentinel (last)
-    index of ``cap_rem``; frozen (F,) 0/1 mask in cap dtype (padding
-    rows enter frozen); rates (F,); cap_rem (L+1,) with cap_rem[-1]=inf.
-    ``tol`` is the relative freeze slack (1e-6 suits float32 solves;
-    the float64 dynamic-segment solver passes 1e-12 to mirror the numpy
-    ``flowsim.static_maxmin`` filling).  Returns the round's
-    (rates, frozen, cap_rem).
-    """
-    n_caps = cap_rem.shape[0]
-    dtype = cap_rem.dtype
-    live = 1.0 - frozen
-    # per-link demand: scatter every live flow onto its links
-    cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
-        jnp.broadcast_to(live[:, None], flow_links.shape))
-    share = jnp.where(cnt > 0.0, cap_rem / jnp.maximum(cnt, 1.0), jnp.inf)
-    # each flow's tightest link share (sentinel gathers inf)
-    tightest = jnp.min(share[flow_links], axis=1)
-    limit = jnp.where(frozen > 0.5, jnp.inf, tightest)
-    b = jnp.min(limit)
-    newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
-    newf = newly.astype(dtype)
-    rates = jnp.where(newly, b, rates)
-    used = jnp.zeros(n_caps, dtype).at[flow_links].add(
-        jnp.broadcast_to((newf * b)[:, None], flow_links.shape))
-    cap_rem = jnp.maximum(cap_rem - used, 0.0)
-    return rates, jnp.minimum(frozen + newf, 1.0), cap_rem
-
-
-def loss_factors_reference(flow_links, rates, active, cap, q, wsq, wnd,
-                           ecn, *, dcqcn_num: float, dcqcn_min: float,
-                           util_eps: float = 1e-3):
-    """Expected-value loss/DCQCN rate-correction factors, (F,) in (0, 1].
-
-    The oracle for ``kernels/maxmin.py:loss_factors`` — the per-flow
-    multiplier the fluid solver applies to its max-min rates so lossy
-    go-back-N transfers slow down the way the packet engine's do (see
-    docs/ARCHITECTURE.md "Loss & congestion model"):
-
-    - go-back-N replay: a loss costs ``W = min(sqrt(rate * wsq), wnd)``
-      replayed packets (``wsq`` pre-folds the calibrated replay window
-      and NACK-merge damping, so ``sqrt(rate * wsq)`` is the geometric
-      mean of the flow- and link-BDP in packets); the steady-state
-      goodput fraction is ``(1-q) / (1-q + q*W)``.
-    - DCQCN: flows crossing a *shared saturated* link (>= 2 active
-      flows, utilization at capacity) with ECN marking enabled sit on
-      the CNP/recovery sawtooth; the average undershoot is
-      ``alpha_eq / 4`` with ``alpha_eq = dcqcn_num / rate`` (clipped to
-      [0, 1]), floored so the effective rate never falls below the
-      DCQCN minimum rate — and never negative or above capacity, since
-      the returned factor is always in (0, 1].
-
-    flow_links (F, H) int32 padded with the sentinel (last) index of
-    ``cap``; rates (F,) solved max-min rates; active (F,) 0/1 mask in
-    cap dtype; cap (L+1,) with cap[-1] = inf (the sentinel can never be
-    saturated); q / wsq / wnd / ecn (F,) per-flow loss-model arrays
-    (all-zero rows — padding or lossless flows — get factor exactly 1).
-    """
-    n_caps = cap.shape[0]
-    dtype = cap.dtype
-    # per-link utilization + active-flow count (one scatter each)
-    util = jnp.zeros(n_caps, dtype).at[flow_links].add(
-        jnp.broadcast_to((active * rates)[:, None], flow_links.shape))
-    cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
-        jnp.broadcast_to(active[:, None], flow_links.shape))
-    hot = ((cnt >= 2.0) & (util >= cap * (1.0 - util_eps))).astype(dtype)
-    flow_hot = jnp.max(hot[flow_links], axis=1)
-    # go-back-N: replay window in packets, then steady-state goodput
-    w = jnp.minimum(jnp.sqrt(jnp.maximum(rates * wsq, 0.0)), wnd)
-    gbn = (1.0 - q) / jnp.maximum(1.0 - q + q * w, 1e-30)
-    # DCQCN sawtooth undershoot on ECN-marked (shared, saturated) links
-    alpha = jnp.clip(dcqcn_num / jnp.maximum(rates, 1e-30), 0.0, 1.0)
-    dc = 1.0 - 0.25 * alpha * ecn * flow_hot
-    floor = jnp.minimum(dcqcn_min / jnp.maximum(rates, 1e-30), 1.0)
-    dc = jnp.maximum(dc, floor)
-    return jnp.clip(gbn * dc, 1e-9, 1.0)
-
-
 def mha_reference(q, k, v, *, causal: bool, window: int = 0):
     """Multi-head attention oracle. q (B,Sq,H,D); k,v (B,Skv,KVH,D).
     GQA: H = KVH * rep.  window > 0 = sliding window (causal band)."""

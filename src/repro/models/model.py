@@ -16,7 +16,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn

@@ -27,7 +27,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.models.blocks import ParamDef
 

@@ -28,7 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 
 
 def pipeline(stage_fn, axis_name: str):

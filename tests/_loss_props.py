@@ -37,7 +37,7 @@ def run_factor_bounds_case(seed):
     """Kernel-level: correction factors are always in (0, 1], so the
     effective rate is positive and never above the solved max-min rate
     (hence never above link capacity) — whatever the q/wsq/ECN mix."""
-    from repro.kernels.ref import loss_factors_reference
+    from repro.kernels.maxmin import loss_factors
     rng = np.random.default_rng(seed)
     n_links = int(rng.integers(1, 12))
     n_flows = int(rng.integers(1, 24))
@@ -52,7 +52,7 @@ def run_factor_bounds_case(seed):
     wsq = rng.uniform(0.0, 1e-4, n_flows).astype(f32)
     wnd = rng.uniform(1.0, 1024.0, n_flows).astype(f32)
     ecn = (rng.random(n_flows) < 0.5).astype(f32)
-    fac = np.asarray(loss_factors_reference(
+    fac = np.asarray(loss_factors(
         links, rates, active, cap, q, wsq, wnd, ecn,
         dcqcn_num=flowsim.DCQCN_RATE_NUM,
         dcqcn_min=flowsim.DCQCN_MIN_RATE))

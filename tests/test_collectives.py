@@ -13,7 +13,7 @@ COLLECTIVES_SRC = r"""
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import collectives as coll
 
 mesh = jax.make_mesh((8,), ("x",))

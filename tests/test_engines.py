@@ -10,6 +10,8 @@ each other far tighter.
 """
 from __future__ import annotations
 
+import sys
+
 import pytest
 
 from repro.core import fattree
@@ -101,7 +103,6 @@ def test_transport_jct_parity_flow_vs_packet(transport, nbytes):
 def test_transport_flow_solvers_agree(transport):
     """numpy and JAX lower transports identically (same edge flows,
     same finalizers): JCTs must match to 0.1%."""
-    pytest.importorskip("jax")
     j_np = transport_bcast_jct("flow-np", transport, 1 << 20)
     j_jx = transport_bcast_jct("flow", transport, 1 << 20)
     assert abs(j_np - j_jx) / j_np < 1e-3, (transport, j_np, j_jx)
@@ -143,7 +144,6 @@ def test_overlay_transport_per_receiver_ordering():
 def test_flow_solvers_agree_tightly():
     """numpy and JAX progressive filling are the same algorithm; on a
     contended fat tree their JCTs must match to 0.1%."""
-    pytest.importorskip("jax")
     topo = two_pod_fat_tree()
     members = list(topo.hosts)
     j_np = bcast_jct("flow-np", topo, members, 1 << 20)
@@ -184,6 +184,18 @@ def test_unicast_and_write_complete_on_both_engines():
         assert ru.jct(1) != float("inf"), name
         assert rw.jct(3) != float("inf"), name
         assert ru.complete and rw.complete, name
+
+
+def test_flow_engine_is_the_device_solver_or_raises(monkeypatch):
+    """``flow`` is always the JAX solver; where that cannot be built it
+    raises instead of quietly becoming the numpy ``flow-np``."""
+    from repro.core.flowsim_jax import JaxFlowSim
+    eng = make_engine("flow", fattree.testbed())
+    assert eng.name == "flow" and isinstance(eng._sim, JaxFlowSim)
+    monkeypatch.setitem(sys.modules, "repro.core.flowsim_jax", None)
+    with pytest.raises(ImportError):
+        make_engine("flow", fattree.testbed())
+    assert make_engine("flow-np", fattree.testbed()).name == "flow-np"
 
 
 def test_flow_engine_epochs_are_sequential():

@@ -123,32 +123,46 @@ def test_dcqcn_constants_pinned_to_packet_engine():
         2.0 * rs.inc * qp.cnp_interval / rs.period)
 
 
-def test_kernel_modes_agree():
-    """loss_factors: interpret-mode Pallas kernel vs the jnp oracle."""
-    jnp = pytest.importorskip("jax.numpy")
+def test_loss_factors_match_numpy_twin():
+    """loss_factors (the device path) vs ``FlowSim._apply_loss`` on the
+    same solved rates: same math, so float64 agrees to rounding."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.flowsim import Flow, FlowSim, LossParams
     from repro.kernels.maxmin import loss_factors
     rng = np.random.default_rng(7)
-    n_links, n_flows, hops = 9, 50, 3
-    cap = np.append(rng.uniform(1e9, 3e10, n_links), np.inf)
-    links = rng.integers(0, n_links, (n_flows, hops)).astype(np.int32)
-    links[5:, 2] = n_links                   # sentinel padding column
-    rates = rng.uniform(1e8, 2.5e10, n_flows)
-    active = (rng.random(n_flows) < 0.8).astype(float)
-    q = np.where(rng.random(n_flows) < 0.5,
-                 rng.uniform(0.0, 0.3, n_flows), 0.0)
-    wsq = rng.uniform(0.0, 1e-5, n_flows)
-    wnd = np.full(n_flows, 512.0)
-    ecn = (rng.random(n_flows) < 0.5).astype(float)
-    args = tuple(jnp.asarray(a) for a in
-                 (links, rates, active, cap, q, wsq, wnd, ecn))
-    kw = dict(dcqcn_num=flowsim.DCQCN_RATE_NUM,
-              dcqcn_min=flowsim.DCQCN_MIN_RATE)
-    ref = loss_factors(*args, mode="ref", **kw)
-    out = loss_factors(*args, mode="interpret", block_f=16, **kw)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6)
-    assert np.all(np.asarray(ref) > 0.0)
-    assert np.all(np.asarray(ref) <= 1.0)
+    sim = FlowSim(fattree.testbed(n_hosts=4))
+    n_links, n_flows, hops = len(sim.cap), 50, 3
+    flows = []
+    for _ in range(n_flows):
+        links = tuple(int(x) for x in rng.choice(
+            n_links, int(rng.integers(1, hops + 1)), replace=False))
+        lp = LossParams(q=float(rng.uniform(0.0, 0.3)),
+                        wsq=float(rng.uniform(0.0, 1e-5)), wnd=512.0,
+                        tail=0.0, ecn=bool(rng.random() < 0.5)) \
+            if rng.random() < 0.7 else None
+        f = Flow(links, 1e6, loss=lp)
+        f.rate = float(rng.uniform(1e8, 2.5e10))
+        flows.append(f)
+    rates = np.array([f.rate for f in flows])
+    links = np.full((n_flows, hops), n_links, np.int32)
+    rows = np.zeros((4, n_flows))
+    for i, f in enumerate(flows):
+        links[i, :len(f.links)] = f.links
+        if f.loss is not None:
+            rows[:, i] = (f.loss.q, f.loss.wsq, f.loss.wnd, float(f.loss.ecn))
+    sim._apply_loss(flows)
+    want = np.array([f.rate for f in flows])
+    with jax.enable_x64(True):
+        fac = loss_factors(
+            jnp.asarray(links), jnp.asarray(rates), jnp.ones(n_flows),
+            jnp.asarray(np.append(sim.cap, np.inf)),
+            *(jnp.asarray(r) for r in rows),
+            dcqcn_num=flowsim.DCQCN_RATE_NUM,
+            dcqcn_min=flowsim.DCQCN_MIN_RATE)
+        got = rates * np.asarray(fac)
+    np.testing.assert_allclose(got, want, rtol=1e-12)
+    assert np.any(got < rates)               # the loss model did bite
 
 
 # ========================================= invariants (seeded fuzz)

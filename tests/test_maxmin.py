@@ -1,8 +1,8 @@
-"""The fused max-min solver stack (kernels/maxmin.py + flowsim_jax.py).
+"""The max-min solver stack (kernels/maxmin.py + flowsim_jax.py).
 
-- property-style randomized agreement: the Pallas kernel (interpret
-  mode, so it runs on any backend) against the numpy ``FlowSim``
-  progressive filling, on randomized topologies and flow sets, to 0.1%;
+- property-style randomized agreement: the jnp progressive filling
+  against the numpy ``FlowSim`` filling, on randomized topologies and
+  flow sets, to 0.1%;
 - shape bucketing: two sweep points in the same (F, H) bucket must hit
   the jit cache (no recompile);
 - float64 auto-promotion once volumes exceed the float32 safe-integer
@@ -19,18 +19,16 @@ from repro.core import fattree
 from repro.core.engine import make_engine
 from repro.core.flowsim import FlowSim
 
-jax = pytest.importorskip("jax")
-jnp = jax.numpy
+import jax.numpy as jnp
 
-from repro.core import flowsim_jax                     # noqa: E402
-from repro.core.flowsim_jax import JaxFlowSim, _bucket, _solver  # noqa: E402
-from repro.kernels import maxmin                       # noqa: E402
-from repro.kernels.ref import maxmin_round_reference   # noqa: E402
+from repro.core import flowsim_jax
+from repro.core.flowsim_jax import JaxFlowSim, _bucket, _solver
+from repro.kernels import maxmin
 
 
 def _jit_cache_size() -> int:
     """Compiled-shape count of the solver flavor ``run()`` dispatches."""
-    return _solver(False, maxmin._resolve_mode())._cache_size()
+    return _solver(False)._cache_size()
 
 
 def small_fat_tree():
@@ -66,12 +64,12 @@ def pack_links(flows, n_links):
     return fl
 
 
-# =============================================== kernel vs numpy filling
+# ================================================ jnp vs numpy filling
 
 @pytest.mark.parametrize("seed", range(5))
-def test_pallas_kernel_matches_numpy_filling(seed):
-    """ISSUE acceptance: interpret-mode kernel rates agree with the
-    numpy FlowSim progressive filling within 0.1% on random cases."""
+def test_maxmin_rates_match_numpy_filling(seed):
+    """The jnp progressive filling agrees with the numpy FlowSim
+    filling within 0.1% on random cases."""
     rng = np.random.default_rng(seed)
     topo = small_fat_tree() if seed % 2 else fattree.fig4()
     ref_sim = FlowSim(topo)
@@ -84,29 +82,8 @@ def test_pallas_kernel_matches_numpy_filling(seed):
     cap = np.append(ref_sim.cap, np.inf).astype(np.float32)
     active = np.ones(len(flows), bool)
     got = np.asarray(maxmin.maxmin_rates(
-        jnp.asarray(fl), jnp.asarray(cap), jnp.asarray(active),
-        mode="interpret", block_f=8))
+        jnp.asarray(fl), jnp.asarray(cap), jnp.asarray(active)))
     np.testing.assert_allclose(got, want, rtol=1e-3)
-
-
-def test_kernel_round_matches_reference_exactly():
-    """One fused round == the jnp oracle, including freeze/cap state."""
-    rng = np.random.default_rng(7)
-    F, H, L = 23, 4, 17
-    links = rng.integers(0, L, (F, H)).astype(np.int32)
-    for i in range(F):                     # ragged link lists
-        links[i, int(rng.integers(1, H + 1)):] = L
-    cap = np.append(rng.uniform(1.0, 10.0, L), np.inf).astype(np.float32)
-    frozen = (rng.random(F) < 0.3).astype(np.float32)
-    rates = np.zeros(F, np.float32)
-    want = maxmin_round_reference(jnp.asarray(links), jnp.asarray(frozen),
-                                  jnp.asarray(rates), jnp.asarray(cap))
-    got = maxmin.maxmin_round_pallas(
-        jnp.asarray(links), jnp.asarray(frozen), jnp.asarray(rates),
-        jnp.asarray(cap), block_f=8, interpret=True)
-    for g, w, name in zip(got, want, ("rates", "frozen", "cap_rem")):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=1e-6, err_msg=name)
 
 
 @pytest.mark.parametrize("seed", range(3))
@@ -169,24 +146,6 @@ def test_unbucketed_mode_recompiles_per_shape():
     before = _jit_cache_size()
     solve(19)                               # exact shapes -> recompile
     assert _jit_cache_size() == before + 1
-
-
-def test_mode_override_not_stale_after_compile(monkeypatch):
-    """REPRO_MAXMIN set AFTER a bucket compiled must still take effect
-    (the kernel mode is part of the jit cache key, not baked into a
-    stale executable)."""
-    topo = fattree.testbed()
-    sim = JaxFlowSim(topo)
-    sim.add(sim.unicast_links("h0", "h1"), 1e6)
-    sim.run()
-    want = sim.flows[0].done_t
-    monkeypatch.setenv("REPRO_MAXMIN", "interpret")
-    before = _solver(False, "interpret")._cache_size()
-    sim2 = JaxFlowSim(topo)
-    sim2.add(sim2.unicast_links("h0", "h1"), 1e6)
-    sim2.run()
-    assert _solver(False, "interpret")._cache_size() == before + 1
-    assert sim2.flows[0].done_t == pytest.approx(want, rel=1e-5)
 
 
 # ==================================================== float64 promotion

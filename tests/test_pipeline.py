@@ -10,7 +10,7 @@ SRC = r"""
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.parallel.pipeline import pipeline, pipeline_stages
 
 S = 8            # stages

@@ -301,7 +301,6 @@ def test_device_segment_rates_match_numpy_oracle(seed, n_problems,
     """The device (JAX) batched segment solver matches the numpy
     ``segment_rates_many`` oracle to <= 1e-6 relative, with and
     without per-segment loss/DCQCN factors."""
-    pytest.importorskip("jax")
     from _segment_props import run_segment_rates_parity_case
     run_segment_rates_parity_case(seed, n_problems=n_problems,
                                   with_loss=with_loss)

@@ -8,15 +8,11 @@ token regression.
 import math
 
 import numpy as np
-import pytest
 
 from repro.core import fattree
 from repro.core.engine import make_engine
 from repro.core.flowsim import static_maxmin, static_maxmin_loops
-from repro.core.flowsim_jax import HAS_JAX
 from repro.core.workload import GroupOp, MemberEvent
-
-needs_jax = pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
 
 
 # ------------------------------------------------- vectorized filling
@@ -79,13 +75,11 @@ def test_lone_dynamic_op_scenarios_numpy():
                              scenarios=True)
 
 
-@needs_jax
 def test_batched_matches_legacy_jax():
     from _segment_props import run_engine_timeline_case
     run_engine_timeline_case(0, n_ops=3, engine="flow")
 
 
-@needs_jax
 def test_segment_rates_many_parity():
     from _segment_props import run_segment_rates_parity_case
     for seed in range(4):
@@ -111,7 +105,6 @@ def test_zero_event_bit_identity_numpy():
         _static_records("flow-np", "legacy")
 
 
-@needs_jax
 def test_zero_event_bit_identity_jax():
     assert _static_records("flow", "batched") == \
         _static_records("flow", "legacy")

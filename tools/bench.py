@@ -8,14 +8,18 @@ paths, each in its OWN subprocess so neither warms the other's
 topology/routing/jit caches:
 
 - **before** — the PR-1 solver discipline: one engine + one solve per
-  scenario, shape bucketing off, fresh topology per scenario, no
-  persistent compilation cache (PR-1 recompiled every process);
+  scenario, shape bucketing off, fresh topology per scenario, an
+  emptied persistent compilation cache (PR-1 recompiled every process);
 - **after**  — the stage-then-batch path: the whole sweep staged on one
   engine, solved by a single ``run_many`` (shape-bucketed, vmapped
   epoch batches), persistent compilation cache on.  Measured twice:
-  a cold process with an empty cache directory, then a second fresh
+  a cold process with an emptied cache directory, then a second fresh
   process against the now-warm directory (the steady state every run
   after the first sees).
+
+The cache directory is the program's own (``flowsim_jax.
+compile_cache_dir()``: ``JAX_COMPILATION_CACHE_DIR`` when set, else
+``.jax_cache/`` at the checkout's root); a cold measurement empties it.
 
 It also records a **dyn-segments** point (the ISSUE-10 churn-under-
 loss sweep — 64 dynamic ops cut into 320 piecewise segments on a
@@ -85,13 +89,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "src"))
 sys.path.insert(0, REPO)
 
-DEFAULT_SCALES = (8, 16, 32)
+from repro.core.flowsim_jax import compile_cache_dir  # noqa: E402
 
-# the 'before' baselines must really run without a persistent
-# compilation cache, even when the surrounding shell (e.g. CI) exports
-# one — PR-1 recompiled every process
-_JAX_CACHE_VARS = ("JAX_COMPILATION_CACHE_DIR",
-                   "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS")
+DEFAULT_SCALES = (8, 16, 32)
 
 # packet bench workloads: fig15 points (group, loss).  The 512-host
 # point is the headline (feedback aggregation scales with group size);
@@ -427,10 +427,8 @@ def _child_packet(kind: str, spec: dict) -> int:
 
 # ---------------------------------------------------- parent orchestration
 
-def _run_child(kind: str, env_extra: dict, *, scales=None,
-               spec: dict = None) -> dict:
-    env = dict(os.environ, **env_extra)
-    env = {k: v for k, v in env.items() if v != ""}   # "" = unset
+def _run_child(kind: str, *, scales=None, spec: dict = None) -> dict:
+    env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     argv = [sys.executable, os.path.abspath(__file__), "--_child", kind]
@@ -441,6 +439,12 @@ def _run_child(kind: str, env_extra: dict, *, scales=None,
     out = subprocess.run(argv, capture_output=True, text=True, env=env,
                          cwd=REPO, check=True)
     return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _empty_compile_cache() -> None:
+    """Empty the persistent compilation cache so the next child
+    compiles cold."""
+    shutil.rmtree(compile_cache_dir(), ignore_errors=True)
 
 
 def _git_ref_tree(ref: str) -> str:
@@ -466,10 +470,11 @@ def _run_git_ref_flow(ref: str, scales) -> dict:
         "print('sweep done in %.4fs' % (time.perf_counter() - t0))\n")
     try:
         walls = []
-        env = dict(os.environ, REPRO_JAX_CACHE="0")
-        for k in ("PYTHONPATH", *_JAX_CACHE_VARS):
-            env.pop(k, None)
+        # the ref tree compiles cold into this checkout's cache directory
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=compile_cache_dir())
+        env.pop("PYTHONPATH", None)
         for _ in range(2):
+            _empty_compile_cache()
             out = subprocess.run([sys.executable, "-c", driver],
                                  capture_output=True, text=True,
                                  env=env, cwd=tmp, check=True)
@@ -519,54 +524,46 @@ def _main_flow(args, result: dict) -> None:
         if args.scales else ((8,) if args.smoke else DEFAULT_SCALES)
     result["workload"] = {"figure": "fig14", "engine": "flow",
                           "scales": list(scales), "smoke": args.smoke}
-    cache_dir = tempfile.mkdtemp(prefix="bench-jax-cache-")
-    try:
-        if not args.smoke:
-            # before: PR-1 solver discipline, no persistent cache
-            no_cache = {"REPRO_JAX_CACHE": "0",
-                        **{k: "" for k in _JAX_CACHE_VARS}}
-            result["before"] = _run_child("serial", no_cache,
-                                          scales=scales)
-            if args.before_git:
-                result["before_git"] = _run_git_ref_flow(args.before_git,
-                                                         scales)
-        # after, cold: fresh process + empty compilation-cache dir
-        cache_env = {"JAX_COMPILATION_CACHE_DIR": cache_dir}
-        result["after_cold"] = _run_child("batched", cache_env,
-                                          scales=scales)
-        # after, steady state: fresh process, warm cache dir
-        result["after_warm"] = _run_child("batched", cache_env,
-                                          scales=scales)
-        # loss-sweep point: fig15 on the flow engine (loss-aware solver)
-        result["loss_sweep"] = _run_child("flow-loss", cache_env,
-                                          spec={"smoke": args.smoke})
-        # app-plane point: fig_apps lowering + phase-split execution
-        result["apps_sweep"] = _run_child("flow-apps", cache_env,
-                                          spec={"smoke": args.smoke})
-        # fleet-scale headline: 16k hosts x 1k groups, cold vs warm
-        # staging cache (CI-sized in smoke)
-        result["fleet_scale"] = _run_child("flow-fleet", cache_env,
-                                           spec={"smoke": args.smoke})
-        # dyn-segments point: churn-under-loss piecewise segments,
-        # batched device solver vs the legacy per-segment closures
-        dyn = {mode: _run_child("flow-dyn", cache_env,
-                                spec={"smoke": args.smoke, "mode": mode})
-               for mode in ("legacy", "batched")}
-        dyn["speedup_cold"] = round(dyn["legacy"]["pass1_wall_s"]
-                                    / dyn["batched"]["pass1_wall_s"], 2)
-        dyn["speedup_steady"] = round(dyn["legacy"]["pass2_wall_s"]
-                                      / dyn["batched"]["pass2_wall_s"], 2)
-        # zero-loss JCT-match tripwire: both modes solve the same
-        # per-segment problems there, so they must agree to 1e-6
-        rel = max((abs(a - b) / abs(b) for a, b in
-                   zip(dyn["legacy"]["jcts0"], dyn["batched"]["jcts0"])),
-                  default=0.0)
-        dyn["jct0_max_rel_diff"] = rel
-        assert rel <= 1e-6, \
-            f"dyn_segments modes diverge on zero-loss JCTs: {rel:g}"
-        result["dyn_segments"] = dyn
-    finally:
-        shutil.rmtree(cache_dir, ignore_errors=True)
+    if not args.smoke:
+        # before: PR-1 solver discipline, cold compilation cache
+        _empty_compile_cache()
+        result["before"] = _run_child("serial", scales=scales)
+        if args.before_git:
+            result["before_git"] = _run_git_ref_flow(args.before_git,
+                                                     scales)
+    # after, cold: fresh process + empty compilation-cache dir
+    _empty_compile_cache()
+    result["after_cold"] = _run_child("batched", scales=scales)
+    # after, steady state: fresh process, warm cache dir
+    result["after_warm"] = _run_child("batched", scales=scales)
+    # loss-sweep point: fig15 on the flow engine (loss-aware solver)
+    result["loss_sweep"] = _run_child("flow-loss",
+                                      spec={"smoke": args.smoke})
+    # app-plane point: fig_apps lowering + phase-split execution
+    result["apps_sweep"] = _run_child("flow-apps",
+                                      spec={"smoke": args.smoke})
+    # fleet-scale headline: 16k hosts x 1k groups, cold vs warm
+    # staging cache (CI-sized in smoke)
+    result["fleet_scale"] = _run_child("flow-fleet",
+                                       spec={"smoke": args.smoke})
+    # dyn-segments point: churn-under-loss piecewise segments,
+    # batched device solver vs the legacy per-segment closures
+    dyn = {mode: _run_child("flow-dyn",
+                            spec={"smoke": args.smoke, "mode": mode})
+           for mode in ("legacy", "batched")}
+    dyn["speedup_cold"] = round(dyn["legacy"]["pass1_wall_s"]
+                                / dyn["batched"]["pass1_wall_s"], 2)
+    dyn["speedup_steady"] = round(dyn["legacy"]["pass2_wall_s"]
+                                  / dyn["batched"]["pass2_wall_s"], 2)
+    # zero-loss JCT-match tripwire: both modes solve the same
+    # per-segment problems there, so they must agree to 1e-6
+    rel = max((abs(a - b) / abs(b) for a, b in
+               zip(dyn["legacy"]["jcts0"], dyn["batched"]["jcts0"])),
+              default=0.0)
+    dyn["jct0_max_rel_diff"] = rel
+    assert rel <= 1e-6, \
+        f"dyn_segments modes diverge on zero-loss JCTs: {rel:g}"
+    result["dyn_segments"] = dyn
 
     if "before" in result:
         b = result["before"]["pass1"]["wall_s"]
@@ -620,11 +617,10 @@ def _main_packet(args, result: dict) -> None:
                   "seeds": seeds}}
 
     result["single"] = [
-        _run_child("packet-single", {},
-                   spec={"group": g, "loss": l})
+        _run_child("packet-single", spec={"group": g, "loss": l})
         for g, l in points]
     result["sweep_serial"] = _run_child(
-        "packet-sweep", {},
+        "packet-sweep",
         spec={"points": sweep_points, "seeds": seeds, "workers": 1})
     # the parallel-vs-serial comparison is only meaningful with real
     # parallelism; record the cpu count it ran with either way so the
@@ -638,8 +634,7 @@ def _main_packet(args, result: dict) -> None:
             "(a one-worker pool would re-measure the serial path)")
     else:
         result["sweep_parallel"] = _run_child(
-            "packet-sweep", {},
-            spec={"points": sweep_points, "seeds": seeds,
+            "packet-sweep", spec={"points": sweep_points, "seeds": seeds,
                   "workers": ncpu})
         # determinism tripwire: the serial and parallel sweeps must
         # agree exactly, record for record
@@ -678,7 +673,7 @@ def _main_packet(args, result: dict) -> None:
     # fault-sweep point: the ISSUE-7 recovery axis (benchmarks/
     # fig_faults.py) — every fault class must end in measured recovery
     result["fault_sweep"] = _run_child(
-        "packet-faults", {}, spec={"group": 4 if args.smoke else 8})
+        "packet-faults", spec={"group": 4 if args.smoke else 8})
 
     if args.smoke:       # regression tripwires for CI
         assert result["single"][0]["passes"][0]["events"] > 0

@@ -96,10 +96,7 @@ def check_oracle(problems):
     on random duplicate-free problems (with and without loss params)."""
     from benchmarks import fig_matrix as fm
     from repro.core.flowsim import FlowSim, LossParams
-    from repro.core.flowsim_jax import HAS_JAX, JaxFlowSim
-    if not HAS_JAX:
-        print("check_matrix: oracle: jax unavailable, skipped")
-        return
+    from repro.core.flowsim_jax import JaxFlowSim
     topo = fm.build_topo(smoke=True)
     np_sim, jx_sim = FlowSim(topo), JaxFlowSim(topo)
     rng = np.random.default_rng(0)
