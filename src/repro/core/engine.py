@@ -1121,18 +1121,20 @@ class FlowEngine(_WorkloadStaging):
         cache = self._sim.cache.sync()
         ent = cache.ops.get(okey) if okey is not None else None
         if ent is None:
-            links = self._sim.multicast_tree_links(source, members, key)
-            seg = wire_bytes(min(nbytes, pk.MTU))
-            deliver, back = {}, 0.0
-            for m in members:
-                if m == source:
-                    continue
-                lat, prop = self._path_latency(source, m, seg, key)
-                deliver[m] = lat
-                back = max(back, prop)
-            loss = self._loss_params(links, nbytes=nbytes, rtt=2.0 * back,
-                                     tuning=self.group_kw, op=op)
-            ent = (links, deliver, back, loss)
+            with self._sim.span("flow.derive"):
+                links = self._sim.multicast_tree_links(source, members, key)
+                seg = wire_bytes(min(nbytes, pk.MTU))
+                deliver, back = {}, 0.0
+                for m in members:
+                    if m == source:
+                        continue
+                    lat, prop = self._path_latency(source, m, seg, key)
+                    deliver[m] = lat
+                    back = max(back, prop)
+                loss = self._loss_params(links, nbytes=nbytes,
+                                         rtt=2.0 * back,
+                                         tuning=self.group_kw, op=op)
+                ent = (links, deliver, back, loss)
             if okey is not None:
                 cache.ops[okey] = ent
         else:
@@ -1144,7 +1146,9 @@ class FlowEngine(_WorkloadStaging):
 
     def _stage_native(self, op: GroupOp) -> MsgRecord:
         if op.events or op.faults:
-            return self._stage_dynamic(op)
+            # no op-level layout cache: every call derives its segments
+            with self._sim.span("flow.derive"):
+                return self._stage_dynamic(op)
         volume = float(wire_bytes(op.nbytes))
         if op.op == "write" and not op.same_mr:
             # §3.3: the MR_UPDATE preamble rides the same tree
@@ -1486,22 +1490,25 @@ class FlowEngine(_WorkloadStaging):
         cache = self._sim.cache.sync()
         ent = cache.ops.get(okey) if okey is not None else None
         if ent is None:
-            plan = relay_plan(transport, members)
-            chunks = op.chunks if transport.chunked else 1
-            chunk = op.nbytes if not transport.chunked else \
-                max(1, math.ceil(op.nbytes / chunks))
-            seg = wire_bytes(min(chunk, pk.MTU))
-            rows = []
-            for parent, child, hops in plan:
-                links = self._sim.unicast_links(parent, child, op.key)
-                lat, prop = self._path_latency(parent, child, seg, op.key)
-                # the op completes at the MAX over its relay flows
-                loss = self._loss_params(links, nbytes=chunk,
-                                         rtt=2.0 * prop,
-                                         tuning=self.relay_kw, op=op,
-                                         parallel=len(plan))
-                rows.append((child, links, {child: lat}, lat, prop, loss))
-            ent = (plan, rows, chunks, chunk, seg)
+            with self._sim.span("flow.derive"):
+                plan = relay_plan(transport, members)
+                chunks = op.chunks if transport.chunked else 1
+                chunk = op.nbytes if not transport.chunked else \
+                    max(1, math.ceil(op.nbytes / chunks))
+                seg = wire_bytes(min(chunk, pk.MTU))
+                rows = []
+                for parent, child, hops in plan:
+                    links = self._sim.unicast_links(parent, child, op.key)
+                    lat, prop = self._path_latency(parent, child, seg,
+                                                   op.key)
+                    # the op completes at the MAX over its relay flows
+                    loss = self._loss_params(links, nbytes=chunk,
+                                             rtt=2.0 * prop,
+                                             tuning=self.relay_kw, op=op,
+                                             parallel=len(plan))
+                    rows.append((child, links, {child: lat}, lat, prop,
+                                 loss))
+                ent = (plan, rows, chunks, chunk, seg)
             if okey is not None:
                 cache.ops[okey] = ent
         else:
@@ -1625,19 +1632,27 @@ class FlowEngine(_WorkloadStaging):
         finalizer (reduce and bcast flows solve concurrently — they
         occupy opposite link directions on duplex fabrics, so each
         phase sees its standalone rate — and the bcast timeline is
-        shifted by the reduce completion)."""
+        shifted by the reduce completion).  The reduce phase has no
+        op-level layout cache, so its per-member paths, latencies and
+        loss parameters count as derived on every call; the bcast
+        phase derives, on a miss, in its own lowering."""
         members = op.ordered_members()
         root = members[0]
         rec = self._new_rec(op.nbytes)
         seg = wire_bytes(min(op.nbytes, pk.MTU))
+        with self._sim.span("flow.derive"):
+            fanin = []
+            for m in members[1:]:
+                links = self._sim.unicast_links(m, root, op.key)
+                lat, prop = self._path_latency(m, root, seg, op.key)
+                loss = self._loss_params(links, nbytes=op.nbytes,
+                                         rtt=2.0 * prop,
+                                         tuning=self.relay_kw, op=op,
+                                         parallel=len(members) - 1)
+                fanin.append((links, lat, loss))
         red = []
-        for m in members[1:]:
-            links = self._sim.unicast_links(m, root, op.key)
-            lat, prop = self._path_latency(m, root, seg, op.key)
+        for links, lat, loss in fanin:
             hidden = self._new_rec(op.nbytes)
-            loss = self._loss_params(links, nbytes=op.nbytes,
-                                     rtt=2.0 * prop, tuning=self.relay_kw,
-                                     op=op, parallel=len(members) - 1)
             self._stage(links, float(wire_bytes(op.nbytes)), hidden,
                         {root: lat}, 0.0, loss)
             red.append(hidden)
@@ -1666,12 +1681,14 @@ class FlowEngine(_WorkloadStaging):
         cache = self._sim.cache.sync()
         ent = cache.ops.get(okey) if okey is not None else None
         if ent is None:
-            links = self._sim.unicast_links(src, dst, key)
-            seg = wire_bytes(min(nbytes, pk.MTU))
-            lat, prop = self._path_latency(src, dst, seg, key)
-            loss = self._loss_params(links, nbytes=nbytes, rtt=2.0 * prop,
-                                     tuning=self.relay_kw)
-            ent = (links, {dst: lat}, prop, loss)
+            with self._sim.span("flow.derive"):
+                links = self._sim.unicast_links(src, dst, key)
+                seg = wire_bytes(min(nbytes, pk.MTU))
+                lat, prop = self._path_latency(src, dst, seg, key)
+                loss = self._loss_params(links, nbytes=nbytes,
+                                         rtt=2.0 * prop,
+                                         tuning=self.relay_kw)
+                ent = (links, {dst: lat}, prop, loss)
             if okey is not None:
                 cache.ops[okey] = ent
         else:
@@ -1736,22 +1753,21 @@ class FlowEngine(_WorkloadStaging):
         cache = self._sim.cache.sync()
         if cache.paths:
             return
-        pairs: set = set()
-        lats: set = set()
-        for wl in workloads:
-            for op in wl.ops:
-                if op.events or op.faults:
-                    continue
-                self._op_pairs(op, pairs, lats)
-        self._sim.warm_paths(sorted(pairs))
-        self._sim.warm_latencies(sorted(lats))
+        with self._sim.span("flow.warm"):
+            pairs: set = set()
+            lats: set = set()
+            for wl in workloads:
+                for op in wl.ops:
+                    if op.events or op.faults:
+                        continue
+                    self._op_pairs(op, pairs, lats)
+            self._sim.warm_paths(sorted(pairs))
+            self._sim.warm_latencies(sorted(lats))
 
     def run_workloads(self, workloads: Sequence[Workload],
                       timeout: float = 30.0,
                       workers: Optional[int] = None
                       ) -> List[List[MsgRecord]]:
-        if self.staging_cache:
-            self._warm_workloads(workloads)
         out: List[List[MsgRecord]] = [[] for _ in workloads]
         fast_ok = self.staging_cache and self._cfg_key is not None
 
@@ -1787,9 +1803,12 @@ class FlowEngine(_WorkloadStaging):
                     recs.append(rec)
             return fn
 
-        self.run_many([scenario(wl, recs)
-                       for wl, recs in zip(workloads, out)], timeout,
-                      workers=workers)
+        with self._sim.span("flow.run_workloads"):
+            if self.staging_cache:
+                self._warm_workloads(workloads)
+            self.run_many([scenario(wl, recs)
+                           for wl, recs in zip(workloads, out)], timeout,
+                          workers=workers)
         return out
 
     # ------------------------------------------------- dynamic segments
@@ -1822,60 +1841,64 @@ class FlowEngine(_WorkloadStaging):
         """
         if self.segment_solver != "batched":
             return
+        dyn = [[e[6] for e in staged if e[6] is not None]
+               for staged in scenarios]
+        if not any(dyn):
+            return
         sim = self._sim
         cap = sim.cap
-        probs: List[tuple] = []          # unique (link_sets, loss)
-        keys: Dict[tuple, int] = {}      # problem key -> probs index
-        fills: List[tuple] = []          # (fairs, seg_idx, probs_idx, key)
-        memo = sim.cache.sync().misc.setdefault("segrates", {})
-        for staged in scenarios:
-            tokens = [e[6] for e in staged if e[6] is not None]
-            for token in tokens:
-                timeline = self._dyn_links[token]
-                cap0, lp = self._dyn_meta[token]
-                fairs = [0.0] * len(timeline)
-                self._seg_fair[token] = fairs
-                for k, (t_k, links_k) in enumerate(timeline):
-                    if not links_k:     # no receivers left
-                        fairs[k] = cap0
-                        continue
-                    others = []
-                    for entry in staged:
-                        o_links, o_dyn = entry[0], entry[6]
-                        if o_dyn == token:
+        with sim.span("flow.segments"):
+            probs: List[tuple] = []          # unique (link_sets, loss)
+            keys: Dict[tuple, int] = {}      # problem key -> probs index
+            fills: List[tuple] = []          # (fairs, seg_idx, probs_idx, key)
+            memo = sim.cache.sync().misc.setdefault("segrates", {})
+            for staged, tokens in zip(scenarios, dyn):
+                for token in tokens:
+                    timeline = self._dyn_links[token]
+                    cap0, lp = self._dyn_meta[token]
+                    fairs = [0.0] * len(timeline)
+                    self._seg_fair[token] = fairs
+                    for k, (t_k, links_k) in enumerate(timeline):
+                        if not links_k:     # no receivers left
+                            fairs[k] = cap0
                             continue
-                        tl = self._dyn_links.get(o_dyn) \
-                            if o_dyn is not None else None
-                        if tl is not None:
-                            for at, ls in tl:
-                                if at <= t_k:
-                                    o_links = ls
-                                else:
-                                    break
-                        if o_links:
-                            others.append(o_links)
-                    if not others:      # scenario-lone: exact mincap
-                        fairs[k] = float(min(cap[i] for i in links_k))
-                        continue
-                    sets = tuple(others) + (tuple(links_k),)
-                    key = (sets, lp)
-                    val = memo.get(key)
-                    if val is not None:
-                        fairs[k] = val
-                        continue
-                    pi = keys.get(key)
-                    if pi is None:
-                        pi = keys[key] = len(probs)
-                        probs.append((sets, lp))
-                    fills.append((fairs, k, pi, key))
-        if not probs:
-            return
-        vals = sim.segment_rates_many(probs)
-        bound = len(memo) < staging.MAX_ENTRIES
-        for fairs, k, pi, key in fills:
-            fairs[k] = vals[pi]
-            if bound:
-                memo[key] = vals[pi]
+                        others = []
+                        for entry in staged:
+                            o_links, o_dyn = entry[0], entry[6]
+                            if o_dyn == token:
+                                continue
+                            tl = self._dyn_links.get(o_dyn) \
+                                if o_dyn is not None else None
+                            if tl is not None:
+                                for at, ls in tl:
+                                    if at <= t_k:
+                                        o_links = ls
+                                    else:
+                                        break
+                            if o_links:
+                                others.append(o_links)
+                        if not others:      # scenario-lone: exact mincap
+                            fairs[k] = float(min(cap[i] for i in links_k))
+                            continue
+                        sets = tuple(others) + (tuple(links_k),)
+                        key = (sets, lp)
+                        val = memo.get(key)
+                        if val is not None:
+                            fairs[k] = val
+                            continue
+                        pi = keys.get(key)
+                        if pi is None:
+                            pi = keys[key] = len(probs)
+                            probs.append((sets, lp))
+                        fills.append((fairs, k, pi, key))
+            if not probs:
+                return
+            vals = sim.segment_rates_many(probs)
+            bound = len(memo) < staging.MAX_ENTRIES
+            for fairs, k, pi, key in fills:
+                fairs[k] = vals[pi]
+                if bound:
+                    memo[key] = vals[pi]
 
     def _clear_dynamics(self) -> None:
         self._dyn_links.clear()
@@ -1913,14 +1936,16 @@ class FlowEngine(_WorkloadStaging):
         if not self._staged and not self._post:
             return self.now
         sim = self._sim                          # reuse routing + caps
-        sim.flows, sim.now = [], 0.0             # fresh batch, epoch-local t
-        flows = sim.add_many((links, volume, loss)
-                             for links, volume, _, _, _, loss, _
-                             in self._staged)
+        with sim.span("flow.flows"):
+            sim.flows, sim.now = [], 0.0         # fresh batch, epoch-local t
+            flows = sim.add_many((links, volume, loss)
+                                 for links, volume, _, _, _, loss, _
+                                 in self._staged)
         sim.run()
         self._solve_segments([self._staged])
-        self.now = max(self.now, self._finalize(self._staged, self._post,
-                                                flows, self.now))
+        with sim.span("flow.fill"):
+            end = self._finalize(self._staged, self._post, flows, self.now)
+        self.now = max(self.now, end)
         self._staged, self._post = [], []
         self._clear_dynamics()
         return self.now
@@ -1941,15 +1966,17 @@ class FlowEngine(_WorkloadStaging):
         sim = self._sim
         t0 = self.now
         metas = []
-        for stage in scenarios:
-            stage(self)
-            metas.append((self._staged, self._post))
-            self._staged, self._post = [], []
-        sim.flows, sim.now = [], 0.0
-        epoch_flows = [sim.add_many((links, volume, loss)
-                                    for links, volume, _, _, _, loss, _
-                                    in staged)
-                       for staged, _ in metas]
+        with sim.span("flow.stage"):
+            for stage in scenarios:
+                stage(self)
+                metas.append((self._staged, self._post))
+                self._staged, self._post = [], []
+        with sim.span("flow.flows"):
+            sim.flows, sim.now = [], 0.0
+            epoch_flows = [sim.add_many((links, volume, loss)
+                                        for links, volume, _, _, _, loss, _
+                                        in staged)
+                           for staged, _ in metas]
         if hasattr(sim, "solve_many"):           # vmapped batch (JAX)
             sim.solve_many(epoch_flows)
         else:                                    # numpy: epoch-serial
@@ -1957,8 +1984,9 @@ class FlowEngine(_WorkloadStaging):
                 sim.flows, sim.now = flows, 0.0
                 sim.run()
         self._solve_segments([staged for staged, _ in metas])
-        ends = [self._finalize(staged, post, flows, t0)
-                for (staged, post), flows in zip(metas, epoch_flows)]
+        with sim.span("flow.fill"):
+            ends = [self._finalize(staged, post, flows, t0)
+                    for (staged, post), flows in zip(metas, epoch_flows)]
         self.now = max([self.now] + ends)
         self._clear_dynamics()
         return ends

@@ -27,6 +27,7 @@ its Gleam counterpart contend on identical fabric paths.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -81,6 +82,13 @@ class LinkMap:
     Link ``i`` is the directed (node, port) egress; ``cap[i]`` is its
     bandwidth in bytes/s and ``delay[i]`` its propagation delay.
     """
+
+    @staticmethod
+    def span(name: str):
+        """Host span named ``name`` around one phase of the flow engine
+        (``FlowEngine`` opens them); the numpy solver records none, the
+        JAX solver's land in the profiler's trace."""
+        return contextlib.nullcontext()
 
     def __init__(self, topo: Topology, shared_cache: bool = True):
         from repro.core.staging import StagingCache

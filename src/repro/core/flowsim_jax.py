@@ -61,7 +61,7 @@ from jax import lax
 
 from repro.core.fattree import Topology
 from repro.core.flowsim import DCQCN_MIN_RATE, DCQCN_RATE_NUM, Flow, LinkMap
-from repro.kernels.maxmin import loss_factors, maxmin_rates
+from repro.kernels.maxmin import loss_factors, maxmin_fill, maxmin_rates
 
 #: volumes above this lose integer precision in float32 (2^24 bytes)
 F32_SAFE_MAX = float(1 << 24)
@@ -74,9 +74,28 @@ MAX_BATCH_BYTES = 64 << 20
 #: next to a 64-flow multicast epoch would cost ~50x per round)
 MAX_PAD_WASTE = 4.0
 
-#: device-time telemetry, accumulated by every solve; ``tools/bench.py``
-#: reads it to split python staging from on-device solver time
-SOLVE_STATS = {"solve_s": 0.0, "calls": 0, "shapes": []}
+#: solver telemetry, accumulated by every solve until
+#: ``reset_solve_stats``:
+#:
+#: - ``solve_s``: host seconds around each solve's transfer to the
+#:   device, the call and the copy of its results back (a host clock,
+#:   not device time: the device's own time is in a profiler trace);
+#: - ``calls``: solver calls (epoch and dynamic-segment);
+#: - ``shapes``: the set of distinct padded link-id matrix shapes
+#:   solved;
+#: - ``lanes``: epochs solved by the epoch solver (one lane of a
+#:   batched call each, or one unbatched ``run``);
+#: - ``epochs``: every lane's fluid epochs (event-loop iterations);
+#: - ``rounds``: every lane's max-min filling rounds, as each lane
+#:   needed them;
+#: - ``lane_rounds_run``: filling rounds the device executed, times the
+#:   lanes of the call: a vmapped loop runs every lane until the
+#:   slowest is done, so ``rounds / lane_rounds_run`` is the share of
+#:   that work some lane needed.
+#:
+#: The four counters cover the epoch solver only.
+SOLVE_STATS = {"solve_s": 0.0, "calls": 0, "shapes": set(), "lanes": 0,
+               "epochs": 0, "rounds": 0, "lane_rounds_run": 0}
 _STATS_LOCK = threading.Lock()
 
 #: dynamic-segment solves mirror the numpy ``flowsim.static_maxmin``
@@ -88,7 +107,8 @@ SEG_ROUNDS = 64
 
 
 def reset_solve_stats():
-    SOLVE_STATS.update(solve_s=0.0, calls=0, shapes=[])
+    SOLVE_STATS.update(solve_s=0.0, calls=0, shapes=set(), lanes=0,
+                       epochs=0, rounds=0, lane_rounds_run=0)
 
 
 #: the persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
@@ -125,8 +145,25 @@ def _bucket(n: int, lo: int) -> int:
     return max(lo, 1 << max(int(n) - 1, 0).bit_length())
 
 
-def _simulate(flow_links, cap, vol, loss=None, warm=True):
-    """Fluid event loop: completion times (F,) for every flow.
+#: the vmap axis of the batched epoch solver's lanes
+LANES = "lanes"
+
+
+def _simulate(flow_links, cap, vol, loss=None, warm=True,
+              axis_name=None):
+    """Fluid event loop: completion times (F,) for every flow, with the
+    loop's ``COUNTS`` appended, ``[epochs, rounds, rounds_run]`` in the
+    same dtype: one (F + 3,) array, so the counts reach the host in
+    ``done``'s own transfer (exact below 2^24 in float32); ``_split``
+    takes them apart.
+
+    ``epochs`` is the event-loop iterations and ``rounds`` the max-min
+    filling rounds this problem needed.  ``rounds_run`` is the filling
+    rounds the device executed while this problem's loop ran: under
+    ``jax.vmap`` with ``axis_name`` naming the vmap axis, each epoch runs
+    as many rounds as its slowest lane (``lax.pmax``), and the lane
+    with the most epochs runs in every iteration, so the largest
+    ``rounds_run`` of a batch is exact for the whole call.
 
     ``warm`` compiles in the completion-epoch warm start: when an
     epoch's completed flows are link-disjoint from every survivor,
@@ -150,46 +187,58 @@ def _simulate(flow_links, cap, vol, loss=None, warm=True):
     eps = vol * 1e-6 + 1.0                  # completion slack (bytes)
 
     def cond(st):
-        _, rem, _, _, _, it = st
+        _, rem, _, _, _, it, _, _ = st
         return jnp.logical_and(jnp.any(rem > 0.0), it <= n_flows)
 
     def body(st):
-        t, rem, done, rates, dirty, it = st
-        active = rem > 0.0
-        if warm:
-            rates = lax.cond(
-                dirty,
-                lambda r: maxmin_rates(flow_links, cap, active),
-                lambda r: r, rates)
-        else:
-            rates = maxmin_rates(flow_links, cap, active)
-        eff = rates
-        if loss is not None:
-            eff = rates * loss_factors(
-                flow_links, rates, active.astype(cap.dtype), cap, *loss,
-                dcqcn_num=DCQCN_RATE_NUM, dcqcn_min=DCQCN_MIN_RATE)
-        dt = jnp.min(jnp.where(active, rem / eff, jnp.inf))
-        t = t + dt
-        rem = jnp.where(active, rem - eff * dt, 0.0)
-        fin = active & (rem <= eps)
-        done = jnp.where(fin, t, done)
-        rem = jnp.where(fin, 0.0, rem)
-        if warm:
-            touched = jnp.zeros(n_caps, cap.dtype).at[flow_links].add(
-                jnp.broadcast_to(fin.astype(cap.dtype)[:, None],
-                                 flow_links.shape))
-            touched = touched.at[-1].set(0.0)   # sentinel: no contention
-            survive = active & ~fin
-            dirty = jnp.any(
-                survive & (jnp.max(touched[flow_links], axis=1) > 0.0))
-        return t, rem, done, rates, dirty, it + 1
+        t, rem, done, rates, dirty, it, rounds, ran = st
+        with jax.named_scope("epoch"):
+            active = rem > 0.0
+            if warm:
+                rates, n = lax.cond(
+                    dirty,
+                    lambda r: maxmin_fill(flow_links, cap, active),
+                    lambda r: (r, jnp.int32(0)), rates)
+            else:
+                rates, n = maxmin_fill(flow_links, cap, active)
+            eff = rates
+            if loss is not None:
+                eff = rates * loss_factors(
+                    flow_links, rates, active.astype(cap.dtype), cap, *loss,
+                    dcqcn_num=DCQCN_RATE_NUM, dcqcn_min=DCQCN_MIN_RATE)
+            dt = jnp.min(jnp.where(active, rem / eff, jnp.inf))
+            t = t + dt
+            rem = jnp.where(active, rem - eff * dt, 0.0)
+            fin = active & (rem <= eps)
+            done = jnp.where(fin, t, done)
+            rem = jnp.where(fin, 0.0, rem)
+            if warm:
+                touched = jnp.zeros(n_caps, cap.dtype).at[flow_links].add(
+                    jnp.broadcast_to(fin.astype(cap.dtype)[:, None],
+                                     flow_links.shape))
+                touched = touched.at[-1].set(0.0)   # sentinel: no contention
+                survive = active & ~fin
+                dirty = jnp.any(
+                    survive & (jnp.max(touched[flow_links], axis=1) > 0.0))
+        ran_n = n if axis_name is None else lax.pmax(n, axis_name)
+        return t, rem, done, rates, dirty, it + 1, rounds + n, ran + ran_n
 
     zero = jnp.asarray(0.0, cap.dtype)
     init = (zero, vol, jnp.zeros(n_flows, cap.dtype),
             jnp.zeros(n_flows, cap.dtype), jnp.bool_(True),
-            jnp.int32(0))
-    _, _, done, _, _, _ = lax.while_loop(cond, body, init)
-    return done
+            jnp.int32(0), jnp.int32(0), jnp.int32(0))
+    _, _, done, _, _, epochs, rounds, ran = lax.while_loop(cond, body, init)
+    counts = jnp.stack([epochs, rounds, ran]).astype(done.dtype)
+    return jnp.concatenate([done, counts])
+
+
+#: the loop counts ``_simulate`` appends to each completion vector
+COUNTS = 3
+
+
+def _split(out: np.ndarray):
+    """A solve's output as (completion times, int64 counts (..., 3))."""
+    return out[..., :-COUNTS], out[..., -COUNTS:].astype(np.int64)
 
 
 def _solver(batched: bool, lossy: bool = False):
@@ -206,16 +255,16 @@ def _solver(batched: bool, lossy: bool = False):
 
 @functools.lru_cache(maxsize=None)
 def _solver_impl(batched: bool, lossy: bool):
-    """``donate_argnums`` hands the volume buffer back to XLA (a
-    no-op on backends without donation support, e.g. CPU)."""
-    sim = functools.partial(_simulate, warm=not batched)
+    """Every flavor keeps ``_simulate``'s name, so its executable is
+    ``jit__simulate``.  Nothing is donated: the output, three slots
+    longer than the volume buffer, could not reuse it."""
     if batched:
+        sim = functools.partial(_simulate, warm=False, axis_name=LANES)
         fn = jax.vmap(sim, in_axes=(0, None, 0, 0) if lossy
-                      else (0, None, 0))
+                      else (0, None, 0), axis_name=LANES)
     else:
-        fn = sim
-    donate = (2,) if jax.default_backend() not in ("cpu",) else ()
-    return jax.jit(fn, donate_argnums=donate)
+        fn = functools.partial(_simulate, warm=True)
+    return jax.jit(functools.update_wrapper(fn, _simulate))
 
 
 @functools.lru_cache(maxsize=None)
@@ -228,9 +277,10 @@ def _seg_solver():
     ``SEG_TOL``/``SEG_ROUNDS`` regime, applies the loss/DCQCN
     factors (all-zero loss rows give factor exactly 1.0, so one
     always-lossy executable covers lossless problems bit-exactly),
-    and returns the own flow's corrected rate.
+    and returns the own flow's corrected rate.  Its executable is
+    ``jit__segment_rate``.
     """
-    def one(fl, active, own, cap, loss):
+    def _segment_rate(fl, active, own, cap, loss):
         rates = maxmin_rates(fl, cap, active, tol=SEG_TOL,
                              max_rounds=SEG_ROUNDS)
         fac = loss_factors(fl, rates, active, cap, *loss,
@@ -238,8 +288,8 @@ def _seg_solver():
                            dcqcn_min=DCQCN_MIN_RATE)
         return rates[own] * fac[own]
 
-    return jax.jit(jax.vmap(one, in_axes=(0, 0, 0, None,
-                                          (0, 0, 0, 0))))
+    return jax.jit(jax.vmap(_segment_rate, in_axes=(0, 0, 0, None,
+                                                    (0, 0, 0, 0))))
 
 
 class JaxFlowSim(LinkMap):
@@ -256,6 +306,10 @@ class JaxFlowSim(LinkMap):
     bucketing = True
     F_BUCKET_MIN = 16
     H_BUCKET_MIN = 8
+
+    #: host spans land in a running profiler's trace, on the device
+    #: trace's clock (about a microsecond each when none runs)
+    span = staticmethod(jax.profiler.TraceAnnotation)
 
     def __init__(self, topo: Topology, shared_cache: bool = True):
         super().__init__(topo, shared_cache)
@@ -330,7 +384,9 @@ class JaxFlowSim(LinkMap):
 
     def _dispatch(self, batched: bool, fl, cap, vol, dtype,
                   loss=None) -> np.ndarray:
-        """Run the jitted solver (under x64 when promoted), timed.
+        """Run the jitted solver (under x64 when promoted), timed; the
+        completion times, with the loop's counts added to
+        ``SOLVE_STATS``.
 
         The ``jnp.asarray`` conversions MUST happen inside the x64
         scope: without it enabled, float64 inputs silently downcast to
@@ -339,16 +395,25 @@ class JaxFlowSim(LinkMap):
         solve = _solver(batched, loss is not None)
         ctx = jax.enable_x64(True) if dtype == np.float64 \
             else contextlib.nullcontext()
-        t0 = time.perf_counter()
-        with ctx:
-            args = [jnp.asarray(fl), jnp.asarray(cap), jnp.asarray(vol)]
-            if loss is not None:
-                args.append(tuple(jnp.asarray(a) for a in loss))
-            done = np.asarray(solve(*args))
+        with self.span("flow.dispatch"):
+            t0 = time.perf_counter()
+            with ctx:
+                args = [jnp.asarray(fl), jnp.asarray(cap),
+                        jnp.asarray(vol)]
+                if loss is not None:
+                    args.append(tuple(jnp.asarray(a) for a in loss))
+                done, counts = _split(np.asarray(solve(*args)))
+            dt = time.perf_counter() - t0
+        epochs, rounds, ran = counts.reshape(-1, COUNTS).T
+        lanes = len(epochs)
         with _STATS_LOCK:
-            SOLVE_STATS["solve_s"] += time.perf_counter() - t0
+            SOLVE_STATS["solve_s"] += dt
             SOLVE_STATS["calls"] += 1
-            SOLVE_STATS["shapes"].append(tuple(fl.shape))
+            SOLVE_STATS["shapes"].add(tuple(fl.shape))
+            SOLVE_STATS["lanes"] += lanes
+            SOLVE_STATS["epochs"] += int(epochs.sum())
+            SOLVE_STATS["rounds"] += int(rounds.sum())
+            SOLVE_STATS["lane_rounds_run"] += lanes * int(ran.max())
         return done
 
     def _finish(self, flows: Sequence[Flow], done: np.ndarray) -> float:
@@ -377,15 +442,17 @@ class JaxFlowSim(LinkMap):
         if not self.flows:
             return self.now
         flows = self.flows
-        dtype = self._select_dtype(flows)
-        self.solve_dtype = dtype
-        f_pad, h_pad = self._shape(flows)
-        fl, vol = self._pack(flows, dtype, f_pad, h_pad)
-        loss = self._pack_loss(flows, dtype, f_pad) \
-            if any(f.loss is not None for f in flows) else None
-        done = self._dispatch(False, fl, self._cap_ext(dtype), vol, dtype,
-                              loss)
-        self.now = self._finish(flows, done)
+        with self.span("flow.pack"):
+            dtype = self._select_dtype(flows)
+            self.solve_dtype = dtype
+            f_pad, h_pad = self._shape(flows)
+            fl, vol = self._pack(flows, dtype, f_pad, h_pad)
+            loss = self._pack_loss(flows, dtype, f_pad) \
+                if any(f.loss is not None for f in flows) else None
+            cap = self._cap_ext(dtype)
+        done = self._dispatch(False, fl, cap, vol, dtype, loss)
+        with self.span("flow.finish"):
+            self.now = self._finish(flows, done)
         return self.now
 
     # ------------------------------------------------------- batched solve
@@ -428,33 +495,36 @@ class JaxFlowSim(LinkMap):
         completion time; per-flow ``done_t`` is filled in as by
         ``run()``.
         """
-        epochs = [list(ep) for ep in epochs]
-        out = [0.0] * len(epochs)
-        nonempty = [i for i, ep in enumerate(epochs) if ep]
-        if not nonempty:
-            return out
-        vmax = max(max(f.volume for f in epochs[i]) for i in nonempty)
-        dtype = np.float64 if vmax > F32_SAFE_MAX else np.float32
-        self.solve_dtype = dtype
-        cap = self._cap_ext(dtype)
-        shapes = {i: self._shape(epochs[i]) for i in nonempty}
-        batches = self._plan_batches(epochs, nonempty, shapes)
+        with self.span("flow.pack"):
+            epochs = [list(ep) for ep in epochs]
+            out = [0.0] * len(epochs)
+            nonempty = [i for i, ep in enumerate(epochs) if ep]
+            if not nonempty:
+                return out
+            vmax = max(max(f.volume for f in epochs[i]) for i in nonempty)
+            dtype = np.float64 if vmax > F32_SAFE_MAX else np.float32
+            self.solve_dtype = dtype
+            cap = self._cap_ext(dtype)
+            shapes = {i: self._shape(epochs[i]) for i in nonempty}
+            batches = self._plan_batches(epochs, nonempty, shapes)
 
         def solve_batch(batch):
-            f_pad = h_pad = 0
-            for i in batch:
-                f, h = shapes[i]
-                f_pad, h_pad = max(f_pad, f), max(h_pad, h)
-            packed = [self._pack(epochs[i], dtype, f_pad, h_pad)
-                      for i in batch]
-            fl = np.stack([p[0] for p in packed])
-            vol = np.stack([p[1] for p in packed])
-            loss = None
-            if any(f.loss is not None for i in batch for f in epochs[i]):
-                rows = [self._pack_loss(epochs[i], dtype, f_pad)
-                        for i in batch]
-                loss = tuple(np.stack([r[k] for r in rows])
-                             for k in range(4))
+            with self.span("flow.pack"):
+                f_pad = h_pad = 0
+                for i in batch:
+                    f, h = shapes[i]
+                    f_pad, h_pad = max(f_pad, f), max(h_pad, h)
+                packed = [self._pack(epochs[i], dtype, f_pad, h_pad)
+                          for i in batch]
+                fl = np.stack([p[0] for p in packed])
+                vol = np.stack([p[1] for p in packed])
+                loss = None
+                if any(f.loss is not None
+                       for i in batch for f in epochs[i]):
+                    rows = [self._pack_loss(epochs[i], dtype, f_pad)
+                            for i in batch]
+                    loss = tuple(np.stack([r[k] for r in rows])
+                                 for k in range(4))
             return self._dispatch(True, fl, cap, vol, dtype, loss)
 
         # batches solve sequentially: concurrent XLA compiles thrash on
@@ -463,8 +533,9 @@ class JaxFlowSim(LinkMap):
         # repeat-compile cost
         dones = [solve_batch(b) for b in batches]
         for batch, done in zip(batches, dones):
-            for row, i in enumerate(batch):
-                out[i] = self._finish(epochs[i], done[row])
+            with self.span("flow.finish"):
+                for row, i in enumerate(batch):
+                    out[i] = self._finish(epochs[i], done[row])
         self.now = max([self.now] + out)
         return out
 
@@ -523,16 +594,18 @@ class JaxFlowSim(LinkMap):
                 if lp is not None:
                     lrows[r, :, n - 1] = (lp.q, lp.wsq, lp.wnd,
                                           1.0 if lp.ecn else 0.0)
-            t0 = time.perf_counter()
-            with jax.enable_x64(True):
-                vals = np.asarray(solve(
-                    jnp.asarray(fl), jnp.asarray(act), jnp.asarray(own),
-                    jnp.asarray(cap),
-                    tuple(jnp.asarray(lrows[:, k]) for k in range(4))))
+            with self.span("flow.dispatch"):
+                t0 = time.perf_counter()
+                with jax.enable_x64(True):
+                    vals = np.asarray(solve(
+                        jnp.asarray(fl), jnp.asarray(act),
+                        jnp.asarray(own), jnp.asarray(cap),
+                        tuple(jnp.asarray(lrows[:, k]) for k in range(4))))
+                dt = time.perf_counter() - t0
             with _STATS_LOCK:
-                SOLVE_STATS["solve_s"] += time.perf_counter() - t0
+                SOLVE_STATS["solve_s"] += dt
                 SOLVE_STATS["calls"] += 1
-                SOLVE_STATS["shapes"].append(tuple(fl.shape))
+                SOLVE_STATS["shapes"].add(tuple(fl.shape))
             for r, i in enumerate(batch):
                 out[i] = float(vals[r])
         return out

@@ -17,6 +17,7 @@ numpy ``flowsim.FlowSim`` filling is its reference.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -34,31 +35,43 @@ def maxmin_round(flow_links, frozen, rates, cap_rem, *, tol: float = 1e-6):
     """
     n_caps = cap_rem.shape[0]
     dtype = cap_rem.dtype
-    live = 1.0 - frozen
-    # per-link demand: scatter every live flow onto its links
-    cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
-        jnp.broadcast_to(live[:, None], flow_links.shape))
-    share = jnp.where(cnt > 0.0, cap_rem / jnp.maximum(cnt, 1.0), jnp.inf)
-    # each flow's tightest link share (sentinel gathers inf)
-    tightest = jnp.min(share[flow_links], axis=1)
-    limit = jnp.where(frozen > 0.5, jnp.inf, tightest)
-    b = jnp.min(limit)
-    newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
-    newf = newly.astype(dtype)
-    rates = jnp.where(newly, b, rates)
-    used = jnp.zeros(n_caps, dtype).at[flow_links].add(
-        jnp.broadcast_to((newf * b)[:, None], flow_links.shape))
-    cap_rem = jnp.maximum(cap_rem - used, 0.0)
-    return rates, jnp.minimum(frozen + newf, 1.0), cap_rem
+    with jax.named_scope("maxmin_round"):
+        live = 1.0 - frozen
+        # per-link demand: scatter every live flow onto its links
+        cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
+            jnp.broadcast_to(live[:, None], flow_links.shape))
+        share = jnp.where(cnt > 0.0, cap_rem / jnp.maximum(cnt, 1.0),
+                          jnp.inf)
+        # each flow's tightest link share (sentinel gathers inf)
+        tightest = jnp.min(share[flow_links], axis=1)
+        limit = jnp.where(frozen > 0.5, jnp.inf, tightest)
+        b = jnp.min(limit)
+        newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
+        newf = newly.astype(dtype)
+        rates = jnp.where(newly, b, rates)
+        used = jnp.zeros(n_caps, dtype).at[flow_links].add(
+            jnp.broadcast_to((newf * b)[:, None], flow_links.shape))
+        cap_rem = jnp.maximum(cap_rem - used, 0.0)
+        return rates, jnp.minimum(frozen + newf, 1.0), cap_rem
 
 
 def maxmin_rates(flow_links, cap, active, *, tol: float = 1e-6,
                  max_rounds=None):
-    """Max-min fair rates by progressive filling over ``maxmin_round``.
+    """Max-min fair rates by progressive filling over ``maxmin_round``
+    (``maxmin_fill`` without its round count)."""
+    return maxmin_fill(flow_links, cap, active, tol=tol,
+                       max_rounds=max_rounds)[0]
+
+
+def maxmin_fill(flow_links, cap, active, *, tol: float = 1e-6,
+                max_rounds=None):
+    """Max-min fair rates by progressive filling over ``maxmin_round``,
+    and the number of filling rounds it ran.
 
     flow_links (F, H) int32 padded with the sentinel (last) index of
     ``cap``; cap (L+1,) bytes/s with cap[-1] = inf; active (F,) bool.
-    Returns (F,) rates; inactive flows get ~0.  Terminates in at most F
+    Returns ((F,) rates, int32 rounds); inactive flows get ~0, and an
+    all-inactive problem runs 0 rounds.  Terminates in at most F
     rounds (>= 1 flow freezes per round; in practice a handful, since
     whole bottleneck groups freeze together).
 
@@ -84,8 +97,8 @@ def maxmin_rates(flow_links, cap, active, *, tol: float = 1e-6,
 
     init = (jnp.zeros(n_flows, dtype), 1.0 - active.astype(dtype),
             cap, jnp.int32(0))
-    rates, _, _, _ = lax.while_loop(cond, body, init)
-    return jnp.maximum(rates, 1e-9)
+    rates, _, _, rounds = lax.while_loop(cond, body, init)
+    return jnp.maximum(rates, 1e-9), rounds
 
 
 def loss_factors(flow_links, rates, active, cap, q, wsq, wnd, ecn, *,
@@ -119,19 +132,20 @@ def loss_factors(flow_links, rates, active, cap, q, wsq, wnd, ecn, *,
     """
     n_caps = cap.shape[0]
     dtype = cap.dtype
-    # per-link utilization + active-flow count (one scatter each)
-    util = jnp.zeros(n_caps, dtype).at[flow_links].add(
-        jnp.broadcast_to((active * rates)[:, None], flow_links.shape))
-    cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
-        jnp.broadcast_to(active[:, None], flow_links.shape))
-    hot = ((cnt >= 2.0) & (util >= cap * (1.0 - util_eps))).astype(dtype)
-    flow_hot = jnp.max(hot[flow_links], axis=1)
-    # go-back-N: replay window in packets, then steady-state goodput
-    w = jnp.minimum(jnp.sqrt(jnp.maximum(rates * wsq, 0.0)), wnd)
-    gbn = (1.0 - q) / jnp.maximum(1.0 - q + q * w, 1e-30)
-    # DCQCN sawtooth undershoot on ECN-marked (shared, saturated) links
-    alpha = jnp.clip(dcqcn_num / jnp.maximum(rates, 1e-30), 0.0, 1.0)
-    dc = 1.0 - 0.25 * alpha * ecn * flow_hot
-    floor = jnp.minimum(dcqcn_min / jnp.maximum(rates, 1e-30), 1.0)
-    dc = jnp.maximum(dc, floor)
-    return jnp.clip(gbn * dc, 1e-9, 1.0)
+    with jax.named_scope("loss_factors"):
+        # per-link utilization + active-flow count (one scatter each)
+        util = jnp.zeros(n_caps, dtype).at[flow_links].add(
+            jnp.broadcast_to((active * rates)[:, None], flow_links.shape))
+        cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
+            jnp.broadcast_to(active[:, None], flow_links.shape))
+        hot = ((cnt >= 2.0) & (util >= cap * (1.0 - util_eps))).astype(dtype)
+        flow_hot = jnp.max(hot[flow_links], axis=1)
+        # go-back-N: replay window in packets, then steady-state goodput
+        w = jnp.minimum(jnp.sqrt(jnp.maximum(rates * wsq, 0.0)), wnd)
+        gbn = (1.0 - q) / jnp.maximum(1.0 - q + q * w, 1e-30)
+        # DCQCN sawtooth undershoot on ECN-marked (shared, saturated) links
+        alpha = jnp.clip(dcqcn_num / jnp.maximum(rates, 1e-30), 0.0, 1.0)
+        dc = 1.0 - 0.25 * alpha * ecn * flow_hot
+        floor = jnp.minimum(dcqcn_min / jnp.maximum(rates, 1e-30), 1.0)
+        dc = jnp.maximum(dc, floor)
+        return jnp.clip(gbn * dc, 1e-9, 1.0)

@@ -158,7 +158,7 @@ def _timed_sweep(scales, batched: bool, bucketing: bool) -> dict:
         "solve_s": round(stats["solve_s"], 4),
         "python_s": round(wall - stats["solve_s"], 4),
         "solve_calls": stats["calls"],
-        "solve_shapes": [list(s) for s in stats["shapes"]],
+        "solve_shapes": [list(s) for s in sorted(stats["shapes"])],
         "rows": [[n, round(v, 4)] for n, v, _ in rows],
     }
 
