@@ -930,6 +930,7 @@ class FlowEngine(_WorkloadStaging):
             sf = float(sum(seg_wire / sim.cap[i] for i in ids[1:]))
             memo = cache.lat[(src, dst, seg_wire, key)] = \
                 (prop + sf, prop)
+            cache.bound()
         else:
             cache.hits += 1
         return memo

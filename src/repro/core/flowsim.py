@@ -140,18 +140,72 @@ class LinkMap:
         Memoized in the shared staging cache: large-scale staging
         (fig14 meshes both tree links AND per-receiver latency paths)
         asks for the same pair repeatedly, and `run_many` sweeps ask
-        again per scenario.
+        again per scenario.  A miss between two single-homed hosts is
+        composed from the switch-pair memo (``_route``).
         """
         cache = self.cache.sync()
         memo = cache.paths.get((src, dst, key))
         if memo is None:
             cache.misses += 1
-            memo = cache.paths[(src, dst, key)] = tuple(
-                self.link_id[hop]
-                for hop in self.topo.path_links(src, dst, key))
+            memo = cache.paths[(src, dst, key)] = self._route(src, dst, key)
+            cache.bound()
         else:
             cache.hits += 1
         return memo
+
+    def _homed(self) -> Dict[str, Tuple[str, int, int]]:
+        """Single-homed hosts: those with one port, whose link is up in
+        both directions to a switch; host -> (switch, uplink id, the
+        switch's downlink id).
+
+        Such a host ``h`` under switch ``L`` is a dead end of the
+        fabric: every other node's distance to ``h`` is its distance to
+        ``L`` plus one, so the ECMP candidates toward ``h`` are those
+        toward ``L``, node by node, and the path between two such hosts
+        under one key is the source's uplink, the path between their
+        switches under that key, then the downlink."""
+        homed = self.cache.misc.get("homed")
+        if homed is None:
+            topo = self.topo
+            switches = set(topo.switches)
+            homed = {}
+            for h in topo.hosts:
+                ports = topo.ports[h]
+                if len(ports) != 1:
+                    continue
+                (p, (sw, q)), = ports.items()
+                if sw in switches and not topo.is_down(h, p) \
+                        and not topo.is_down(sw, q):
+                    homed[h] = (sw, self.link_id[(h, p)],
+                                self.link_id[(sw, q)])
+            self.cache.misc["homed"] = homed
+        return homed
+
+    def _route(self, src: str, dst: str, key: int) -> Tuple[int, ...]:
+        """The path src -> dst walked anew: composed from the
+        switch-pair memo between single-homed hosts (``_homed``), else
+        ``Topology.path_links`` (which also raises where the pair is
+        unroutable)."""
+        homed = self._homed()
+        a, b = homed.get(src), homed.get(dst)
+        if a is not None and b is not None and src != dst:
+            cache = self.cache
+            sk = (a[0], b[0], key)
+            mid = cache.switch_paths.get(sk)
+            if mid is not None:
+                cache.sw_hits += 1
+                return (a[1],) + mid + (b[2],)
+            try:
+                hops = self.topo.path_links(a[0], b[0], key)
+            except ValueError:
+                pass                    # the host walk raises as it did
+            else:
+                cache.sw_misses += 1
+                mid = cache.switch_paths[sk] = tuple(
+                    self.link_id[hop] for hop in hops)
+                return (a[1],) + mid + (b[2],)
+        return tuple(self.link_id[hop]
+                     for hop in self.topo.path_links(src, dst, key))
 
     def multicast_tree_links(self, src: str, members: Sequence[str],
                              key: int = 0):
@@ -177,19 +231,42 @@ class LinkMap:
     def warm_paths(self, requests: Sequence[Tuple[str, str, int]]) -> None:
         """Batch-derive many unicast paths into the staging cache.
 
-        Deduplicates against cached entries and hands the misses to
-        ``Topology.paths_many`` — one shared frontier sweep per
-        destination chunk instead of one Python BFS walk per pair.
-        Bit-identical to per-pair ``unicast_links`` by construction.
+        Deduplicates against cached entries.  Pairs of single-homed
+        hosts are composed from the switch-pair memo (``_route``), its
+        misses derived in one ``Topology.paths_many`` call over switch
+        pairs, so the shared frontier sweep runs per destination switch
+        rather than per destination host; every other pair goes to
+        ``paths_many`` as it is.  Bit-identical to per-pair
+        ``unicast_links`` by construction.
         """
         cache = self.cache.sync()
         missing = sorted({r for r in requests if r not in cache.paths})
         if not missing:
             return
         cache.misses += len(missing)
-        hop_lists = self.topo.paths_many(missing)
+        homed = self._homed()
+        walk, composed = [], []
+        for req in missing:
+            a, b = homed.get(req[0]), homed.get(req[1])
+            if a is None or b is None or req[0] == req[1]:
+                walk.append(req)
+            else:
+                composed.append((req, a, b))
         link_id = self.link_id
-        for req, hops in zip(missing, hop_lists):
+        sw = cache.switch_paths
+        need = sorted({(a[0], b[0], req[2]) for req, a, b in composed}
+                      - sw.keys())
+        try:
+            mids = self.topo.paths_many(need)
+        except ValueError:              # the host walk raises as it did
+            walk, composed, need, mids = missing, [], [], []
+        cache.sw_misses += len(need)
+        cache.sw_hits += len(composed) - len(need)
+        for sk, hops in zip(need, mids):
+            sw[sk] = tuple(link_id[h] for h in hops)
+        for req, a, b in composed:
+            cache.paths[req] = (a[1],) + sw[(a[0], b[0], req[2])] + (b[2],)
+        for req, hops in zip(walk, self.topo.paths_many(walk)):
             cache.paths[req] = tuple(link_id[h] for h in hops)
         cache.bound()
 

@@ -21,6 +21,11 @@ plane"):
   down/up round trip (flow-engine fault staging) restores the original
   fingerprint and the pristine artifacts survive.
 - ``paths``  : (src, dst, ecmp key)            -> directed link ids
+- ``switch_paths`` : (leaf of src, leaf of dst, ecmp key) -> directed
+  link ids between two switches, the middle of every path between two
+  single-homed hosts (``LinkMap.unicast_links``): host pairs under one
+  leaf pair share it, so a fresh placement walks leaf pairs, not
+  host pairs.  Counted apart, in ``sw_hits``/``sw_misses``.
 - ``trees``  : (source, member frozenset, key) -> multicast tree links
 - ``lat``    : (src, dst, seg_wire, key)       -> (latency, return prop)
 - ``ops``    : engine-config-prefixed per-op layouts (links, deliver
@@ -28,12 +33,13 @@ plane"):
   faults re-derive every time (their staging mutates the down-set
   mid-op, and their artifacts are timeline-dependent).
 - ``misc``   : small derived singletons (the LinkMap link-id/capacity
-  arrays) keyed by an arbitrary string; same invalidation rules.  The
-  batched dynamic-segment solver parks its solved-rate memo here
-  (``misc['segrates']``: (link-set tuple, loss params) -> fair rate),
-  so a sweep's second pass over the same churn/fault timelines skips
-  the segment solves entirely — and a fingerprint move (real topology
-  mutation) drops the memo with everything else.
+  arrays, its single-homed host table) keyed by an arbitrary string;
+  same invalidation rules.  The batched dynamic-segment solver parks
+  its solved-rate memo here (``misc['segrates']``: (link-set tuple,
+  loss params) -> fair rate), so a sweep's second pass over the same
+  churn/fault timelines skips the segment solves entirely — and a
+  fingerprint move (real topology mutation) drops the memo with
+  everything else.
 
 Entries are plain derived values; nothing downstream mutates them
 (``FlowEngine._backfill`` reads deliver maps read-only), which is what
@@ -48,7 +54,10 @@ from repro.core.fattree import Topology
 
 # coarse safety valve: artifact dicts are cleared wholesale when any one
 # of them exceeds this many entries (a 16k-host x 1k-group sweep stages
-# ~20k paths; the cap only trips on degenerate churn)
+# ~20k paths; fresh placements add one path and one latency per new
+# host pair, so a long Monte-Carlo sweep trips it now and then, and the
+# next pass re-warms once).  Checked after every scalar path or latency
+# insertion and after every batch.
 MAX_ENTRIES = 1 << 20
 
 
@@ -59,12 +68,15 @@ class StagingCache:
         self.topo = topo
         self._fp = topo.fingerprint()
         self.paths: Dict[tuple, Tuple[int, ...]] = {}
+        self.switch_paths: Dict[tuple, Tuple[int, ...]] = {}
         self.trees: Dict[tuple, Tuple[int, ...]] = {}
         self.lat: Dict[tuple, Tuple[float, float]] = {}
         self.ops: Dict[tuple, tuple] = {}
         self.misc: Dict[str, object] = {}
         self.hits = 0
         self.misses = 0
+        self.sw_hits = 0
+        self.sw_misses = 0
         self.invalidations = 0
 
     @classmethod
@@ -85,6 +97,7 @@ class StagingCache:
 
     def invalidate(self) -> None:
         self.paths.clear()
+        self.switch_paths.clear()
         self.trees.clear()
         self.lat.clear()
         self.ops.clear()
@@ -94,8 +107,8 @@ class StagingCache:
 
     def bound(self) -> None:
         """Coarse entry-count safety valve (see MAX_ENTRIES)."""
-        if max(len(self.paths), len(self.trees), len(self.lat),
-               len(self.ops)) > MAX_ENTRIES:
+        if max(len(self.paths), len(self.switch_paths), len(self.trees),
+               len(self.lat), len(self.ops)) > MAX_ENTRIES:
             self.invalidate()
 
     # --------------------------------------------------------- telemetry
@@ -106,8 +119,11 @@ class StagingCache:
             "hits": self.hits,
             "misses": self.misses,
             "hit_rate": self.hits / total if total else 0.0,
+            "sw_hits": self.sw_hits,
+            "sw_misses": self.sw_misses,
             "invalidations": self.invalidations,
             "paths": len(self.paths),
+            "switch_paths": len(self.switch_paths),
             "trees": len(self.trees),
             "lat": len(self.lat),
             "ops": len(self.ops),

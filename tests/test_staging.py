@@ -10,6 +10,11 @@ down-set) fingerprint.  The contract under test:
   sweep whose fault op forces a mid-sweep invalidation;
 - `Topology.paths_many` (batched CSR frontier sweep) returns exactly
   what the scalar `path_links` walk returns, downed links included;
+- host paths composed from the switch-pair memo (`LinkMap._route`,
+  scalar and batched) equal the host walk, and every pair the memo
+  cannot serve (a host with a one-way or second link) walks as before;
+  a fresh-placement sweep is bit-identical with the memo switched off,
+  and the cache stays bounded on the scalar insertion path;
 - fingerprint semantics: `connect` invalidates, a transient
   down/clear round trip does NOT (fault staging relies on this), a
   persistent down DOES;
@@ -20,11 +25,14 @@ down-set) fingerprint.  The contract under test:
 """
 from __future__ import annotations
 
+import random
+
 import pytest
 
-from repro.core import fattree
+from repro.core import fattree, staging
 from repro.core.engine import FlowEngine, PacketEngine, make_engine
 from repro.core.faults import FaultEvent
+from repro.core.flowsim import FlowSim, LinkMap
 from repro.core.staging import StagingCache
 from repro.core.workload import GroupOp, MemberEvent, Workload
 
@@ -97,6 +105,148 @@ def test_paths_many_raises_on_unreachable():
     topo.set_link_down(iso, leaf_of(topo, iso), True)
     with pytest.raises(ValueError):
         topo.paths_many([(topo.hosts[0], iso, 0)])
+
+
+# ===================================================== switch-pair memo
+
+def _down_one_way(topo, node, port):
+    """Down one direction of a link (the fault plane downs both), with
+    the invalidation ``set_link_down`` does."""
+    topo._down.add((node, port))
+    topo._dist.clear()
+    topo._cand.clear()
+    topo._csr = None
+    topo._fp = (topo._struct_rev, frozenset(topo._down))
+
+
+def _routed(build, case):
+    """(topology, hosts the memo must not serve) of one test case."""
+    topo = build()
+    hosts = topo.hosts
+    switches = set(topo.switches)
+    if case == "leaf_agg_down":
+        leaf = leaf_of(topo, hosts[0])
+        agg = next(peer for _, (peer, _) in sorted(topo.ports[leaf].items())
+                   if peer in switches)
+        topo.set_link_down(leaf, agg, True)
+        return topo, set()
+    if case == "leaf_cut_off":
+        # every uplink of the last leaf down: its hosts keep their one
+        # live port each but reach nothing beyond their leaf
+        leaf = leaf_of(topo, hosts[-1])
+        for _, (peer, _) in sorted(topo.ports[leaf].items()):
+            if peer in switches:
+                topo.set_link_down(leaf, peer, True)
+        return topo, set()
+    if case == "one_way":
+        # hosts[1] cannot send, hosts[2] cannot be reached
+        _down_one_way(topo, hosts[1], 0)
+        _down_one_way(topo, *topo.ports[hosts[2]][0])
+        return topo, {hosts[1], hosts[2]}
+    if case == "dual_homed":
+        topo.connect(hosts[0], leaf_of(topo, hosts[-1]),
+                     100 * fattree.GBPS, 0.6e-6)
+        return topo, {hosts[0]}
+    return topo, set()
+
+
+def _outcome(fn):
+    try:
+        return "ok", tuple(fn())
+    except Exception as e:             # the walk's own error, as it is
+        return "raises", (type(e), str(e))
+
+
+@pytest.mark.parametrize("case", ["pristine", "leaf_agg_down",
+                                  "leaf_cut_off", "one_way", "dual_homed"])
+@pytest.mark.parametrize("build", [small_fat_tree, fattree.fig4],
+                         ids=["small_fat_tree", "fig4"])
+def test_switch_memo_paths_equal_the_host_walk(build, case):
+    topo, unserved = _routed(build, case)
+    sim = FlowSim(topo, shared_cache=False)
+    assert set(sim._homed()) == set(topo.hosts) - unserved
+    reqs = [(s, d, k) for s in topo.hosts for d in topo.hosts
+            for k in (0, 1, 7)]
+
+    def walk(s, d, k):
+        return [sim.link_id[h] for h in topo.path_links(s, d, k)]
+
+    want = {r: _outcome(lambda: walk(*r)) for r in reqs}
+    for r in reqs:
+        assert _outcome(lambda: sim.unicast_links(*r)) == want[r], r
+    served = [r for r in reqs if r[0] != r[1] and want[r][0] == "ok"
+              and not {r[0], r[1]} & unserved]
+    cache = sim.cache
+    assert cache.sw_misses == len(cache.switch_paths) > 0
+    assert cache.sw_hits + cache.sw_misses == len(served)
+    # the batch path, from a cold cache: routable pairs in one call,
+    # each unroutable one raising as ``paths_many`` raises for it
+    warm = FlowSim(topo, shared_cache=False)
+    ok = [r for r in reqs if want[r][0] == "ok"]
+    warm.warm_paths(ok)
+    assert {r: warm.cache.paths[r] for r in ok} == \
+        {r: want[r][1] for r in ok}
+    assert warm.cache.sw_misses == len(warm.cache.switch_paths) > 0
+    for r in reqs:
+        if want[r][0] == "raises":
+            batch = _outcome(lambda: topo.paths_many([r])[0])
+            assert batch[0] == "raises"
+            assert _outcome(lambda: warm.warm_paths([r])) == batch, r
+    assert (case in ("leaf_cut_off", "one_way")) == (len(ok) < len(reqs))
+
+
+def _fresh_sweep(loss_rate, passes=3):
+    """Fresh members every pass over one fabric, as a Monte-Carlo
+    placement sweep runs: (records per pass, engines' staging stats)."""
+    topo = fattree.fat_tree(n_pods=4, leaves_per_pod=4, hosts_per_leaf=4,
+                            aggs_per_pod=2, bw=100 * fattree.GBPS)
+    rng = random.Random(11)
+    recs, stats = [], []
+    for i in range(passes):
+        wls = []
+        for g in (4, 16):
+            wl = Workload(f"fresh/{i}/{g}")
+            wl.bcast(rng.sample(topo.hosts, g), 1 << 20)
+            wls.append(wl)
+        kw = {"loss_rate": loss_rate} if loss_rate else {}
+        eng = make_engine("flow", topo, **kw)
+        recs.append(record_tuples(eng.run_workloads(wls)))
+        stats.append(eng.staging_stats())
+    return recs, stats
+
+
+@pytest.mark.parametrize("loss_rate", [0.0, 1e-4])
+def test_fresh_placements_bit_identical_without_switch_memo(loss_rate,
+                                                            monkeypatch):
+    recs, stats = _fresh_sweep(loss_rate)
+    assert stats[1]["sw_hits"] > 0
+    monkeypatch.setattr(LinkMap, "_homed", lambda self: {})
+    off, off_stats = _fresh_sweep(loss_rate)
+    assert recs == off
+    assert off_stats[-1]["sw_hits"] == off_stats[-1]["sw_misses"] == 0
+    # the memo changes what is derived, not what the cache counts
+    for on, no in zip(stats, off_stats):
+        assert (on["hits"], on["misses"]) == (no["hits"], no["misses"])
+
+
+def test_scalar_insertions_stay_bounded(monkeypatch):
+    """Fresh placements add paths and latencies on the scalar path every
+    pass; the entry cap holds there too, and a wholesale drop costs one
+    re-derivation, not a change of result."""
+    want, _ = _fresh_sweep(0.0, passes=4)
+    monkeypatch.setattr(staging, "MAX_ENTRIES", 40)
+    got, stats = _fresh_sweep(0.0, passes=4)
+    assert got == want
+    assert stats[-1]["invalidations"] > 0
+    assert max(stats[-1][k] for k in ("paths", "switch_paths", "lat",
+                                      "trees", "ops")) <= 40
+    # and for paths asked for one by one, with no latency behind them
+    sim = FlowSim(small_fat_tree(), shared_cache=False)
+    for src in sim.topo.hosts:
+        for dst in sim.topo.hosts:
+            sim.unicast_links(src, dst)
+            assert len(sim.cache.paths) <= 40
+    assert sim.cache.invalidations > 0
 
 
 # ==================================================== cache-off = cache-on
@@ -246,7 +396,12 @@ def test_staging_stats_shape():
     wl.bcast(topo.hosts[:4], 1 << 20)
     eng.run_workloads([wl])
     stats = eng.staging_stats()
-    for k in ("hits", "misses", "hit_rate", "invalidations", "paths",
-              "trees", "lat", "ops"):
+    for k in ("hits", "misses", "hit_rate", "sw_hits", "sw_misses",
+              "invalidations", "paths", "switch_paths", "trees", "lat",
+              "ops"):
         assert k in stats
     assert 0.0 <= stats["hit_rate"] <= 1.0
+    # three receivers under the source's own leaf: one switch pair
+    # (the leaf to itself) derived, then served twice
+    assert stats["sw_misses"] == stats["switch_paths"] == 1
+    assert stats["sw_hits"] == 2
