@@ -29,14 +29,35 @@ class ArchConfig:
     window: int = 0                 # sliding-window size; 0 = full attention
     rope_theta: float = 1e4
     use_rope: bool = True
-    # repeating sublayer pattern; n_layers must be len(pattern) * n_blocks
+    # repeating sublayer pattern after the leading dense layers:
+    # n_layers must be n_dense_layers + len(pattern) * n_blocks
     pattern: Tuple[Sublayer, ...] = (("attn", "mlp"),)
+    # leading dense layers (DeepSeek's ``first_k_dense_replace``): attn
+    # + a dense FFN of width ``dense_d_ff``, before the pattern repeats
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
     # MoE
     n_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    n_shared_experts: int = 0       # always-on experts of width moe_d_ff
+    router_bias: bool = False       # a balancing bias per routed expert
+    # group-limited routing: the experts fall in n_group equal groups
+    # and a token's top_k come from its topk_group best groups, ranked
+    # by the sum of each group's top (top_k // topk_group) scores
+    n_group: int = 0
+    topk_group: int = 0
+    # multi-head latent attention (MLA), on when kv_lora_rank > 0: q
+    # through a rank-q_lora_rank bottleneck, k/v up from a shared
+    # kv_lora_rank latent plus one rope key
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    n_mtp_layers: int = 0           # multi-token-prediction modules
     # SSM (Mamba-2 / SSD)
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -73,10 +94,13 @@ class ArchConfig:
 
     @property
     def n_blocks(self) -> int:
-        assert self.n_layers % len(self.pattern) == 0, (
-            f"{self.name}: n_layers={self.n_layers} not a multiple of "
+        """Repeats of ``pattern`` after the leading dense layers."""
+        n = self.n_layers - self.n_dense_layers
+        assert n % len(self.pattern) == 0, (
+            f"{self.name}: n_layers={self.n_layers} less "
+            f"{self.n_dense_layers} dense not a multiple of "
             f"pattern length {len(self.pattern)}")
-        return self.n_layers // len(self.pattern)
+        return n // len(self.pattern)
 
     @property
     def is_subquadratic(self) -> bool:
@@ -99,6 +123,7 @@ ARCH_IDS = (
     "mamba2_370m",
     "internvl2_26b",
     "jamba_v0_1_52b",
+    "deepseek_v3",
 )
 
 

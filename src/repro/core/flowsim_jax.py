@@ -38,8 +38,8 @@ padded to a multicast tree's hop count.
 largest staged volume exceeds the float32 safe-integer range (2^24
 bytes ~ 16MB); beyond that (the multi-GB fig12/13 replication regime)
 the solve auto-promotes to float64 under a scoped ``jax.enable_x64``
-so completion times keep full precision.  ``solve_dtype`` records the
-choice.
+so completion times keep full precision, at float64's own freeze and
+completion slacks (``EPOCH_TOL``).  ``solve_dtype`` records the choice.
 
 Flows, link ids, and routing come from ``flowsim.LinkMap`` so this
 solver and its numpy reference ``flowsim.FlowSim`` are numerically
@@ -66,6 +66,12 @@ from repro.kernels.maxmin import loss_factors, maxmin_fill, maxmin_rates
 #: volumes above this lose integer precision in float32 (2^24 bytes)
 F32_SAFE_MAX = float(1 << 24)
 
+#: the epoch solver's slacks by solve dtype: (relative freeze slack,
+#: relative completion slack, bytes added to it).  float32 leaves room
+#: for its rounding; a promoted float64 solve keeps the precision it
+#: was promoted for, at the numpy filling's 1e-12 freeze slack
+EPOCH_TOL = {"float32": (1e-6, 1e-6, 1.0), "float64": (1e-12, 1e-9, 0.0)}
+
 #: padded-batch budget for ``_plan_batches`` (int32 link-id bytes)
 MAX_BATCH_BYTES = 64 << 20
 
@@ -91,11 +97,17 @@ MAX_PAD_WASTE = 4.0
 #: - ``lane_rounds_run``: filling rounds the device executed, times the
 #:   lanes of the call: a vmapped loop runs every lane until the
 #:   slowest is done, so ``rounds / lane_rounds_run`` is the share of
-#:   that work some lane needed.
+#:   that work some lane needed;
+#: - ``x64_lanes``: lanes solved under the float64 promotion;
+#: - ``epochs_run``: per call, the most epochs of any of its lanes —
+#:   the device loop's serial depth, summed over calls;
+#: - ``rounds_run``: per call, the filling rounds the device executed
+#:   (``lane_rounds_run`` without the lanes factor), summed over calls.
 #:
-#: The four counters cover the epoch solver only.
+#: The counters from ``lanes`` on cover the epoch solver only.
 SOLVE_STATS = {"solve_s": 0.0, "calls": 0, "shapes": set(), "lanes": 0,
-               "epochs": 0, "rounds": 0, "lane_rounds_run": 0}
+               "epochs": 0, "rounds": 0, "lane_rounds_run": 0,
+               "x64_lanes": 0, "epochs_run": 0, "rounds_run": 0}
 _STATS_LOCK = threading.Lock()
 
 #: dynamic-segment solves mirror the numpy ``flowsim.static_maxmin``
@@ -108,7 +120,8 @@ SEG_ROUNDS = 64
 
 def reset_solve_stats():
     SOLVE_STATS.update(solve_s=0.0, calls=0, shapes=set(), lanes=0,
-                       epochs=0, rounds=0, lane_rounds_run=0)
+                       epochs=0, rounds=0, lane_rounds_run=0,
+                       x64_lanes=0, epochs_run=0, rounds_run=0)
 
 
 #: the persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
@@ -184,7 +197,8 @@ def _simulate(flow_links, cap, vol, loss=None, warm=True,
     """
     n_flows = flow_links.shape[0]
     n_caps = cap.shape[0]
-    eps = vol * 1e-6 + 1.0                  # completion slack (bytes)
+    tol, slack, pad = EPOCH_TOL[jnp.dtype(cap.dtype).name]
+    eps = vol * slack + pad                 # completion slack (bytes)
 
     def cond(st):
         _, rem, _, _, _, it, _, _ = st
@@ -197,10 +211,10 @@ def _simulate(flow_links, cap, vol, loss=None, warm=True,
             if warm:
                 rates, n = lax.cond(
                     dirty,
-                    lambda r: maxmin_fill(flow_links, cap, active),
+                    lambda r: maxmin_fill(flow_links, cap, active, tol=tol),
                     lambda r: (r, jnp.int32(0)), rates)
             else:
-                rates, n = maxmin_fill(flow_links, cap, active)
+                rates, n = maxmin_fill(flow_links, cap, active, tol=tol)
             eff = rates
             if loss is not None:
                 eff = rates * loss_factors(
@@ -414,6 +428,10 @@ class JaxFlowSim(LinkMap):
             SOLVE_STATS["epochs"] += int(epochs.sum())
             SOLVE_STATS["rounds"] += int(rounds.sum())
             SOLVE_STATS["lane_rounds_run"] += lanes * int(ran.max())
+            if dtype == np.float64:
+                SOLVE_STATS["x64_lanes"] += lanes
+            SOLVE_STATS["epochs_run"] += int(epochs.max())
+            SOLVE_STATS["rounds_run"] += int(ran.max())
         return done
 
     def _finish(self, flows: Sequence[Flow], done: np.ndarray) -> float:

@@ -175,7 +175,11 @@ def main(argv=None) -> int:
     out_dir = pathlib.Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)
     meshes = ("pod", "multipod") if args.mesh == "both" else (args.mesh,)
-    cells = ([(a, s) for a in ARCH_IDS for s in SHAPE_TABLE]
+    # --all sweeps the architectures the model layer builds
+    from repro.configs.base import get_config
+    from repro.models.model import supports
+    cells = ([(a, s) for a in ARCH_IDS if supports(get_config(a))
+              for s in SHAPE_TABLE]
              if args.all else [(args.arch, args.shape)])
     overrides = {}
     for kv in args.set:

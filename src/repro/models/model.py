@@ -75,7 +75,19 @@ def _sublayer_defs(cfg: ArchConfig, mixer, ffn, cross=False):
     return {"mixer": mdefs, "ffn": _ffn_defs(cfg, ffn)}
 
 
+def supports(cfg: ArchConfig) -> bool:
+    """Whether this layer builds ``cfg``: it has no latent attention,
+    no leading dense layers, no shared experts and no MTP modules
+    (``apps.collectives_lowering`` lowers such configs all the same)."""
+    return not (cfg.kv_lora_rank or cfg.n_dense_layers
+                or cfg.n_shared_experts or cfg.n_mtp_layers)
+
+
 def model_defs(cfg: ArchConfig):
+    if not supports(cfg):
+        raise NotImplementedError(
+            f"{cfg.name}: the model layer has no MLA attention, leading "
+            "dense layers, shared experts or MTP modules")
     d, v = cfg.d_model, cfg.vocab_size
     block = {f"sub{i}": _sublayer_defs(cfg, m, f,
                                        cross=(cfg.enc_layers > 0))

@@ -6,7 +6,8 @@
 - shape bucketing: two sweep points in the same (F, H) bucket must hit
   the jit cache (no recompile);
 - float64 auto-promotion once volumes exceed the float32 safe-integer
-  range, pinned against a float64 numpy reference;
+  range, pinned against a float64 numpy reference, with float64's
+  own freeze and completion slacks;
 - ``run_many`` batched scenarios == serial runs on fresh engines;
 - solvers never clobber the staged ``Flow.volume``.
 """
@@ -175,6 +176,25 @@ def test_large_volumes_auto_promote_to_float64():
     assert sim_jx.solve_dtype == np.float64
     np.testing.assert_allclose([f.done_t for f in fj],
                                [f.done_t for f in fn], rtol=1e-9)
+
+
+@pytest.mark.parametrize("gap,merged", [(5e-7, False), (5e-10, True)])
+def test_float64_solve_keeps_float64_slacks(gap, merged):
+    """Two promoted flows on one path whose volumes differ by ``gap``:
+    they share the link until the smaller finishes at t1, then the
+    larger drains the rest alone, at t1 * (1 + gap / 2).  A float64
+    solve finishes the larger within 1e-9 of its volume only: 5e-7 of
+    it left is a float32 solve's slack, not a float64 one's."""
+    sim = JaxFlowSim(fattree.testbed())
+    links = sim.unicast_links("h0", "h1")
+    vol = 2 * flowsim_jax.F32_SAFE_MAX
+    first = sim.add(links, vol)
+    second = sim.add(links, vol * (1.0 + gap))
+    sim.run()
+    assert sim.solve_dtype == np.float64
+    t1 = first.done_t
+    want = t1 if merged else t1 * (1.0 + gap / 2.0)
+    assert second.done_t == pytest.approx(want, rel=1e-12, abs=0.0)
 
 
 def test_f32_boundary_is_safe_integer_range():

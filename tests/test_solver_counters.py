@@ -5,7 +5,9 @@
   that lane) and the exact filling rounds the device ran for the
   batch, and its completion times are bit for bit those of the solver
   loop without counters;
-- ``SOLVE_STATS`` adds them up, keeps the set of distinct shapes, and
+- ``SOLVE_STATS`` adds them up, with each call's serial depth (its
+  slowest lane's epochs, the rounds it ran) and the lanes solved in
+  float64, keeps the set of distinct shapes, and
   ``reset_solve_stats`` clears every key;
 - both solver flavours lower to ``jit__simulate`` (the name the
   benchmark's trace reduction looks for); the segment solver does not;
@@ -131,9 +133,28 @@ def test_solve_stats_add_up_the_counts(sim):
     # a batch runs its lanes for its slowest lane's rounds; one lane
     # alone runs what it needs
     assert st["lane_rounds_run"] == 2 * 6 + 2 * 6 + 6
+    # per call, the slowest lane's epochs and the rounds the device ran
+    assert st["epochs_run"] == 3 + 3 + 3
+    assert st["rounds_run"] == 6 + 6 + 6
+    assert st["x64_lanes"] == 0
     flowsim_jax.reset_solve_stats()
     assert st == {"solve_s": 0.0, "calls": 0, "shapes": set(), "lanes": 0,
-                  "epochs": 0, "rounds": 0, "lane_rounds_run": 0}
+                  "epochs": 0, "rounds": 0, "lane_rounds_run": 0,
+                  "x64_lanes": 0, "epochs_run": 0, "rounds_run": 0}
+
+
+def test_x64_lanes_count_the_promoted_solves(sim):
+    packed = [_pack(lane) for lane in LANES]
+    fl = np.stack([p[0] for p in packed])
+    vol = np.stack([p[1] for p in packed])
+    sim._dispatch(True, fl, CAP, vol, np.float32)
+    sim._dispatch(True, fl, CAP.astype(np.float64), vol.astype(np.float64),
+                  np.float64)
+    sim._dispatch(False, packed[1][0], CAP.astype(np.float64),
+                  packed[1][1].astype(np.float64), np.float64)
+    st = flowsim_jax.SOLVE_STATS
+    assert (st["lanes"], st["x64_lanes"]) == (5, 3)
+    assert (st["epochs_run"], st["rounds_run"]) == (9, 18)
 
 
 def test_maxmin_fill_counts_the_rounds_maxmin_rates_runs():
