@@ -6,9 +6,9 @@ of its tree links), but the whole simulation is dense-array loops:
 
 - **inner loop**: progressive-filling max-min fair allocation, one
   round per iteration (``kernels/maxmin.py``, jnp on every platform).
-  Each round scatter-adds the unfrozen flows onto their links, computes
-  every link's fair share, gathers each flow's tightest share, and
-  freezes the bottleneck group.
+  Each round counts the unfrozen flows on each link, computes every
+  link's fair share, gathers each flow's tightest share, and freezes
+  the bottleneck group.
   Terminates in at most F rounds (whole bottleneck groups freeze
   together, so in practice a handful).
 - **outer loop** (``_simulate``): classic fluid event loop — advance
@@ -40,6 +40,11 @@ bytes ~ 16MB); beyond that (the multi-GB fig12/13 replication regime)
 the solve auto-promotes to float64 under a scoped ``jax.enable_x64``
 so completion times keep full precision, at float64's own freeze and
 completion slacks (``EPOCH_TOL``).  ``solve_dtype`` records the choice.
+A float64 filling round scatters no float64: it keeps each link's live
+flows as an int32 count carried across the fill's rounds, and takes
+the frozen bandwidth off a link as the round's one bottleneck rate
+times the int32 count of flows it froze there (``kernels/maxmin.py``);
+the warm start's touched-link test is an int32 count too.
 
 Flows, link ids, and routing come from ``flowsim.LinkMap`` so this
 solver and its numpy reference ``flowsim.FlowSim`` are numerically
@@ -61,7 +66,8 @@ from jax import lax
 
 from repro.core.fattree import Topology
 from repro.core.flowsim import DCQCN_MIN_RATE, DCQCN_RATE_NUM, Flow, LinkMap
-from repro.kernels.maxmin import loss_factors, maxmin_fill, maxmin_rates
+from repro.kernels.maxmin import (link_counts, loss_factors, maxmin_fill,
+                                  maxmin_rates)
 
 #: volumes above this lose integer precision in float32 (2^24 bytes)
 F32_SAFE_MAX = float(1 << 24)
@@ -227,13 +233,11 @@ def _simulate(flow_links, cap, vol, loss=None, warm=True,
             done = jnp.where(fin, t, done)
             rem = jnp.where(fin, 0.0, rem)
             if warm:
-                touched = jnp.zeros(n_caps, cap.dtype).at[flow_links].add(
-                    jnp.broadcast_to(fin.astype(cap.dtype)[:, None],
-                                     flow_links.shape))
-                touched = touched.at[-1].set(0.0)   # sentinel: no contention
+                touched = link_counts(flow_links, fin, n_caps)
+                touched = touched.at[-1].set(0)     # sentinel: no contention
                 survive = active & ~fin
                 dirty = jnp.any(
-                    survive & (jnp.max(touched[flow_links], axis=1) > 0.0))
+                    survive & (jnp.max(touched[flow_links], axis=1) > 0))
         ran_n = n if axis_name is None else lax.pmax(n, axis_name)
         return t, rem, done, rates, dirty, it + 1, rounds + n, ran + ran_n
 

@@ -3,12 +3,24 @@
 One round of water-filling over the padded (F, H) flow->link matrix
 (see ``core/flowsim_jax.py``) makes four logical passes:
 
-1. per-link demand  — scatter-add every unfrozen flow onto its links;
+1. per-link demand  — the unfrozen flows on each link;
 2. fair share       — ``cap_remaining / demand`` per link;
 3. tightest share   — per-flow min-gather over its link list, global
    bottleneck ``b`` = min over unfrozen flows;
 4. freeze mask      — flows at the bottleneck freeze at rate ``b`` and
    their bandwidth is subtracted from every link they cross.
+
+The fill picks the round's form from the solve dtype.  A float32 round
+scatter-adds the unfrozen flows (pass 1) and the frozen rates (pass 4)
+onto their links in float32.  A float64 round scatters no float64: the
+fill counts each link's live flows once, as int32, and carries the
+count; a round scatter-adds its newly frozen flows as an int32 ``k``
+per link, subtracts ``b * k`` from the remaining capacity (every flow
+of a round freezes at the same ``b``) and ``k`` from the count.  The
+TPU emulates float64, and a float64 scatter-add there costs tens of
+times a float32 one per entry; the counts are exact either way, and
+``b * k`` rounds once where a sum of ``k`` copies of ``b`` rounds
+``k - 1`` times.
 
 This jnp formulation is the solver's path on every platform: XLA
 lowers its scatter-adds and gathers for the CPU and for the TPU alike
@@ -22,26 +34,35 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def maxmin_round(flow_links, frozen, rates, cap_rem, *, tol: float = 1e-6):
+def maxmin_round(flow_links, frozen, rates, cap_rem, cnt=None, *,
+                 tol: float = 1e-6):
     """One progressive-filling round of max-min fair allocation.
 
     flow_links (F, H) int32 link ids padded with the sentinel (last)
     index of ``cap_rem``; frozen (F,) 0/1 mask in cap dtype (padding
     rows enter frozen); rates (F,); cap_rem (L+1,) with cap_rem[-1]=inf.
+    ``cnt`` (L+1,) int32 is each link's live-flow count, carried from
+    round to round (float64 fills); None recounts the live flows with a
+    scatter in cap dtype (float32 fills).
     ``tol`` is the relative freeze slack (1e-6 suits float32 solves;
-    the float64 dynamic-segment solver passes 1e-12 to mirror the numpy
+    float64 solves pass 1e-12 to mirror the numpy
     ``flowsim.static_maxmin`` filling).  Returns the round's
-    (rates, frozen, cap_rem).
+    (rates, frozen, cap_rem, cnt), ``cnt`` None when it came in None.
     """
     n_caps = cap_rem.shape[0]
     dtype = cap_rem.dtype
     with jax.named_scope("maxmin_round"):
-        live = 1.0 - frozen
-        # per-link demand: scatter every live flow onto its links
-        cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
-            jnp.broadcast_to(live[:, None], flow_links.shape))
-        share = jnp.where(cnt > 0.0, cap_rem / jnp.maximum(cnt, 1.0),
-                          jnp.inf)
+        if cnt is None:
+            live = 1.0 - frozen
+            # per-link demand: scatter every live flow onto its links
+            demand = jnp.zeros(n_caps, dtype).at[flow_links].add(
+                jnp.broadcast_to(live[:, None], flow_links.shape))
+            share = jnp.where(demand > 0.0,
+                              cap_rem / jnp.maximum(demand, 1.0), jnp.inf)
+        else:
+            share = jnp.where(cnt > 0,
+                              cap_rem / jnp.maximum(cnt, 1).astype(dtype),
+                              jnp.inf)
         # each flow's tightest link share (sentinel gathers inf)
         tightest = jnp.min(share[flow_links], axis=1)
         limit = jnp.where(frozen > 0.5, jnp.inf, tightest)
@@ -49,10 +70,22 @@ def maxmin_round(flow_links, frozen, rates, cap_rem, *, tol: float = 1e-6):
         newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
         newf = newly.astype(dtype)
         rates = jnp.where(newly, b, rates)
-        used = jnp.zeros(n_caps, dtype).at[flow_links].add(
-            jnp.broadcast_to((newf * b)[:, None], flow_links.shape))
+        if cnt is None:
+            used = jnp.zeros(n_caps, dtype).at[flow_links].add(
+                jnp.broadcast_to((newf * b)[:, None], flow_links.shape))
+        else:
+            k = link_counts(flow_links, newly, n_caps)
+            used = b * k.astype(dtype)
+            cnt = cnt - k
         cap_rem = jnp.maximum(cap_rem - used, 0.0)
-        return rates, jnp.minimum(frozen + newf, 1.0), cap_rem
+        return rates, jnp.minimum(frozen + newf, 1.0), cap_rem, cnt
+
+
+def link_counts(flow_links, mask, n_caps: int):
+    """(n_caps,) int32: how many flows of ``mask`` (F,) cross each link,
+    as an int32 scatter-add (a link twice in a row counts twice)."""
+    return jnp.zeros(n_caps, jnp.int32).at[flow_links].add(
+        jnp.broadcast_to(mask.astype(jnp.int32)[:, None], flow_links.shape))
 
 
 def maxmin_rates(flow_links, cap, active, *, tol: float = 1e-6,
@@ -80,24 +113,30 @@ def maxmin_fill(flow_links, cap, active, *, tol: float = 1e-6,
     bound).  The dynamic-segment solver passes ``tol=1e-12,
     max_rounds=64`` under float64 to mirror the numpy
     ``flowsim.static_maxmin`` filling round for round.
+
+    A float64 fill counts each link's live flows once, as int32, and
+    its rounds carry that count (see the module docstring); a float32
+    fill's rounds recount it.
     """
     n_flows = flow_links.shape[0]
     dtype = cap.dtype
     bound = n_flows if max_rounds is None else max_rounds - 1
+    cnt = link_counts(flow_links, active, cap.shape[0]) \
+        if dtype == jnp.float64 else None
 
     def cond(st):
-        _, frozen, _, it = st
+        _, frozen, _, _, it = st
         return jnp.logical_and(jnp.min(frozen) < 0.5, it <= bound)
 
     def body(st):
-        rates, frozen, cap_rem, it = st
-        rates, frozen, cap_rem = maxmin_round(flow_links, frozen, rates,
-                                              cap_rem, tol=tol)
-        return rates, frozen, cap_rem, it + 1
+        rates, frozen, cap_rem, cnt, it = st
+        rates, frozen, cap_rem, cnt = maxmin_round(
+            flow_links, frozen, rates, cap_rem, cnt, tol=tol)
+        return rates, frozen, cap_rem, cnt, it + 1
 
     init = (jnp.zeros(n_flows, dtype), 1.0 - active.astype(dtype),
-            cap, jnp.int32(0))
-    rates, _, _, rounds = lax.while_loop(cond, body, init)
+            cap, cnt, jnp.int32(0))
+    rates, _, _, _, rounds = lax.while_loop(cond, body, init)
     return jnp.maximum(rates, 1e-9), rounds
 
 

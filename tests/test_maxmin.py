@@ -9,9 +9,14 @@
   range, pinned against a float64 numpy reference, with float64's
   own freeze and completion slacks;
 - ``run_many`` batched scenarios == serial runs on fresh engines;
-- solvers never clobber the staged ``Flow.volume``.
+- solvers never clobber the staged ``Flow.volume``;
+- the float64 filling's int32 link counts against a copy of the
+  float-sum round, and no float64 scatter in the float64 solver.
 """
 from __future__ import annotations
+
+import functools
+import re
 
 import numpy as np
 import pytest
@@ -20,7 +25,9 @@ from repro.core import fattree
 from repro.core.engine import make_engine
 from repro.core.flowsim import FlowSim
 
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import flowsim_jax
 from repro.core.flowsim_jax import JaxFlowSim, _bucket, _solver
@@ -324,3 +331,135 @@ def test_solvers_preserve_staged_volume(cls):
     assert f.volume == float(1 << 20)
     assert f.remaining == 0.0
     assert f.done_t > 0.0
+
+
+# ======================================= int32 link counts (float64 fill)
+
+def _float_sum_fill(flow_links, cap, active, tol):
+    """The filling as every round once ran it: the live flows and the
+    frozen rates scatter-added onto their links in cap dtype, recounted
+    each round.  The reference for ``maxmin_fill``'s int32 counts."""
+    n_flows, n_caps, dtype = flow_links.shape[0], cap.shape[0], cap.dtype
+
+    def body(st):
+        rates, frozen, cap_rem, it = st
+        live = 1.0 - frozen
+        cnt = jnp.zeros(n_caps, dtype).at[flow_links].add(
+            jnp.broadcast_to(live[:, None], flow_links.shape))
+        share = jnp.where(cnt > 0.0, cap_rem / jnp.maximum(cnt, 1.0),
+                          jnp.inf)
+        tightest = jnp.min(share[flow_links], axis=1)
+        limit = jnp.where(frozen > 0.5, jnp.inf, tightest)
+        b = jnp.min(limit)
+        newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
+        newf = newly.astype(dtype)
+        rates = jnp.where(newly, b, rates)
+        used = jnp.zeros(n_caps, dtype).at[flow_links].add(
+            jnp.broadcast_to((newf * b)[:, None], flow_links.shape))
+        cap_rem = jnp.maximum(cap_rem - used, 0.0)
+        return rates, jnp.minimum(frozen + newf, 1.0), cap_rem, it + 1
+
+    def cond(st):
+        return jnp.logical_and(jnp.min(st[1]) < 0.5, st[3] <= n_flows)
+
+    init = (jnp.zeros(n_flows, dtype), 1.0 - active.astype(dtype), cap,
+            jnp.int32(0))
+    rates, _, _, rounds = lax.while_loop(cond, body, init)
+    return jnp.maximum(rates, 1e-9), rounds
+
+
+def _fill_problem(seed, n_links=300, f_pad=512, h_pad=8):
+    """A (512, 8) problem over ``n_links`` links of uneven capacity:
+    flows of 1 to 8 distinct links padded with the sentinel, a quarter
+    of the used rows inactive, the last rows all sentinel padding."""
+    rng = np.random.default_rng(seed)
+    n_used = int(rng.integers(f_pad // 2, f_pad - 16))
+    fl = np.full((f_pad, h_pad), n_links, np.int32)
+    for i in range(n_used):
+        hops = int(rng.integers(1, h_pad + 1))
+        fl[i, :hops] = rng.choice(n_links, hops, replace=False)
+    active = np.zeros(f_pad, bool)
+    active[:n_used] = rng.random(n_used) >= 0.25
+    cap = np.append(rng.uniform(1e9, 5e10, n_links), np.inf)
+    return fl, cap, active
+
+
+def _fills(fl, cap, active, tol, batched):
+    """(new, reference) jitted fills on these arrays, vmapped over a
+    leading lane axis when ``batched``."""
+    new = functools.partial(maxmin.maxmin_fill, tol=tol)
+    ref = functools.partial(_float_sum_fill, tol=tol)
+    if batched:
+        new = jax.vmap(new, in_axes=(0, None, 0))
+        ref = jax.vmap(ref, in_axes=(0, None, 0))
+    args = (jnp.asarray(fl), jnp.asarray(cap), jnp.asarray(active))
+    return jax.jit(new)(*args), jax.jit(ref)(*args)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_float64_fill_counts_match_float_sum_round(seed):
+    """int32 counts carried across rounds give the float-sum round's
+    rates to 1e-14 and its round count, on tens of rounds."""
+    fl, cap, active = _fill_problem(seed)
+    with jax.enable_x64(True):
+        (got, n), (want, n_ref) = _fills(fl, cap, active, 1e-12, False)
+        assert got.dtype == jnp.float64
+        got, want = np.asarray(got), np.asarray(want)
+    assert int(n) == int(n_ref) >= 10
+    np.testing.assert_allclose(got, want, rtol=1e-14, atol=0.0)
+    assert np.all(got[~active] == 1e-9)
+
+
+def test_float64_fill_counts_match_under_vmap():
+    """Two lanes whose round counts differ: each lane stops carrying its
+    count when it is done, and matches its own reference."""
+    fl0, cap, act0 = _fill_problem(10)
+    fl1, _, act1 = _fill_problem(11)
+    act1[32:] = False                       # a lane of few rounds
+    fl, active = np.stack([fl0, fl1]), np.stack([act0, act1])
+    with jax.enable_x64(True):
+        (got, n), (want, n_ref) = _fills(fl, cap, active, 1e-12, True)
+        got, want = np.asarray(got), np.asarray(want)
+    n, n_ref = np.asarray(n), np.asarray(n_ref)
+    assert n.tolist() == n_ref.tolist()
+    assert n[0] != n[1]
+    np.testing.assert_allclose(got, want, rtol=1e-14, atol=0.0)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_float32_fill_is_the_float_sum_round(batched):
+    """A float32 fill keeps the float-sum round: bit for bit."""
+    fl, cap, active = _fill_problem(20)
+    cap = cap.astype(np.float32)
+    if batched:
+        fl2, _, act2 = _fill_problem(21)
+        fl, active = np.stack([fl, fl2]), np.stack([active, act2])
+    (got, n), (want, n_ref) = _fills(fl, cap, active, 1e-6, batched)
+    assert got.dtype == jnp.float32
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert np.array_equal(np.asarray(n), np.asarray(n_ref))
+
+
+def _scatter_results(text):
+    """The result types of every scatter in lowered StableHLO text."""
+    return re.findall(r'"stablehlo\.scatter".*?\}\) : \([^)]*\) -> '
+                      r'(tensor<[^>]*>)', text, flags=re.S)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_float64_epoch_solver_scatters_no_float64(batched):
+    """The lossless float64 ``jit__simulate`` scatters int32 only (the
+    float32 one still scatters float32)."""
+    lanes = (2,) if batched else ()
+    for dtype in (jnp.float64, jnp.float32):
+        with jax.enable_x64(dtype == jnp.float64):
+            text = _solver(batched, False).lower(
+                jax.ShapeDtypeStruct(lanes + (512, 8), jnp.int32),
+                jax.ShapeDtypeStruct((301,), dtype),
+                jax.ShapeDtypeStruct(lanes + (512,), dtype)).as_text()
+        results = _scatter_results(text)
+        assert results
+        if dtype == jnp.float64:
+            assert all(r.endswith("xi32>") for r in results), results
+        else:
+            assert any(r.endswith("xf32>") for r in results), results
