@@ -15,11 +15,11 @@ uniform).
 
 The sweep is stage-then-batch: every (scale, workload) scenario on the
 same topology is staged on ONE engine and solved by a single
-``run_many`` call — the shape-bucketed solver compiles once for the
-whole sweep instead of once per point, and the topology (with its BFS
-routing caches) is built once per size class.  ``--serial`` restores
-the PR-1 behavior (fresh engine + solve per scenario) for A/B timing;
-``tools/bench.py`` records both.
+``run_workloads`` call — the shape-bucketed solver compiles once for
+the whole sweep instead of once per point, and the topology (with its
+BFS routing caches) is built once per size class.  ``--serial``
+restores the PR-1 behavior (fresh engine + solve per scenario) for A/B
+timing.
 
 This figure is inherently beyond packet-level reach (the paper
 parallelized ns-3 for it); requesting ``--engine packet`` falls back to
@@ -80,9 +80,8 @@ def _flow_engine(name: str):
 def _workloads(big: bool, n: int, transport: str):
     """Workload IR for one sweep point, cached: ops are immutable
     (engines lower them into per-epoch records without touching the
-    IR), so repeated passes — `tools/bench.py` runs the sweep twice to
-    separate compile from steady state — reuse the same ~n*n GroupOps
-    instead of re-declaring them."""
+    IR), so repeated passes reuse the same ~n*n GroupOps instead of
+    re-declaring them."""
     hosts = _build(big).hosts
     return (gleam_workload(hosts, n),
             baseline_workload(hosts, n, transport))
@@ -198,7 +197,7 @@ def main(argv=None) -> int:
                          f"minutes; solver time stays in seconds)")
     ap.add_argument("--serial", action="store_true",
                     help="PR-1 behavior: fresh engine + solve per "
-                         "scenario instead of one batched run_many")
+                         "scenario instead of one batched run_workloads")
     args = ap.parse_args(argv)
     rows: list = []
     t0 = time.time()

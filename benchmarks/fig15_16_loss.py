@@ -11,7 +11,7 @@ recovery is exactly where a single seed is least trustworthy: which
 packets the fabric discards decides whether one go-back-N round or a
 timeout-recovery storm follows, so each (scheme, group, loss) point runs
 ``seeds`` independent repetitions and reports mean±std.  The
-repetitions are scenarios of ONE ``run_many`` batch on one engine — the
+repetitions are scenarios of ONE ``run_workloads`` batch on one engine — the
 engine quiesces between scenarios and gives scenario *i* the RNG stream
 derived from ``(seed, i)``, so the repetitions double as the seed axis
 and parallelize across worker processes (``workers``; see
@@ -42,7 +42,7 @@ import os
 
 from repro.core import fattree
 from repro.core.engine import make_engine
-from repro.core.workload import GroupOp
+from repro.core.workload import GroupOp, Workload
 
 NBYTES = 1 << 20
 LOSS_RATES = (0.0, 1e-6, 1e-5, 1e-4, 1e-3)
@@ -86,20 +86,17 @@ def _point(group, loss, transport):
 
 def _sweep_point(group, loss, transport, seeds, workers, timeout):
     """(mean, std, per-seed JCTs) over ``seeds`` independent repetitions
-    of one (scheme, group, loss) point, run as one run_many batch."""
+    of one (scheme, group, loss) point, run as one run_workloads batch."""
     topo = fattree.testbed(n_hosts=group, bw=200 * fattree.GBPS)
     eng = make_engine("packet", topo, loss_rate=loss, seed=11,
                       group_kw={"window": 512},
                       relay_kw={"window": 512})
     members = [f"h{i}" for i in range(group)]
-    recs = []
-
-    def scenario(e):
-        recs.append(e.stage(GroupOp("bcast", members, NBYTES,
-                                    transport=transport, chunks=8)))
-
-    eng.run_many([scenario] * seeds, timeout=timeout, workers=workers)
-    jcts = [r.jct(group - 1) for r in recs]
+    wl = Workload(ops=[GroupOp("bcast", members, NBYTES,
+                               transport=transport, chunks=8)])
+    recss = eng.run_workloads([wl] * seeds, timeout=timeout,
+                              workers=workers)
+    jcts = [recs[0].jct(group - 1) for recs in recss]
     mean = sum(jcts) / len(jcts)
     std = math.sqrt(sum((j - mean) ** 2 for j in jcts) / len(jcts))
     return mean, std, jcts
@@ -201,7 +198,7 @@ def run(rows, engine="packet", seeds=DEFAULT_SEEDS, workers=0,
     # STAGE: declare every point of the sweep before driving any of it
     gleam_pts = [(g, l) for g in sizes for l in LOSS_RATES]
     ring_pts = [(g, l) for g in sizes for l in RING_LOSS_RATES]
-    # BATCH: drive the sweep; each point is a seeds-wide run_many batch
+    # BATCH: drive the sweep; each point is a seeds-wide run_workloads batch
     # (lazy build-run-discard per point, see module docstring)
     jct_g = {(g, l): _sweep_point(g, l, "gleam", seeds, workers,
                                   120.0)[:2] for g, l in gleam_pts}

@@ -20,14 +20,14 @@ Every point runs on the packet engine (per-packet control plane: real
 MFT-update envelopes, QP re-arm, isolation) AND the flow engine
 (piecewise-membership segments), and the derived column carries the
 packet-vs-flow divergence — the acceptance gate is <= 10%.  Packet
-points of one group size run as a single ``run_many`` batch
+points of one group size run as a single ``run_workloads`` batch
 (``--workers`` aware).
 """
 from __future__ import annotations
 
 from repro.core import fattree
 from repro.core.engine import make_engine
-from repro.core.workload import GroupOp, MemberEvent
+from repro.core.workload import GroupOp, MemberEvent, Workload
 
 NBYTES = 1 << 20
 SIZES = (16, 64)
@@ -70,17 +70,10 @@ def _sweep(engine_name, group, workers, timeout=120.0):
     topo = fattree.testbed(n_hosts=group + SPARES)
     eng = make_engine(engine_name, topo)
     pts = _points(group)
-    recs = []
-
-    def scenario(op):
-        def fn(e):
-            recs.append(e.stage(op))
-        return fn
-
-    eng.run_many([scenario(op) for _, op in pts], timeout=timeout,
-                 workers=workers)
-    return {label: rec.jct(len(op.surviving_receivers()))
-            for (label, op), rec in zip(pts, recs)}
+    recss = eng.run_workloads([Workload(ops=[op]) for _, op in pts],
+                              timeout=timeout, workers=workers)
+    return {label: recs[0].jct(len(op.surviving_receivers()))
+            for (label, op), recs in zip(pts, recss)}
 
 
 def run(rows, engine="packet", workers=0, sizes=SIZES):

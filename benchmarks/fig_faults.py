@@ -33,7 +33,7 @@ row (``ring-dark``) exercises the relay-schedule repair path in
 baselines.py: a mid-ring relay goes dark and its children are spliced
 onto the dead relay's parent.
 
-Each point runs on a FRESH engine (no shared ``run_many`` fabric):
+Each point runs on a FRESH engine (no shared ``run_workloads`` fabric):
 Algorithm 4 balances tree edges across the agg planes by accumulated
 port utilization, so a point's tree — and therefore whether a given
 fault even touches it — would otherwise depend on its batch position.

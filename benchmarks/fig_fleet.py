@@ -16,7 +16,7 @@ engine, and reports:
   analytically; per-host QP counts must match the packet engine's
   measured census exactly — tests/test_fleet.py);
 - staging-cache hit rate for the flow sweep (the cached staging plane
-  is what makes the 1k-group point in BENCH_flowsim.json feasible);
+  is what makes the 1k-group point feasible);
 - one LRU-pressure point: registration churn (many tenants' groups
   registered through capacity-pinned switch tables), reporting the
   evictions/salvages the fabric eats while the newest tenant still

@@ -43,7 +43,7 @@ if __package__ in (None, ""):      # `python benchmarks/fig_matrix.py`
 
 from repro.core import fattree
 from repro.core.engine import make_engine
-from repro.core.workload import FaultEvent, GroupOp, MemberEvent
+from repro.core.workload import FaultEvent, GroupOp, MemberEvent, Workload
 
 NBYTES = 1 << 20
 CHURN_RATES = (0.0, 5e4)                # membership events / second
@@ -80,8 +80,7 @@ def cell_ops(hosts, n_groups, group, churn_rate, n_flaps,
              nbytes=NBYTES, spares=SPARES):
     """One matrix cell: ``n_groups`` contending bcasts over disjoint
     host blocks, each op carrying its cell's churn schedule and link
-    flaps.  Also the workload builder for ``tools/bench.py``'s
-    ``dyn_segments`` point (64 ops x 5 segments on a 1024-host tree)."""
+    flaps."""
     stride = group + spares
     assert n_groups * stride <= len(hosts), (n_groups, stride, len(hosts))
     ops = []
@@ -121,7 +120,7 @@ def _cells():
 def sweep_grid(engine_name, topo, n_groups, group, nbytes,
                workers=None, timeout=120.0, seeds=1, engine_kw=None):
     """The full (churn x flaps) grid for each loss level, one
-    ``run_many`` batch per engine pass; {(churn, loss, flaps): jct}.
+    ``run_workloads`` batch per engine pass; {(churn, loss, flaps): jct}.
 
     Lossy packet points average ``seeds`` independent repetitions —
     the packet engine SAMPLES drops and RTO stalls while the flow
@@ -137,14 +136,9 @@ def sweep_grid(engine_name, topo, n_groups, group, nbytes,
         all_ops = [cell_ops(topo.hosts, n_groups, group, churn, flaps,
                             nbytes=nbytes)
                    for churn, flaps in cells]
-        recss = []
-
-        def scenario(ops):
-            return lambda e: recss.append([e.stage(op) for op in ops])
-
         run_kw = {"workers": workers} if workers is not None else {}
-        eng.run_many([scenario(ops) for ops in all_ops] * reps,
-                     timeout=timeout, **run_kw)
+        recss = eng.run_workloads([Workload(ops=ops) for ops in all_ops]
+                                  * reps, timeout=timeout, **run_kw)
         for i, (cell, ops) in enumerate(zip(cells, all_ops)):
             # cell metric: MEAN over the cell's group JCTs — linear in
             # the per-op values, so the sampled packet mean and the

@@ -1,9 +1,9 @@
 """Chip smoke run: the flow engine's device solver on one TPU chip.
 
 Drives the main path users call — ``make_engine("flow", topo)`` then
-``run_workloads`` / ``run_many`` — at fleet scale, in one process that
-forks nothing, and checks every answer against the numpy reference
-engine ``flow-np``:
+``run_workloads`` — at fleet scale, in one process that forks nothing,
+and checks every answer against the numpy reference engine
+``flow-np``:
 
 - A. the 16,384-host fat-tree under a 10-tenant x 100-group fleet
   (1,128 ops), solved twice (cold, then warm) — the jitted epoch

@@ -124,7 +124,7 @@ def split_phases(wl) -> List["object"]:
 def run_phased(eng, wl, *, timeout: float = 120.0,
                workers: Optional[int] = None):
     """Run ``wl`` phase by phase (each phase one independent scenario,
-    all phases one ``run_many`` batch) and return ``(ops, recs)``
+    all phases one ``run_workloads`` batch) and return ``(ops, recs)``
     aligned — feed them to ``step_time`` / ``phase_stats``."""
     phases = split_phases(wl)
     results = eng.run_workloads(phases, timeout=timeout, workers=workers)

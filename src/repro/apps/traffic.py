@@ -21,7 +21,7 @@ Past the saturation rate rounds outlast their windows, the backlog
 term compounds, and the p999 hockey-stick appears — the queueing
 behaviour an open-loop harness exists to expose.  Because a round's
 time does not depend on its start, ALL windows run as one
-``run_many`` batch (serial == ``workers=N`` bit-identical on the
+``run_workloads`` batch (serial == ``workers=N`` bit-identical on the
 packet engine) and the chaining is applied analytically afterwards.
 """
 from __future__ import annotations
@@ -227,7 +227,7 @@ class ServingGenerator:
 
     def run(self, eng, spec: ArrivalSpec, *, timeout: float = 120.0,
             workers: Optional[int] = None) -> ServeReport:
-        """Run every window phase by phase — one flat ``run_many``
+        """Run every window phase by phase — one flat ``run_workloads``
         batch (each window's prefill / decode / kv-replicate phase is
         an independent scenario; requests inside a phase contend) —
         then fold the report."""

@@ -19,7 +19,6 @@ drivers (``core/workload.py`` defines the IR):
     rec  = eng.stage(GroupOp(op, members, nbytes,
                              transport=...))       # declarative staging
     eng.run()                                      # drive staged ops
-    eng.run_many([stage_a, stage_b, ...])          # batched scenarios
     recss = eng.run_workloads([wl_a, wl_b, ...])   # batched Workloads
 
 ``GroupOp.transport`` selects the strategy carrying the bytes — the
@@ -41,25 +40,20 @@ its contribution to the root, the many-to-one analogue of Algs. 2-3's
 feedback aggregation — followed by a bcast of the result over the
 op's transport.
 
-``run_many`` is the stage-then-batch API: each scenario callable stages
-ops on the engine, and all scenarios are then driven as INDEPENDENT
-experiments (no cross-scenario bandwidth sharing).  The flow engine
+``run_workloads`` is the stage-then-batch API: each ``Workload`` is
+one scenario, its ops are staged on the engine, and all scenarios are
+then driven as INDEPENDENT experiments (no cross-scenario bandwidth
+sharing), returning each workload's per-op records.  The flow engine
 solves every scenario in one vmapped executable
 (``flowsim_jax.solve_many``); the packet engine runs them serially,
 quiescing between scenarios (drain residual events, reset the clock
 and congestion state) so its serial fallback keeps the same
-independent-experiment semantics.  ``run_workloads`` is the IR-level
-wrapper: one ``Workload`` = one scenario, returning per-op records.
+independent-experiment semantics.
 
 Each staged op returns a ``metrics.MsgRecord``; after ``run()`` the
 record carries per-receiver delivery times and the sender CQE time, so
 JCT / IOPS / IO-latency are computed identically regardless of backend
 (see ``core/metrics.py`` for the §5 definitions).
-
-The pre-IR staging methods (``add_bcast`` / ``add_write`` /
-``add_unicast``) remain as deprecation shims that delegate to
-``stage`` — existing callers keep working for one release and see a
-``DeprecationWarning``.
 
 Engines are selected by name through ``make_engine`` — the same names
 the ``--engine`` flag of ``benchmarks/run.py`` accepts:
@@ -115,14 +109,15 @@ class SimEngine(Protocol):
         """Drive every staged operation to completion; returns sim time."""
         ...
 
-    def run_many(self, scenarios: Sequence[Callable[["SimEngine"], None]],
-                 timeout: float = 30.0,
-                 workers: Optional[int] = None) -> List[float]:
-        """Stage-then-batch: each callable stages ops on this engine;
+    def run_workloads(self, workloads: Sequence[Workload],
+                      timeout: float = 30.0,
+                      workers: Optional[int] = None
+                      ) -> List[List[MsgRecord]]:
+        """Stage-then-batch: each Workload is staged as one scenario;
         all scenarios then run as independent experiments (no
-        cross-scenario bandwidth sharing).  Returns the engine clock at
-        each scenario's completion — compute metrics from the records
-        (relative to their ``t_submit``), not from these values.
+        cross-scenario bandwidth sharing).  Returns the per-op records
+        of each workload, in op order; compute metrics from them
+        (relative to their ``t_submit``).
 
         ``workers`` requests scenario-level parallelism where the
         backend supports it (the packet engine forks worker processes;
@@ -131,20 +126,11 @@ class SimEngine(Protocol):
         path; results are identical either way."""
         ...
 
-    def run_workloads(self, workloads: Sequence[Workload],
-                      timeout: float = 30.0,
-                      workers: Optional[int] = None
-                      ) -> List[List[MsgRecord]]:
-        """Run each Workload as one independent scenario; returns the
-        per-op records of each workload, in op order."""
-        ...
-
 
 # ==================================================== shared staging glue
 
 class _WorkloadStaging:
-    """The engine-agnostic half of the contract: GroupOp dispatch,
-    Workload batching, and the deprecated ``add_*`` shims.
+    """The engine-agnostic half of the contract: GroupOp dispatch.
 
     Concrete engines provide the four lowering primitives:
     ``_stage_unicast`` / ``_stage_native`` (gleam bcast+write) /
@@ -163,50 +149,6 @@ class _WorkloadStaging:
         if transport.native:
             return self._stage_native(op)
         return self._stage_overlay(op, transport)
-
-    def run_workloads(self, workloads: Sequence[Workload],
-                      timeout: float = 30.0,
-                      workers: Optional[int] = None
-                      ) -> List[List[MsgRecord]]:
-        out: List[List[MsgRecord]] = [[] for _ in workloads]
-
-        def scenario(wl: Workload, recs: List[MsgRecord]):
-            def fn(eng):
-                recs.extend(eng.stage(op) for op in wl.ops)
-            return fn
-
-        self.run_many([scenario(wl, recs)
-                       for wl, recs in zip(workloads, out)], timeout,
-                      workers=workers)
-        return out
-
-    # ------------------------------------------------- deprecated shims
-
-    def _legacy(self, name: str, op: GroupOp) -> MsgRecord:
-        warnings.warn(
-            f"SimEngine.{name}() is deprecated; stage a workload.GroupOp "
-            f"via stage() instead", DeprecationWarning, stacklevel=3)
-        return self.stage(op)
-
-    def add_bcast(self, members: Sequence[str], nbytes: int, *,
-                  source: Optional[str] = None, key: int = 0) -> MsgRecord:
-        """Deprecated: ``stage(GroupOp('bcast', members, nbytes))``."""
-        return self._legacy("add_bcast", GroupOp(
-            "bcast", tuple(members), nbytes, source=source, key=key))
-
-    def add_write(self, members: Sequence[str], nbytes: int, *,
-                  source: Optional[str] = None, same_mr: bool = False,
-                  key: int = 0) -> MsgRecord:
-        """Deprecated: ``stage(GroupOp('write', members, nbytes))``."""
-        return self._legacy("add_write", GroupOp(
-            "write", tuple(members), nbytes, source=source,
-            same_mr=same_mr, key=key))
-
-    def add_unicast(self, src: str, dst: str, nbytes: int, *,
-                    key: int = 0) -> MsgRecord:
-        """Deprecated: ``stage(GroupOp('unicast', (src, dst), nbytes))``."""
-        return self._legacy("add_unicast", GroupOp(
-            "unicast", (src, dst), nbytes, key=key))
 
 
 # =========================================================== packet engine
@@ -253,7 +195,7 @@ class PacketEngine(_WorkloadStaging):
         self._pending: List[Tuple[MsgRecord, int, Optional[Callable]]] = []
         self._op_phys: Dict[str, float] = {}    # op-level fabric overrides
         self.last_run_stats: List = []
-        self.last_run_errors: List[str] = []    # run_many degradations
+        self.last_run_errors: List[str] = []    # parallel-run degradations
 
     # ------------------------------------------------------------ helpers
 
@@ -340,7 +282,7 @@ class PacketEngine(_WorkloadStaging):
         record waits for every *surviving* initial receiver (leavers
         and failed members are excused; joiners deliver from their
         join point but are not required to complete the in-flight
-        message), which keeps ``run_many``'s quiesce/fork machinery
+        message), which keeps ``run_workloads``'s quiesce/fork machinery
         working unchanged — events are scheduled relative to the
         submission instant inside the deferred thunk.
 
@@ -608,7 +550,7 @@ class PacketEngine(_WorkloadStaging):
         return (sim.events, sim.dropped, sim.tx_bytes, no_qp, rtx)
 
     def _run_scenario(self, index: int, staged: List, pending: List,
-                      timeout: float) -> Tuple[float, Dict[str, int]]:
+                      timeout: float) -> Dict[str, int]:
         """Drive one staged scenario on a quiesced fabric with its own
         deterministic RNG stream (seed ⊕ scenario index — never the
         residue of earlier scenarios' draws), so the result does not
@@ -621,20 +563,21 @@ class PacketEngine(_WorkloadStaging):
         sim.reseed_scenario(index)
         before = self._scenario_counters()
         self._staged, self._pending = staged, pending
-        end = self.run(timeout)
+        self.run(timeout)
         after = self._scenario_counters()
-        stats = {"events": after[0] - before[0],
-                 "dropped": after[1] - before[1],
-                 "tx_bytes": after[2] - before[2],
-                 "no_qp_drops": after[3] - before[3],
-                 "retransmitted": after[4] - before[4]}
-        return end, stats
+        return {"events": after[0] - before[0],
+                "dropped": after[1] - before[1],
+                "tx_bytes": after[2] - before[2],
+                "no_qp_drops": after[3] - before[3],
+                "retransmitted": after[4] - before[4]}
 
-    def run_many(self, scenarios: Sequence[Callable], timeout: float = 30.0,
-                 workers: Optional[int] = None) -> List[float]:
-        """Independent-experiment scenario batch.
+    def run_workloads(self, workloads: Sequence[Workload],
+                      timeout: float = 30.0,
+                      workers: Optional[int] = None
+                      ) -> List[List[MsgRecord]]:
+        """Independent-experiment scenario batch, one per Workload.
 
-        Every scenario is staged first (staging is silent: group
+        Every workload is staged first (staging is silent: group
         registration traffic runs, data submission thunks are
         deferred), then each scenario is driven on a quiesced fabric
         with the clock reset to 0 and a per-scenario RNG stream
@@ -660,25 +603,23 @@ class PacketEngine(_WorkloadStaging):
         via ``os._exit``, which has been robust in practice even with
         JAX loaded, but pass ``workers=None``/``1`` if your embedding
         process cannot tolerate ``fork``."""
+        recss: List[List[MsgRecord]] = []
         metas: List[Tuple[List, List]] = []
-        for stage in scenarios:
-            stage(self)
+        for wl in workloads:
+            recss.append([self.stage(op) for op in wl.ops])
             metas.append((self._staged, self._pending))
             self._staged, self._pending = [], []
         if workers is not None and workers == 0:
             workers = os.cpu_count() or 1
         workers = min(workers or 1, len(metas))
         if workers > 1 and hasattr(os, "fork"):
-            return self._run_many_parallel(metas, timeout, workers)
-        ends: List[float] = []
-        stats: List[Dict[str, int]] = []
-        for i, (staged, pending) in enumerate(metas):
-            end, st = self._run_scenario(i, staged, pending, timeout)
-            ends.append(end)
-            stats.append(st)
-        self.last_run_stats = stats
-        self.last_run_errors: List[str] = []
-        return ends
+            self._run_parallel(metas, timeout, workers)
+        else:
+            self.last_run_stats = [
+                self._run_scenario(i, staged, pending, timeout)
+                for i, (staged, pending) in enumerate(metas)]
+            self.last_run_errors = []
+        return recss
 
     def _restore_records(self, pending: List, rec_times: List) -> None:
         """Back-fill a scenario's caller-held records from a worker's
@@ -692,8 +633,8 @@ class PacketEngine(_WorkloadStaging):
             rec.t_deliver.update(deliver)
             rec.error = err
 
-    def _run_many_parallel(self, metas: List[Tuple[List, List]],
-                           timeout: float, workers: int) -> List[float]:
+    def _run_parallel(self, metas: List[Tuple[List, List]],
+                      timeout: float, workers: int) -> None:
         """Fork-based scenario parallelism (quiesce makes scenarios
         independent experiments, so they partition freely).  Each child
         inherits the fully-staged engine copy-on-write, drives scenarios
@@ -724,9 +665,9 @@ class PacketEngine(_WorkloadStaging):
                         for i in range(w, len(metas), workers):
                             staged, pending = metas[i]
                             try:
-                                end, st = self._run_scenario(
+                                st = self._run_scenario(
                                     i, staged, pending, timeout)
-                                frame = ("ok", i, end, st,
+                                frame = ("ok", i, st,
                                          [(r.msg_id, r.t_submit,
                                            r.t_sender_cqe,
                                            dict(r.t_deliver), r.error)
@@ -743,7 +684,6 @@ class PacketEngine(_WorkloadStaging):
             os.close(w_fd)                                # ---- parent
             children.append((pid, r_fd, w))
         sim = self.net.sim
-        ends = [0.0] * len(metas)
         stats: List[Optional[Dict[str, int]]] = [None] * len(metas)
         reported: set = set()
         errors: List[str] = []
@@ -765,9 +705,8 @@ class PacketEngine(_WorkloadStaging):
                         errors.append(
                             f"scenario {i} raised in worker {w}:\n{tb}")
                         continue
-                    _, i, end, st, rec_times = frame
+                    _, i, st, rec_times = frame
                     reported.add(i)
-                    ends[i] = end
                     stats[i] = st
                     self._restore_records(metas[i][1], rec_times)
                     sim.events += st["events"]
@@ -784,16 +723,13 @@ class PacketEngine(_WorkloadStaging):
         self.last_run_errors = errors
         if retry:
             warnings.warn(
-                f"parallel run_many degraded: re-running scenarios "
+                f"parallel run_workloads degraded: re-running scenarios "
                 f"{retry} serially ({len(errors)} worker report(s) — "
                 f"see last_run_errors)", RuntimeWarning)
             for i in retry:
                 staged, pending = metas[i]
-                end, st = self._run_scenario(i, staged, pending, timeout)
-                ends[i] = end
-                stats[i] = st
+                stats[i] = self._run_scenario(i, staged, pending, timeout)
         self.last_run_stats = stats
-        return ends
 
 
 # ============================================================= flow engine
@@ -825,17 +761,15 @@ class FlowEngine(_WorkloadStaging):
                  relay_kw: Optional[dict] = None, loss_rate: float = 0.0,
                  ecn_backlog: float = math.inf, seed: Optional[int] = None,
                  staging_cache: bool = True,
-                 segment_solver: Optional[str] = None, **sim_kw):
+                 segment_solver: str = "batched", **sim_kw):
         self.topo = topo
         # ``segment_solver`` picks how dynamic ops' per-segment fairness
         # snapshots are solved: "batched" (default) collects every
-        # segment problem across the run/run_many batch and solves them
-        # in a few bucketed ``segment_rates_many`` calls (device-
+        # segment problem across the run/run_workloads batch and solves
+        # them in a few bucketed ``segment_rates_many`` calls (device-
         # resident on the JAX backend); "legacy" keeps the per-segment
-        # ``static_maxmin_loops`` closure — the before-leg of the
-        # ``dyn_segments`` benchmark.  ``REPRO_SEGMENTS`` overrides.
-        segment_solver = segment_solver or \
-            os.environ.get("REPRO_SEGMENTS", "batched")
+        # ``static_maxmin_loops`` closure — the tests' reference for
+        # the batched solver.
         if segment_solver not in ("batched", "legacy"):
             raise ValueError(f"segment_solver {segment_solver!r}; "
                              "choose 'batched' or 'legacy'")
@@ -938,72 +872,6 @@ class FlowEngine(_WorkloadStaging):
     def staging_stats(self) -> Dict[str, float]:
         """Hit/miss telemetry of this engine's staging cache."""
         return self._sim.cache.stats()
-
-    def stage(self, op: GroupOp) -> MsgRecord:
-        # Identity fast path: figure sweeps reuse the exact GroupOp
-        # objects pass after pass (fig14 memoizes its Workload IR), so
-        # a replay row keyed on the op's identity skips transport
-        # dispatch and layout-key hashing entirely.  Rows live in the
-        # staging cache's ``misc`` store — fingerprint invalidation
-        # drops them with every other artifact — hold the op reference
-        # (a recycled ``id()`` can never alias) and the engine config
-        # key (two engines with different loss tuning over one fabric
-        # never replay each other's rows).
-        if self.staging_cache and self._cfg_key is not None:
-            rows = self._sim.cache.sync().misc.get("oprows")
-            if rows is not None:
-                row = rows.get(id(op))
-                if row is not None and row[0] is op \
-                        and row[1] == self._cfg_key:
-                    _, _, links, volume, deliver, extra, loss, nb = row
-                    self._sim.cache.hits += 1
-                    return self._stage(links, volume, self._new_rec(nb),
-                                       deliver, extra, loss)
-        rec = super().stage(op)
-        self._note_oprow(op)
-        return rec
-
-    def _note_oprow(self, op: GroupOp) -> None:
-        """Record an identity replay row for ``stage``'s fast path.
-
-        Only the flat single-flow lowerings (unicast, native bcast /
-        write) are replayable from one row; overlay / allreduce /
-        dynamic ops keep the full path (their op-level layout cache
-        already carries the expensive parts)."""
-        if op.op == "unicast":
-            okey = self._op_key(
-                "uni", (op.members[0], op.members[1], op.nbytes, op.key))
-            if okey is None:
-                return
-            ent = self._sim.cache.ops.get(okey)
-            if ent is None:
-                return
-            links, deliver, prop, loss = ent
-            row = (op, self._cfg_key, links, wire_bytes(op.nbytes),
-                   deliver, prop, loss, op.nbytes)
-        elif op.op in ("bcast", "write") \
-                and get_transport(op.transport).native:
-            volume = float(wire_bytes(op.nbytes))
-            if op.op == "write" and not op.same_mr:
-                volume += wire_bytes(12 * (len(op.members) - 1) + 16)
-            source = op.source or op.members[0]
-            okey = self._op_key(
-                "mcast",
-                (source, tuple(op.members), op.nbytes, float(volume),
-                 op.key), op)
-            if okey is None:
-                return
-            ent = self._sim.cache.ops.get(okey)
-            if ent is None:
-                return
-            links, deliver, back, loss = ent
-            row = (op, self._cfg_key, links, volume, deliver, back, loss,
-                   op.nbytes)
-        else:
-            return
-        rows = self._sim.cache.misc.setdefault("oprows", {})
-        if len(rows) < staging.MAX_ENTRIES:
-            rows[id(op)] = row
 
     def _op_key(self, kind: str, fields: tuple,
                 op: Optional[GroupOp] = None) -> Optional[tuple]:
@@ -1765,53 +1633,6 @@ class FlowEngine(_WorkloadStaging):
             self._sim.warm_paths(sorted(pairs))
             self._sim.warm_latencies(sorted(lats))
 
-    def run_workloads(self, workloads: Sequence[Workload],
-                      timeout: float = 30.0,
-                      workers: Optional[int] = None
-                      ) -> List[List[MsgRecord]]:
-        out: List[List[MsgRecord]] = [[] for _ in workloads]
-        fast_ok = self.staging_cache and self._cfg_key is not None
-
-        # Scenario closures replay ``stage``'s identity fast path with
-        # the per-op bookkeeping hoisted out of the loop.  The hoist is
-        # only sound for all-static workloads: a dynamic op's fault
-        # staging can move the fingerprint mid-scenario, so those keep
-        # the per-op ``sync`` inside ``stage``.
-        def scenario(wl: Workload, recs: List[MsgRecord]):
-            dyn = any(op.events or op.faults for op in wl.ops)
-
-            def fn(eng):
-                rows = self._sim.cache.sync().misc.get("oprows") \
-                    if fast_ok and not dyn else None
-                if rows is None:
-                    recs.extend(self.stage(op) for op in wl.ops)
-                    return
-                cfg = self._cfg_key
-                cache = self._sim.cache
-                staged = self._staged
-                now = self.now
-                for op in wl.ops:
-                    row = rows.get(id(op))
-                    if row is None or row[0] is not op or row[1] != cfg:
-                        recs.append(self.stage(op))
-                        continue
-                    _, _, links, volume, deliver, extra, loss, nb = row
-                    rec = MsgRecord(self._next_msg, nb, now)
-                    self._next_msg += 1
-                    cache.hits += 1
-                    staged.append((links, volume, rec, deliver, extra,
-                                   loss, None))
-                    recs.append(rec)
-            return fn
-
-        with self._sim.span("flow.run_workloads"):
-            if self.staging_cache:
-                self._warm_workloads(workloads)
-            self.run_many([scenario(wl, recs)
-                           for wl, recs in zip(workloads, out)], timeout,
-                          workers=workers)
-        return out
-
     # ------------------------------------------------- dynamic segments
 
     def _solve_segments(self, scenarios: Sequence[List[tuple]]) -> None:
@@ -1951,46 +1772,53 @@ class FlowEngine(_WorkloadStaging):
         self._clear_dynamics()
         return self.now
 
-    def run_many(self, scenarios: Sequence[Callable], timeout: float = 30.0,
-                 workers: Optional[int] = None) -> List[float]:
-        """Batched scenarios: every scenario is an isolated fabric (no
-        cross-scenario bandwidth sharing) whose clock starts at the
-        engine's current ``now``.  On the JAX solver the whole batch is
-        ONE vmapped solve (``solve_many``); the numpy solver falls back
-        to per-scenario solves.  ``workers`` is accepted for contract
-        uniformity and ignored — the vmapped solve already exploits all
-        device parallelism.  Returns per-scenario end times; the engine
-        clock advances to the latest one."""
+    def run_workloads(self, workloads: Sequence[Workload],
+                      timeout: float = 30.0,
+                      workers: Optional[int] = None
+                      ) -> List[List[MsgRecord]]:
+        """Batched scenarios, one per Workload: every scenario is an
+        isolated fabric (no cross-scenario bandwidth sharing) whose
+        clock starts at the engine's current ``now``.  On the JAX
+        solver the whole batch is ONE vmapped solve (``solve_many``);
+        the numpy solver falls back to per-scenario solves.
+        ``workers`` is accepted for contract uniformity and ignored —
+        the vmapped solve already exploits all device parallelism.  The
+        engine clock advances to the latest scenario's end."""
         if self._staged or self._post:
             raise RuntimeError("pending staged ops; run() them first or "
-                               "stage them inside a scenario")
+                               "stage them in a workload")
         sim = self._sim
-        t0 = self.now
-        metas = []
-        with sim.span("flow.stage"):
-            for stage in scenarios:
-                stage(self)
-                metas.append((self._staged, self._post))
-                self._staged, self._post = [], []
-        with sim.span("flow.flows"):
-            sim.flows, sim.now = [], 0.0
-            epoch_flows = [sim.add_many((links, volume, loss)
-                                        for links, volume, _, _, _, loss, _
-                                        in staged)
-                           for staged, _ in metas]
-        if hasattr(sim, "solve_many"):           # vmapped batch (JAX)
-            sim.solve_many(epoch_flows)
-        else:                                    # numpy: epoch-serial
-            for flows in epoch_flows:
-                sim.flows, sim.now = flows, 0.0
-                sim.run()
-        self._solve_segments([staged for staged, _ in metas])
-        with sim.span("flow.fill"):
-            ends = [self._finalize(staged, post, flows, t0)
-                    for (staged, post), flows in zip(metas, epoch_flows)]
-        self.now = max([self.now] + ends)
-        self._clear_dynamics()
-        return ends
+        with sim.span("flow.run_workloads"):
+            if self.staging_cache:
+                self._warm_workloads(workloads)
+            t0 = self.now
+            recss: List[List[MsgRecord]] = []
+            metas = []
+            with sim.span("flow.stage"):
+                for wl in workloads:
+                    recss.append([self.stage(op) for op in wl.ops])
+                    metas.append((self._staged, self._post))
+                    self._staged, self._post = [], []
+            with sim.span("flow.flows"):
+                sim.flows, sim.now = [], 0.0
+                epoch_flows = [sim.add_many((links, volume, loss)
+                                            for links, volume, _, _, _,
+                                            loss, _ in staged)
+                               for staged, _ in metas]
+            if hasattr(sim, "solve_many"):       # vmapped batch (JAX)
+                sim.solve_many(epoch_flows)
+            else:                                # numpy: epoch-serial
+                for flows in epoch_flows:
+                    sim.flows, sim.now = flows, 0.0
+                    sim.run()
+            self._solve_segments([staged for staged, _ in metas])
+            with sim.span("flow.fill"):
+                ends = [self._finalize(staged, post, flows, t0)
+                        for (staged, post), flows in zip(metas,
+                                                          epoch_flows)]
+            self.now = max([self.now] + ends)
+            self._clear_dynamics()
+        return recss
 
 
 # ================================================================= factory

@@ -139,7 +139,7 @@ class LinkMap:
 
         Memoized in the shared staging cache: large-scale staging
         (fig14 meshes both tree links AND per-receiver latency paths)
-        asks for the same pair repeatedly, and `run_many` sweeps ask
+        asks for the same pair repeatedly, and `run_workloads` sweeps ask
         again per scenario.  A miss between two single-homed hosts is
         composed from the switch-pair memo (``_route``).
         """

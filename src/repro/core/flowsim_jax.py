@@ -30,7 +30,7 @@ one compiled executable — a fig14 sweep or a fig12/13 message-size
 ladder compiles once, not once per point.  ``solve_many`` goes further:
 independent epochs are padded to a common bucket, stacked, and solved
 by ONE ``jax.vmap``-ed executable (the batched path behind
-``SimEngine.run_many``); a byte-budget planner (``_plan_batches``)
+``SimEngine.run_workloads``); a byte-budget planner (``_plan_batches``)
 splits shape-incompatible epochs so a 32k-flow unicast mesh is never
 padded to a multicast tree's hop count.
 
@@ -319,9 +319,6 @@ class JaxFlowSim(LinkMap):
     batches in one vmapped executable.
     """
 
-    #: class-level toggle so benchmarks can measure the unbucketed
-    #: (PR-1 style, jit-per-exact-shape) solver against the same code
-    bucketing = True
     F_BUCKET_MIN = 16
     H_BUCKET_MIN = 8
 
@@ -374,10 +371,7 @@ class JaxFlowSim(LinkMap):
     def _shape(self, flows: Sequence[Flow]):
         n = len(flows)
         h = max(len(f.links) for f in flows)
-        if self.bucketing:
-            return _bucket(n, self.F_BUCKET_MIN), \
-                _bucket(h, self.H_BUCKET_MIN)
-        return n, h
+        return _bucket(n, self.F_BUCKET_MIN), _bucket(h, self.H_BUCKET_MIN)
 
     def _pack_loss(self, flows: Sequence[Flow], dtype, f_pad: int):
         """(q, wsq, wnd, ecn) per-flow loss-model rows, each (f_pad,).
@@ -587,8 +581,7 @@ class JaxFlowSim(LinkMap):
         for i, (sets, _) in enumerate(problems):
             f, h = len(sets), max(len(ls) for ls in sets)
             shapes[i] = (_bucket(f, self.F_BUCKET_MIN),
-                         _bucket(h, self.H_BUCKET_MIN)) \
-                if self.bucketing else (f, h)
+                         _bucket(h, self.H_BUCKET_MIN))
         batches = self._plan_batches(problems, list(range(len(problems))),
                                      shapes)
         solve = _seg_solver()

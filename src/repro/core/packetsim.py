@@ -320,7 +320,7 @@ class PacketSim:
     def retire_qp(self, qp) -> None:
         """Permanently decommission a QP silenced by ``host_dark``: the
         scenario reset (``clear_faults``) revives darkened QPs so OTHER
-        groups sharing the host keep working across ``run_many``
+        groups sharing the host keep working across ``run_workloads``
         scenarios — but the faulted group's own QP must never come
         back.  Its group excised the member (re-election / teardown
         confirm) and a revived sender would replay its frozen
@@ -333,7 +333,7 @@ class PacketSim:
 
     def clear_faults(self) -> None:
         """Undo every injected fault (scenario quiesce).  Reactivation
-        matters: cached static groups share host QPs across run_many
+        matters: cached static groups share host QPs across run_workloads
         scenarios, so a QP silenced by host_dark must come back."""
         if not self._faulted:
             return
@@ -359,7 +359,7 @@ class PacketSim:
         """Give scenario ``index`` its own deterministic RNG stream,
         derived from the constructor seed only — never from how many
         draws earlier scenarios consumed.  This is what makes serial and
-        process-parallel ``run_many`` bit-identical (and doubles as the
+        process-parallel ``run_workloads`` bit-identical (and doubles as the
         multi-seed axis of the loss sweeps)."""
         self.rng.seed(self.seed ^ (0x9E3779B97F4A7C15 * (index + 1)))
 

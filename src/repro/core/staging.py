@@ -2,7 +2,7 @@
 
 Staging (tree derivation, path walks, per-receiver latencies, per-op
 flow layouts) is the flow engine's hot path once the solver is batched:
-a `run_many` sweep across seeds/loss-points/arrival-draws re-derives
+a `run_workloads` sweep across seeds/loss-points/arrival-draws re-derives
 each group's artifacts per scenario unless they are cached.  One
 ``StagingCache`` lives on each ``Topology`` (``StagingCache.of``), so
 every engine instance built over the same fabric — including the fresh

@@ -554,7 +554,7 @@ class GleamSwitch:
 
         ``group_ip`` scopes the teardown to ONE group's table: the
         fault plane drives this per group (each group's fault plan
-        carries its own events), which also keeps batched ``run_many``
+        carries its own events), which also keeps batched ``run_workloads``
         scenarios independent experiments — a fault injected by one
         scenario must not prune another scenario's staged tables."""
         emits: List[Emit] = []

@@ -32,9 +32,6 @@ Both simulation engines consume the IR through one entry point:
                              transport="ring"))   # -> MsgRecord
     recs = eng.run_workloads([wl_a, wl_b])        # batched scenarios
 
-which replaces the deprecated per-op staging methods (``add_bcast`` /
-``add_write`` / ``add_unicast`` — thin shims now delegate here).
-
 The IR is plain data: ``to_dict`` / ``from_dict`` round-trip a
 ``Workload`` through JSON-compatible dicts, so sweeps can be declared
 in config files and checked into reference fixtures

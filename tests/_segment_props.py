@@ -25,7 +25,7 @@ from repro.core import fattree
 from repro.core.engine import make_engine
 from repro.core.flowsim import (FlowSim, LossParams, static_maxmin,
                                 static_maxmin_loops)
-from repro.core.workload import GroupOp, MemberEvent
+from repro.core.workload import GroupOp, MemberEvent, Workload
 
 NBYTES = 1 << 18
 SEG_TOL = 1e-6              # jax-vs-oracle acceptance bound
@@ -91,12 +91,8 @@ def _records(engine: str, mode: str, ops, loss_rate: float = 0.0,
     eng = make_engine(engine, fattree.testbed(n_hosts=14),
                       segment_solver=mode, **kw)
     if scenarios:                       # one op per isolated scenario
-        recs = []
-
-        def scenario(op):
-            return lambda e: recs.append(e.stage(op))
-
-        eng.run_many([scenario(op) for op in ops], timeout=60.0)
+        recs = [r for (r,) in eng.run_workloads(
+            [Workload("seg", [op]) for op in ops], timeout=60.0)]
     else:                               # all ops contend in one fabric
         recs = [eng.stage(op) for op in ops]
         eng.run()

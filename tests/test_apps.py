@@ -7,7 +7,7 @@ ISSUE-8 satellite checklist:
   MoE, hybrid-SSM), anchored on ``count_params(model_defs(cfg))`` —
   the analytic mirror must track the real tensor shapes exactly;
 - seeded Poisson arrivals are deterministic (and specs round-trip);
-- packet ``run_many`` serial == ``workers=N`` bit-identical on app
+- packet ``run_workloads`` serial == ``workers=N`` bit-identical on app
   workloads;
 - packet-vs-flow parity <= 10% on a small phase-split train step.
 """
@@ -230,7 +230,7 @@ def test_train_step_packet_flow_parity(transport):
 
 
 def test_packet_serial_matches_workers():
-    """App batches ride packet run_many: forked workers must be
+    """App batches ride packet run_workloads: forked workers must be
     bit-identical to the serial fallback."""
     wl = _small_train_wl()
     phases = split_phases(wl)

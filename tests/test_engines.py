@@ -28,7 +28,7 @@ def two_pod_fat_tree():
 
 def bcast_jct(engine_name, topo, members, nbytes):
     eng = make_engine(engine_name, topo)
-    rec = eng.add_bcast(members, nbytes)
+    rec = eng.stage(GroupOp("bcast", members, nbytes))
     eng.run(timeout=60.0)
     jct = rec.jct(len(members) - 1)
     assert jct != float("inf"), f"{engine_name} bcast did not complete"
@@ -161,8 +161,8 @@ def test_concurrent_groups_share_fabric_consistently():
     jcts = {}
     for name in ("packet", "flow"):
         eng = make_engine(name, fattree.testbed(n_hosts=5))
-        ra = eng.add_bcast(members_a, 1 << 20)
-        rb = eng.add_bcast(members_b, 1 << 20)
+        ra = eng.stage(GroupOp("bcast", members_a, 1 << 20))
+        rb = eng.stage(GroupOp("bcast", members_b, 1 << 20))
         eng.run(timeout=60.0)
         jcts[name] = (ra.jct(2), rb.jct(2))
     for name, (ja, jb) in jcts.items():
@@ -178,8 +178,9 @@ def test_concurrent_groups_share_fabric_consistently():
 def test_unicast_and_write_complete_on_both_engines():
     for name in ("packet", "flow"):
         eng = make_engine(name, fattree.testbed())
-        ru = eng.add_unicast("h0", "h1", 256 << 10)
-        rw = eng.add_write(["h0", "h1", "h2", "h3"], 256 << 10)
+        ru = eng.stage(GroupOp("unicast", ("h0", "h1"), 256 << 10))
+        rw = eng.stage(GroupOp("write", ("h0", "h1", "h2", "h3"),
+                               256 << 10))
         eng.run(timeout=60.0)
         assert ru.jct(1) != float("inf"), name
         assert rw.jct(3) != float("inf"), name
@@ -202,9 +203,10 @@ def test_flow_engine_epochs_are_sequential():
     """Records of a second staged batch start no earlier than the first
     batch's completion (the engine's clock advances)."""
     eng = FlowEngine(fattree.testbed(), backend="auto")
-    r1 = eng.add_bcast(["h0", "h1", "h2", "h3"], 1 << 20)
+    op = GroupOp("bcast", ("h0", "h1", "h2", "h3"), 1 << 20)
+    r1 = eng.stage(op)
     eng.run()
-    r2 = eng.add_bcast(["h0", "h1", "h2", "h3"], 1 << 20)
+    r2 = eng.stage(op)
     eng.run()
     assert r2.t_submit >= max(r1.t_deliver.values())
     assert r2.jct(3) == pytest.approx(r1.jct(3), rel=1e-6)
@@ -215,9 +217,9 @@ def test_packet_engine_source_rotation():
     source must not re-register and must still deliver."""
     eng = PacketEngine(fattree.testbed())
     members = ["h0", "h1", "h2", "h3"]
-    r0 = eng.add_bcast(members, 64 << 10)
+    r0 = eng.stage(GroupOp("bcast", members, 64 << 10))
     eng.run()
-    r1 = eng.add_bcast(members, 64 << 10, source="h2")
+    r1 = eng.stage(GroupOp("bcast", members, 64 << 10, source="h2"))
     eng.run()
     assert r0.jct(3) != float("inf")
     assert r1.jct(3) != float("inf")

@@ -2,7 +2,7 @@
 validation, per-class recovery on the live fabric (link/switch/host/
 master), packet-vs-flow recovery parity, the dead-source sever cascade,
 bounded-retry endpoint semantics, and fault scenarios under the
-parallel ``run_many`` path.
+parallel ``run_workloads`` path.
 
 The deterministic halves of the two headline properties live here (the
 hypothesis twins are in ``test_protocol_properties`` and share the
@@ -20,7 +20,7 @@ from repro.core.faults import (DEFAULT_FAULT_RETRIES, FAULT_CHOICES,
                                FaultEvent, fault_downs,
                                validate_fault_plan)
 from repro.core.gleam import GleamNetwork
-from repro.core.workload import GroupOp
+from repro.core.workload import GroupOp, Workload
 
 from _fault_props import run_bounded_retry_case, run_reelection_case
 
@@ -347,7 +347,7 @@ class TestBoundedRetry:
         assert g.qps["h0"].max_retries is None
 
 
-# ================================================== run_many + faults
+# ============================================= run_workloads + faults
 
 def test_fault_scenarios_serial_equals_workers():
     """Fault scenarios survive the fork/replay parallel path: same
@@ -355,24 +355,15 @@ def test_fault_scenarios_serial_equals_workers():
     scenario makes the comparison exact)."""
     def _batch(workers):
         eng = make_engine("packet", fattree.fig4(), seed=7)
-        recs = []
-
-        def clean(e):
-            recs.append(e.stage(GroupOp("bcast", MEMBERS, NBYTES)))
-
-        def crash(e):
-            recs.append(e.stage(GroupOp(
-                "bcast", MEMBERS, NBYTES,
-                faults=(FaultEvent("master_crash", AT),))))
-
-        def dark(e):
-            recs.append(e.stage(GroupOp(
-                "bcast", MEMBERS, NBYTES,
-                faults=(FaultEvent("host_gone_dark", AT,
-                                   node="h3"),))))
-
-        eng.run_many([clean, crash, dark], timeout=60.0,
-                     workers=workers)
+        clean = GroupOp("bcast", MEMBERS, NBYTES)
+        crash = GroupOp("bcast", MEMBERS, NBYTES,
+                        faults=(FaultEvent("master_crash", AT),))
+        dark = GroupOp("bcast", MEMBERS, NBYTES,
+                       faults=(FaultEvent("host_gone_dark", AT,
+                                          node="h3"),))
+        recs = [r for (r,) in eng.run_workloads(
+            [Workload("f", [op]) for op in (clean, crash, dark)],
+            timeout=60.0, workers=workers)]
         return [(sorted(r.t_deliver.items()), r.t_sender_cqe, r.error)
                 for r in recs]
 

@@ -56,7 +56,7 @@ GRID = [(t, g, l) for t in FID_TRANSPORTS for g in FID_GROUPS
     ids=[f"{t}-g{g}-loss{l:g}" for t, g, l in GRID])
 def test_flow_jct_matches_packet_ground_truth(transport, group, loss):
     """Acceptance: flow vs packet JCT <= 15% at every calibration-grid
-    point, the packet side a live multi-seed ``run_many`` mean."""
+    point, the packet side a live multi-seed ``run_workloads`` mean."""
     jf = flow_jct(group, loss, transport)
     jp = packet_gt(group, loss, transport)
     assert jf == pytest.approx(jp, rel=ZERO_TOL if loss == 0.0 else TOL)

@@ -8,7 +8,7 @@
 - float64 auto-promotion once volumes exceed the float32 safe-integer
   range, pinned against a float64 numpy reference, with float64's
   own freeze and completion slacks;
-- ``run_many`` batched scenarios == serial runs on fresh engines;
+- ``run_workloads`` batched scenarios == serial runs on fresh engines;
 - solvers never clobber the staged ``Flow.volume``;
 - the float64 filling's int32 link counts against a copy of the
   float-sum round, and no float64 scatter in the float64 solver.
@@ -24,6 +24,7 @@ import pytest
 from repro.core import fattree
 from repro.core.engine import make_engine
 from repro.core.flowsim import FlowSim
+from repro.core.workload import GroupOp, Workload
 
 import jax
 import jax.numpy as jnp
@@ -139,23 +140,6 @@ def test_same_bucket_hits_jit_cache():
     assert _jit_cache_size() == before + 1
 
 
-def test_unbucketed_mode_recompiles_per_shape():
-    """The PR-1 behavior is still reachable (bench A/B) and differs."""
-    topo = fattree.testbed(n_hosts=8)
-
-    def solve(n_flows):
-        sim = JaxFlowSim(topo)
-        sim.bucketing = False
-        for i in range(n_flows):
-            sim.add(sim.unicast_links("h0", f"h{1 + i % 7}"), 1e6)
-        sim.run()
-
-    solve(18)
-    before = _jit_cache_size()
-    solve(19)                               # exact shapes -> recompile
-    assert _jit_cache_size() == before + 1
-
-
 # ==================================================== float64 promotion
 
 def test_small_volumes_solve_in_float32():
@@ -216,107 +200,100 @@ def test_f32_boundary_is_safe_integer_range():
     assert sim2.solve_dtype == np.float64
 
 
-# ================================================= run_many / solve_many
+# ============================================ run_workloads / solve_many
 
-def _stage_pair(recs):
-    def a(eng):
-        recs.append(eng.add_bcast(["h0", "h1", "h2"], 1 << 20))
-
-    def b(eng):
-        recs.append(eng.add_bcast(["h0", "h3", "h4"], 2 << 20))
-        recs.append(eng.add_unicast("h1", "h2", 1 << 20))
+def _pair_workloads():
+    a = Workload("a")
+    a.bcast(["h0", "h1", "h2"], 1 << 20)
+    b = Workload("b")
+    b.bcast(["h0", "h3", "h4"], 2 << 20)
+    b.unicast("h1", "h2", 1 << 20)
     return [a, b]
 
 
-@pytest.mark.parametrize("engine", ["flow", "flow-np"])
-def test_run_many_matches_serial_fresh_engines(engine):
-    recs: list = []
-    eng = make_engine(engine, fattree.testbed(n_hosts=5))
-    ends = eng.run_many(_stage_pair(recs))
-    assert len(ends) == 2
-    got = [recs[0].jct(2), recs[1].jct(2), recs[2].jct(1)]
+def _jcts(recs):
+    return [r.jct(len(r.t_deliver)) for r in recs]
 
-    e1 = make_engine(engine, fattree.testbed(n_hosts=5))
-    r1 = e1.add_bcast(["h0", "h1", "h2"], 1 << 20)
-    e1.run()
-    e2 = make_engine(engine, fattree.testbed(n_hosts=5))
-    r2 = e2.add_bcast(["h0", "h3", "h4"], 2 << 20)
-    r3 = e2.add_unicast("h1", "h2", 1 << 20)
-    e2.run()
-    want = [r1.jct(2), r2.jct(2), r3.jct(1)]
+
+@pytest.mark.parametrize("engine", ["flow", "flow-np", "packet"])
+def test_run_workloads_matches_serial_fresh_engines(engine):
+    """A batch of two workloads gives each one's JCTs as a fresh engine
+    that stages its ops and ``run()``s them alone."""
+    wls = _pair_workloads()
+    eng = make_engine(engine, fattree.testbed(n_hosts=5))
+    got = [j for recs in eng.run_workloads(wls, timeout=60.0)
+           for j in _jcts(recs)]
+    want = []
+    for wl in wls:
+        solo = make_engine(engine, fattree.testbed(n_hosts=5))
+        recs = [solo.stage(op) for op in wl.ops]
+        solo.run(timeout=60.0)
+        want += _jcts(recs)
+    assert len(got) == 3
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
-def test_run_many_scenarios_are_isolated():
+def test_run_workloads_scenarios_are_isolated():
     """Identical scenarios staged together must NOT share bandwidth:
     each must match its solo JCT (unlike one run() batch, which halves
     the shared sender link)."""
-    members = ["h0", "h1", "h2", "h3"]
+    members = ("h0", "h1", "h2", "h3")
+    op = GroupOp("bcast", members, 1 << 20)
     solo_eng = make_engine("flow", fattree.testbed())
-    solo = solo_eng.add_bcast(members, 1 << 20)
+    solo = solo_eng.stage(op)
     solo_eng.run()
     eng = make_engine("flow", fattree.testbed())
-    recs = []
-    eng.run_many([lambda e: recs.append(e.add_bcast(members, 1 << 20)),
-                  lambda e: recs.append(e.add_bcast(members, 1 << 20))])
-    for r in recs:
+    recss = eng.run_workloads([Workload("a", [op]), Workload("b", [op])])
+    for (r,) in recss:
         assert r.jct(3) == pytest.approx(solo.jct(3), rel=1e-6)
 
 
-def test_run_many_heterogeneous_epochs_split_batches():
+def test_run_workloads_heterogeneous_epochs_split_batches():
     """A unicast-mesh epoch (many flows, short paths) next to a
     multicast epoch (few flows, long link lists) exercises the batch
     planner; results must still match serial runs."""
     topo = small_fat_tree()
     hosts = topo.hosts
-    eng = make_engine("flow", topo)
-    mesh_recs: list = []
-    tree_recs: list = []
-
-    def mesh(e):
-        for i, a in enumerate(hosts):
-            for b in hosts[i + 1:]:
-                mesh_recs.append(e.add_unicast(a, b, 1 << 18, key=i))
-
-    def tree(e):
-        tree_recs.append(e.add_bcast(list(hosts), 4 << 20))
-
-    eng.run_many([mesh, tree])
-    e1 = make_engine("flow", small_fat_tree())
-    ref_recs: list = []
+    mesh = Workload("mesh")
     for i, a in enumerate(hosts):
         for b in hosts[i + 1:]:
-            ref_recs.append(e1.add_unicast(a, b, 1 << 18, key=i))
+            mesh.unicast(a, b, 1 << 18, key=i)
+    tree = Workload("tree")
+    tree.bcast(list(hosts), 4 << 20)
+    mesh_recs, tree_recs = make_engine("flow", topo).run_workloads(
+        [mesh, tree])
+    e1 = make_engine("flow", small_fat_tree())
+    ref_recs = [e1.stage(op) for op in mesh.ops]
     e1.run()
     e2 = make_engine("flow", small_fat_tree())
-    rt = e2.add_bcast(list(hosts), 4 << 20)
+    rt = e2.stage(tree.ops[0])
     e2.run()
     got = [r.jct(1) for r in mesh_recs] + [tree_recs[0].jct(len(hosts) - 1)]
     want = [r.jct(1) for r in ref_recs] + [rt.jct(len(hosts) - 1)]
     np.testing.assert_allclose(got, want, rtol=1e-4)
 
 
-def test_run_many_rejects_pending_staged_ops():
+def test_run_workloads_rejects_pending_staged_ops():
     eng = make_engine("flow", fattree.testbed())
-    eng.add_bcast(["h0", "h1"], 1 << 20)
+    eng.stage(GroupOp("bcast", ("h0", "h1"), 1 << 20))
     with pytest.raises(RuntimeError):
-        eng.run_many([lambda e: None])
+        eng.run_workloads([Workload("empty")])
 
 
-def test_packet_engine_run_many_serial_fallback():
+def test_packet_engine_run_workloads_serial_fallback():
     """Serial scenarios run as independent experiments: the fabric
     quiesces and the clock resets between them (matching the flow
-    engine's isolated-scenario semantics), so each end time measures
+    engine's isolated-scenario semantics), so each record measures
     its own scenario, not the accumulated history."""
     eng = make_engine("packet", fattree.testbed())
-    recs: list = []
-    ends = eng.run_many(
-        [lambda e: recs.append(e.add_bcast(["h0", "h1", "h2"], 64 << 10)),
-         lambda e: recs.append(e.add_unicast("h0", "h3", 64 << 10))])
-    assert len(ends) == 2
-    assert recs[0].jct(2) != float("inf")
-    assert recs[1].jct(1) != float("inf")
-    assert recs[1].t_submit == 0.0          # clock reset between scenarios
+    recss = eng.run_workloads(
+        [Workload("a", [GroupOp("bcast", ("h0", "h1", "h2"), 64 << 10)]),
+         Workload("b", [GroupOp("unicast", ("h0", "h3"), 64 << 10)])])
+    (r_bcast,), (r_uni,) = recss
+    assert r_bcast.jct(2) != float("inf")
+    assert r_uni.jct(1) != float("inf")
+    assert r_bcast.t_submit == 0.0
+    assert r_uni.t_submit == 0.0            # clock reset between scenarios
 
 
 # ===================================================== volume integrity

@@ -291,27 +291,21 @@ def test_dynamic_events_run_on_multihop_topology():
     assert "h3" in rec.t_deliver and "h2" not in rec.t_deliver
 
 
-def test_dynamic_run_many_serial_equals_parallel():
-    """run_many's quiesce/fork machinery survives dynamic scenarios:
-    serial and workers=2 results are bit-identical."""
+def test_dynamic_run_workloads_serial_equals_parallel():
+    """run_workloads's quiesce/fork machinery survives dynamic
+    scenarios: serial and workers=2 results are bit-identical."""
     evsets = [(), (MemberEvent("leave", "h5", 10e-6),),
               (MemberEvent("fail", "h4", 15e-6),),
               (MemberEvent("join", "h7", 12e-6),)]
     members = [f"h{i}" for i in range(6)]
     out = {}
     for workers in (None, 2):
-        recs = []
         eng = make_engine("packet", fattree.testbed(n_hosts=9),
                           loss_rate=1e-4, seed=7)
-
-        def scen(evs):
-            def fn(e):
-                recs.append(e.stage(GroupOp("bcast", members, 1 << 19,
-                                            events=evs)))
-            return fn
-
-        eng.run_many([scen(e) for e in evsets], timeout=60.0,
-                     workers=workers)
+        wls = [Workload("dyn", [GroupOp("bcast", members, 1 << 19,
+                                        events=evs)]) for evs in evsets]
+        recs = [r for (r,) in eng.run_workloads(wls, timeout=60.0,
+                                                workers=workers)]
         out[workers] = [(r.msg_id, r.t_submit, r.t_sender_cqe,
                          sorted(r.t_deliver.items())) for r in recs]
     assert out[None] == out[2]

@@ -1,5 +1,5 @@
 """Packet-engine hot-path overhaul tests: typed event loop determinism,
-serial vs parallel run_many equivalence, the typed event-budget error,
+serial vs parallel run_workloads equivalence, the typed event-budget error,
 the ready-QP set invariant, and the packet free-list pool."""
 from __future__ import annotations
 
@@ -9,7 +9,7 @@ from repro.core import fattree
 from repro.core import packet as pk
 from repro.core.engine import make_engine
 from repro.core.packetsim import EventBudgetExceeded, PacketSim
-from repro.core.workload import GroupOp
+from repro.core.workload import GroupOp, Workload
 
 MEMBERS16 = [f"h{i}" for i in range(16)]
 
@@ -20,18 +20,16 @@ def _lossy_engine(n_hosts=16, loss=1e-3, seed=7):
                        group_kw={"window": 512})
 
 
-def _stage_bcast(recs, members=MEMBERS16, nbytes=1 << 19):
-    def scenario(eng):
-        recs.append(eng.stage(GroupOp("bcast", members, nbytes,
-                                      transport="gleam", chunks=8)))
-    return scenario
+def _bcast_workload(members=MEMBERS16, nbytes=1 << 19):
+    return Workload("bcast", [GroupOp("bcast", members, nbytes,
+                                      transport="gleam", chunks=8)])
 
 
 def _run_batch(workers, n_scenarios=4, seed=7):
     eng = _lossy_engine(seed=seed)
-    recs = []
-    eng.run_many([_stage_bcast(recs)] * n_scenarios, timeout=60.0,
-                 workers=workers)
+    recs = [r for recs in eng.run_workloads(
+        [_bcast_workload()] * n_scenarios, timeout=60.0, workers=workers)
+        for r in recs]
     jcts = [r.jct(len(MEMBERS16) - 1) for r in recs]
     delivers = [dict(r.t_deliver) for r in recs]
     return jcts, delivers, eng.last_run_stats
@@ -56,9 +54,9 @@ def test_typed_event_loop_deterministic_across_runs():
     assert results[0] == results[1]
 
 
-def test_run_many_serial_matches_parallel_bit_for_bit():
+def test_run_workloads_serial_matches_parallel_bit_for_bit():
     """Satellite: same seed -> identical per-record JCTs, per-receiver
-    delivery times, and drop counters between the serial run_many and
+    delivery times, and drop counters between the serial run_workloads and
     the fork-parallel one (lossy fabric, so the RNG stream matters)."""
     js, ds, ss = _run_batch(workers=None)
     jp, dp, sp = _run_batch(workers=2)
@@ -68,7 +66,7 @@ def test_run_many_serial_matches_parallel_bit_for_bit():
     assert len(js) == 4 and all(j != float("inf") for j in js)
 
 
-def test_run_many_scenarios_reseed_independently():
+def test_run_workloads_scenarios_reseed_independently():
     """Scenario i's RNG stream depends on (engine seed, i) only, so the
     same batch run twice on fresh engines is identical end to end."""
     a = _run_batch(workers=None, n_scenarios=3)
@@ -76,25 +74,30 @@ def test_run_many_scenarios_reseed_independently():
     assert a == b
 
 
-def test_run_many_parallel_worker_failure_surfaces():
+def test_run_workloads_parallel_worker_failure_surfaces():
     """A thunk that raises while a WORKER drives its scenario degrades
     gracefully: the parent warns which scenario failed, re-runs it
     serially (same per-index reseed), and the deterministic error then
     reproduces with its REAL type and traceback — it must not vanish
     into a dead child process or an opaque EOFError."""
     eng = _lossy_engine()
+    stage = eng.stage
 
     def boom():
         raise ValueError("deferred submission explodes in the worker")
 
-    def bad(e):
-        e._staged.append(boom)       # staged thunks run at drive time
+    def stage_bad(op):
+        rec = stage(op)
+        if op.key == 1:
+            eng._staged.append(boom)    # staged thunks run at drive time
+        return rec
 
-    recs = []
-    scenarios = [_stage_bcast(recs), bad]
+    eng.stage = stage_bad
+    bad = Workload("bad", [GroupOp("bcast", MEMBERS16, 1 << 19, key=1)])
     with pytest.warns(RuntimeWarning, match=r"re-running scenarios \[1\]"):
         with pytest.raises(ValueError, match="deferred submission"):
-            eng.run_many(scenarios, timeout=30.0, workers=2)
+            eng.run_workloads([_bcast_workload(), bad], timeout=30.0,
+                              workers=2)
     assert any("deferred submission" in e for e in eng.last_run_errors)
 
 
@@ -179,15 +182,13 @@ def test_sim_run_feeds_the_pool():
 # ------------------------------------------------------- fixed-seed runs
 
 def test_single_run_unaffected_by_prior_scenarios():
-    """A scenario driven through run_many equals the same workload on a
-    fresh engine driven through run_many — PSN offsets and table state
-    from earlier scenarios must not leak into timing."""
-    recs_a = []
-    eng = _lossy_engine(seed=3)
-    eng.run_many([_stage_bcast(recs_a)] * 3, timeout=60.0)
-    recs_b = []
-    eng2 = _lossy_engine(seed=3)
-    eng2.run_many([_stage_bcast(recs_b)] * 2, timeout=60.0)
+    """A scenario driven through run_workloads equals the same workload
+    on a fresh engine driven through run_workloads — PSN offsets and
+    table state from earlier scenarios must not leak into timing."""
+    recs_a = [r for (r,) in _lossy_engine(seed=3).run_workloads(
+        [_bcast_workload()] * 3, timeout=60.0)]
+    recs_b = [r for (r,) in _lossy_engine(seed=3).run_workloads(
+        [_bcast_workload()] * 2, timeout=60.0)]
     # scenario i is the same experiment no matter the batch size
     assert recs_a[0].jct(15) == recs_b[0].jct(15)
     assert recs_a[1].jct(15) == recs_b[1].jct(15)

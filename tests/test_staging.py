@@ -268,6 +268,37 @@ def test_flow_bit_identity_cache_on_vs_off(backend):
     assert on2.staging_stats()["hit_rate"] > 0.5
 
 
+def flat_ops(hosts):
+    """One op of each single-flow lowering: gleam bcast, write, unicast."""
+    return [GroupOp("bcast", tuple(hosts[:5]), 1 << 20, key=1),
+            GroupOp("write", tuple(hosts[4:8]), 256 << 10),
+            GroupOp("unicast", (hosts[1], hosts[6]), 512 << 10, key=3)]
+
+
+@pytest.mark.parametrize("backend", ["flow", "flow-np"])
+def test_reused_and_fresh_ops_stage_alike(backend):
+    """A sweep that re-passes the very same GroupOp objects and one that
+    passes equal new ones stage alike: bit-identical records, and from
+    the second pass on every op is one hit of the op-level layout
+    cache, whichever objects carry it."""
+    def passes(reuse):
+        topo = small_fat_tree()
+        ops = flat_ops(topo.hosts)
+        rows, hits = [], []
+        for _ in range(3):
+            eng = make_engine(backend, topo)
+            wl = Workload("flat", ops if reuse else flat_ops(topo.hosts))
+            before = eng.staging_stats()["hits"]
+            rows.append(record_tuples(eng.run_workloads([wl])))
+            hits.append(eng.staging_stats()["hits"] - before)
+        return rows, hits
+
+    rows_same, hits_same = passes(reuse=True)
+    rows_new, hits_new = passes(reuse=False)
+    assert rows_same == rows_new
+    assert hits_same[1:] == hits_new[1:] == [3, 3]
+
+
 @pytest.mark.parametrize("backend", ["flow", "flow-np"])
 def test_flow_bit_identity_with_fault_invalidation_mid_sweep(backend):
     """A sweep mixing static ops, a fault op, and a persistent topology

@@ -20,7 +20,7 @@ import sys
 
 from repro.core import fattree
 from repro.core.engine import make_engine
-from repro.core.workload import GroupOp, MemberEvent
+from repro.core.workload import GroupOp, MemberEvent, Workload
 
 MEMBERS = [f"h{i}" for i in range(8)]
 NBYTES = 1 << 20
@@ -41,17 +41,11 @@ SCENARIOS = [
 def run_engine(engine: str, workers):
     eng = make_engine(engine, fattree.testbed(n_hosts=10), **(
         {"loss_rate": 1e-5, "seed": 11} if engine == "packet" else {}))
-    recs = []
-
-    def scenario(op):
-        def fn(e):
-            recs.append(e.stage(op))
-        return fn
-
     ops = [GroupOp("bcast", MEMBERS, NBYTES, events=ev)
            for _, ev in SCENARIOS]
     kw = {"workers": workers} if engine == "packet" else {}
-    eng.run_many([scenario(op) for op in ops], timeout=60.0, **kw)
+    recs = [op_recs[0] for op_recs in eng.run_workloads(
+        [Workload(ops=[op]) for op in ops], timeout=60.0, **kw)]
     return [(r.msg_id, r.t_submit, r.t_sender_cqe,
              sorted(r.t_deliver.items())) for r in recs], \
            [r.jct(len(op.surviving_receivers()))

@@ -13,7 +13,7 @@ differential trips the gate.
 
 Unlike ``check_fig09.py`` (flow vs frozen flow), verify and update run
 DIFFERENT engines: ``--update`` re-measures the packet ground truth
-(multi-seed ``run_many`` batches — minutes), while the verify path only
+(multi-seed ``run_workloads`` batches — minutes), while the verify path only
 runs the deterministic fluid model (seconds) — cheap enough for CI.
 The zero-loss points double as a bit-exactness tripwire: with loss off
 the flow engine must reproduce its pre-loss-model results, so they are

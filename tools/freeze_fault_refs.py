@@ -45,14 +45,8 @@ def record_rows(engine_name):
     kw = {"seed": SEED} if engine_name == "packet" else {}
     eng = make_engine(engine_name, topo, **kw)
     ops = [op for _, op in scenarios()]
-    recs = []
-
-    def scenario(op):
-        def fn(e):
-            recs.append(e.stage(op))
-        return fn
-
-    eng.run_many([scenario(op) for op in ops], timeout=60.0)
+    recs = [op_recs[0] for op_recs in eng.run_workloads(
+        [wl.Workload(ops=[op]) for op in ops], timeout=60.0)]
     rows = {}
     for (name, op), r in zip(scenarios(), recs):
         rows[name] = {
