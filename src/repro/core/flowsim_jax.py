@@ -22,7 +22,13 @@ Flows are stored as an (F, H) matrix of link ids padded with a sentinel
 link of infinite capacity (H = longest link list in the batch), NOT a
 dense (F, L) incidence: a 16k-host fat-tree has ~50k directed links and
 fig14's unicast baseline meshes stage ~32k flows, so the dense form
-would need gigabytes while the padded form stays at a few MB.
+would need gigabytes while the padded form stays at a few MB.  The
+dense form is used in one place: a float64 batch is renumbered to the
+links its flows cross (``JaxFlowSim._compact``), and on a TPU a call
+of at most ``maxmin.DENSE_MAX_ENTRIES`` lanes x flows x links builds
+that compacted incidence once and runs its filling rounds over it
+(``kernels/maxmin.py``), since the TPU gathers and scatters one entry
+at a time.  ``SOLVE_STATS["dense_lanes"]`` counts those lanes.
 
 **Shape bucketing**: F and H are padded up to power-of-two buckets
 (``_bucket``) before the jit boundary, so nearby problem sizes share
@@ -66,8 +72,9 @@ from jax import lax
 
 from repro.core.fattree import Topology
 from repro.core.flowsim import DCQCN_MIN_RATE, DCQCN_RATE_NUM, Flow, LinkMap
-from repro.kernels.maxmin import (link_counts, loss_factors, maxmin_fill,
-                                  maxmin_rates)
+from repro.kernels.maxmin import (dense_fill, dense_fill_fits, link_counts,
+                                  loss_factors, maxmin_fill, maxmin_rates,
+                                  with_fill_form)
 
 #: volumes above this lose integer precision in float32 (2^24 bytes)
 F32_SAFE_MAX = float(1 << 24)
@@ -108,12 +115,16 @@ MAX_PAD_WASTE = 4.0
 #: - ``epochs_run``: per call, the most epochs of any of its lanes —
 #:   the device loop's serial depth, summed over calls;
 #: - ``rounds_run``: per call, the filling rounds the device executed
-#:   (``lane_rounds_run`` without the lanes factor), summed over calls.
+#:   (``lane_rounds_run`` without the lanes factor), summed over calls;
+#: - ``dense_lanes``: lanes whose fills took the dense round
+#:   (``kernels/maxmin.py``: a float64 fill on a TPU, under
+#:   ``dense_fill_fits``).
 #:
 #: The counters from ``lanes`` on cover the epoch solver only.
 SOLVE_STATS = {"solve_s": 0.0, "calls": 0, "shapes": set(), "lanes": 0,
                "epochs": 0, "rounds": 0, "lane_rounds_run": 0,
-               "x64_lanes": 0, "epochs_run": 0, "rounds_run": 0}
+               "x64_lanes": 0, "epochs_run": 0, "rounds_run": 0,
+               "dense_lanes": 0}
 _STATS_LOCK = threading.Lock()
 
 #: dynamic-segment solves mirror the numpy ``flowsim.static_maxmin``
@@ -127,7 +138,8 @@ SEG_ROUNDS = 64
 def reset_solve_stats():
     SOLVE_STATS.update(solve_s=0.0, calls=0, shapes=set(), lanes=0,
                        epochs=0, rounds=0, lane_rounds_run=0,
-                       x64_lanes=0, epochs_run=0, rounds_run=0)
+                       x64_lanes=0, epochs_run=0, rounds_run=0,
+                       dense_lanes=0)
 
 
 #: the persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
@@ -200,11 +212,30 @@ def _simulate(flow_links, cap, vol, loss=None, warm=True,
     are never applied twice); only ``dt`` and the drained bytes use
     the effective rates.  ``loss=None`` traces the exact lossless
     graph — zero-loss results are bit-identical.
+
+    The fills take the indexed or the dense round as
+    ``maxmin.with_fill_form`` picks for the lowering platform, the solve
+    dtype and the shape; the results are the same bit for bit.
     """
+    lanes = 1 if axis_name is None else lax.axis_size(axis_name)
+    return with_fill_form(
+        flow_links, cap.shape[0], cap.dtype,
+        lambda inc: _epochs(flow_links, cap, vol, loss, warm, axis_name,
+                            inc), lanes)
+
+
+def _epochs(flow_links, cap, vol, loss, warm, axis_name, incidence):
+    """``_simulate``'s event loop; its fills take the dense round over
+    ``incidence`` where one is given, else the indexed round."""
     n_flows = flow_links.shape[0]
     n_caps = cap.shape[0]
     tol, slack, pad = EPOCH_TOL[jnp.dtype(cap.dtype).name]
     eps = vol * slack + pad                 # completion slack (bytes)
+
+    def fill(active):
+        if incidence is None:
+            return maxmin_fill(flow_links, cap, active, tol=tol)
+        return dense_fill(incidence, cap, active, tol=tol)
 
     def cond(st):
         _, rem, _, _, _, it, _, _ = st
@@ -215,12 +246,10 @@ def _simulate(flow_links, cap, vol, loss=None, warm=True,
         with jax.named_scope("epoch"):
             active = rem > 0.0
             if warm:
-                rates, n = lax.cond(
-                    dirty,
-                    lambda r: maxmin_fill(flow_links, cap, active, tol=tol),
-                    lambda r: (r, jnp.int32(0)), rates)
+                rates, n = lax.cond(dirty, lambda r: fill(active),
+                                    lambda r: (r, jnp.int32(0)), rates)
             else:
-                rates, n = maxmin_fill(flow_links, cap, active, tol=tol)
+                rates, n = fill(active)
             eff = rates
             if loss is not None:
                 eff = rates * loss_factors(
@@ -321,6 +350,9 @@ class JaxFlowSim(LinkMap):
 
     F_BUCKET_MIN = 16
     H_BUCKET_MIN = 8
+    #: the least link bucket of a compacted float64 batch: one TPU
+    #: vector's width of incidence columns
+    L_BUCKET_MIN = 128
 
     #: host spans land in a running profiler's trace, on the device
     #: trace's clock (about a microsecond each when none runs)
@@ -394,6 +426,27 @@ class JaxFlowSim(LinkMap):
     def _cap_ext(self, dtype):
         return np.append(self.cap, np.inf).astype(dtype)
 
+    def _compact(self, fl, cap):
+        """``fl`` renumbered to the links its flows cross, and their
+        capacities: (ids, cap) in place of (fl, cap).
+
+        The crossed links keep their order as ids 0..n-1; the ids are
+        padded to ``_bucket(n, L_BUCKET_MIN)`` links of infinite
+        capacity, and the sentinel stays the last index.  The solve's
+        arithmetic is unchanged (every link is used elementwise or by
+        id), but a float64 fill's dense round then spans the batch's
+        own links, not the fabric's (``kernels/maxmin.py``).
+        """
+        sentinel = len(cap) - 1
+        used, ids = np.unique(fl, return_inverse=True)
+        n = len(used) - int(used[-1] == sentinel)
+        n_pad = _bucket(n, self.L_BUCKET_MIN)
+        ids = ids.reshape(fl.shape).astype(np.int32)
+        ids[fl == sentinel] = n_pad
+        ccap = np.full(n_pad + 1, np.inf, cap.dtype)
+        ccap[:n] = cap[used[:n]]
+        return ids, ccap
+
     def _dispatch(self, batched: bool, fl, cap, vol, dtype,
                   loss=None) -> np.ndarray:
         """Run the jitted solver (under x64 when promoted), timed; the
@@ -418,6 +471,8 @@ class JaxFlowSim(LinkMap):
             dt = time.perf_counter() - t0
         epochs, rounds, ran = counts.reshape(-1, COUNTS).T
         lanes = len(epochs)
+        dense = jax.default_backend() == "tpu" and dense_fill_fits(
+            int(np.prod(fl.shape[:-1])), len(cap), dtype)
         with _STATS_LOCK:
             SOLVE_STATS["solve_s"] += dt
             SOLVE_STATS["calls"] += 1
@@ -430,6 +485,8 @@ class JaxFlowSim(LinkMap):
                 SOLVE_STATS["x64_lanes"] += lanes
             SOLVE_STATS["epochs_run"] += int(epochs.max())
             SOLVE_STATS["rounds_run"] += int(ran.max())
+            if dense:
+                SOLVE_STATS["dense_lanes"] += lanes
         return done
 
     def _finish(self, flows: Sequence[Flow], done: np.ndarray) -> float:
@@ -534,6 +591,9 @@ class JaxFlowSim(LinkMap):
                           for i in batch]
                 fl = np.stack([p[0] for p in packed])
                 vol = np.stack([p[1] for p in packed])
+                bcap = cap
+                if dtype == np.float64:
+                    fl, bcap = self._compact(fl, cap)
                 loss = None
                 if any(f.loss is not None
                        for i in batch for f in epochs[i]):
@@ -541,7 +601,7 @@ class JaxFlowSim(LinkMap):
                             for i in batch]
                     loss = tuple(np.stack([r[k] for r in rows])
                                  for k in range(4))
-            return self._dispatch(True, fl, cap, vol, dtype, loss)
+            return self._dispatch(True, fl, bcap, vol, dtype, loss)
 
         # batches solve sequentially: concurrent XLA compiles thrash on
         # small hosts (XLA's own compile parallelism saturates the

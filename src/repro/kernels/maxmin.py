@@ -22,16 +22,38 @@ times a float32 one per entry; the counts are exact either way, and
 ``b * k`` rounds once where a sum of ``k`` copies of ``b`` rounds
 ``k - 1`` times.
 
-This jnp formulation is the solver's path on every platform: XLA
-lowers its scatter-adds and gathers for the CPU and for the TPU alike
-(``tests/test_tpu_compile.py`` compiles it for a v5e chip).  The
-numpy ``flowsim.FlowSim`` filling is its reference.
+A count-carrying fill takes one of two forms of its round.  The
+indexed round gathers each flow's link shares and scatter-adds ``k``
+over the (F, H) matrix.  The TPU runs those one entry at a time (about
+10 ns each), so there a call small enough (``dense_fill_fits``: at
+most ``DENSE_MAX_ENTRIES`` lanes x flows x links) runs the dense round
+instead, over each lane's (F, L) int32 incidence (``link_incidence``,
+built once per solver call): ``tightest`` is a row minimum of
+``share`` where the incidence is non-zero and ``k`` a column sum, on
+the vector units.  Both take the same minimum over the same float64
+shares and the same integer counts, so their rates agree bit for bit.
+``with_fill_form`` makes the choice with ``lax.platform_dependent``,
+as the program is lowered: the CPU keeps the indexed round, where a
+gather is cheap.
+
+This jnp formulation is the solver's path on every platform
+(``tests/test_tpu_compile.py`` compiles it for a v5e chip).  The numpy
+``flowsim.FlowSim`` filling is its reference.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+#: the most incidence entries (lanes x flows x links, int32) of a
+#: solver call whose float64 fills take the dense round on a TPU: on a
+#: v5e every shape measured up to it ran the dense round at least 3x
+#: faster than the indexed one, the first tie lies 4x above it, and its
+#: incidence stays within 32 MiB (PERF.md)
+DENSE_MAX_ENTRIES = 1 << 23
 
 
 def maxmin_round(flow_links, frozen, rates, cap_rem, cnt=None, *,
@@ -60,16 +82,10 @@ def maxmin_round(flow_links, frozen, rates, cap_rem, cnt=None, *,
             share = jnp.where(demand > 0.0,
                               cap_rem / jnp.maximum(demand, 1.0), jnp.inf)
         else:
-            share = jnp.where(cnt > 0,
-                              cap_rem / jnp.maximum(cnt, 1).astype(dtype),
-                              jnp.inf)
+            share = _count_share(cap_rem, cnt)
         # each flow's tightest link share (sentinel gathers inf)
         tightest = jnp.min(share[flow_links], axis=1)
-        limit = jnp.where(frozen > 0.5, jnp.inf, tightest)
-        b = jnp.min(limit)
-        newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
-        newf = newly.astype(dtype)
-        rates = jnp.where(newly, b, rates)
+        b, newly, newf, rates = _freeze(tightest, frozen, rates, tol)
         if cnt is None:
             used = jnp.zeros(n_caps, dtype).at[flow_links].add(
                 jnp.broadcast_to((newf * b)[:, None], flow_links.shape))
@@ -79,6 +95,24 @@ def maxmin_round(flow_links, frozen, rates, cap_rem, cnt=None, *,
             cnt = cnt - k
         cap_rem = jnp.maximum(cap_rem - used, 0.0)
         return rates, jnp.minimum(frozen + newf, 1.0), cap_rem, cnt
+
+
+def _count_share(cap_rem, cnt):
+    """Each link's fair share of its remaining capacity among its
+    ``cnt`` live flows (int32); inf on a link with none."""
+    return jnp.where(cnt > 0,
+                     cap_rem / jnp.maximum(cnt, 1).astype(cap_rem.dtype),
+                     jnp.inf)
+
+
+def _freeze(tightest, frozen, rates, tol):
+    """A round's bottleneck ``b`` (the least tightest share of an
+    unfrozen flow), the flows within ``tol`` of it that freeze now, as
+    a mask and in cap dtype, and the rates with theirs set to ``b``."""
+    limit = jnp.where(frozen > 0.5, jnp.inf, tightest)
+    b = jnp.min(limit)
+    newly = (frozen < 0.5) & (limit <= b * (1.0 + tol))
+    return b, newly, newly.astype(frozen.dtype), jnp.where(newly, b, rates)
 
 
 def link_counts(flow_links, mask, n_caps: int):
@@ -116,13 +150,36 @@ def maxmin_fill(flow_links, cap, active, *, tol: float = 1e-6,
 
     A float64 fill counts each link's live flows once, as int32, and
     its rounds carry that count (see the module docstring); a float32
-    fill's rounds recount it.
+    fill's rounds recount it.  This is the indexed form; ``dense_fill``
+    is the dense one.
     """
-    n_flows = flow_links.shape[0]
+    cnt = link_counts(flow_links, active, cap.shape[0]) \
+        if cap.dtype == jnp.float64 else None
+    return _fill(functools.partial(maxmin_round, flow_links, tol=tol),
+                 cap, active, cnt, max_rounds)
+
+
+def dense_fill(incidence, cap, active, *, tol: float):
+    """``maxmin_fill``'s count-carrying (float64) fill over the lane's
+    dense link incidence, with no gather or scatter.
+
+    incidence (F, L) int32 from ``link_incidence`` over a ``cap`` of
+    (L+1,) (its sentinel column left out); cap and active as
+    ``maxmin_fill`` takes them.  Returns what ``maxmin_fill`` returns,
+    bit for bit: the same rates and the same round count.
+    """
+    cnt = jnp.sum(jnp.where(active[:, None], incidence, 0), axis=0)
+    return _fill(functools.partial(dense_round, incidence, tol=tol),
+                 cap[:-1], active, cnt, None)
+
+
+def _fill(round_fn, cap, active, cnt, max_rounds):
+    """The progressive-filling loop: ``round_fn`` (a round with its
+    links bound) until every flow is frozen or the round cap is hit;
+    (rates, rounds) as ``maxmin_fill`` returns them."""
+    n_flows = active.shape[0]
     dtype = cap.dtype
     bound = n_flows if max_rounds is None else max_rounds - 1
-    cnt = link_counts(flow_links, active, cap.shape[0]) \
-        if dtype == jnp.float64 else None
 
     def cond(st):
         _, frozen, _, _, it = st
@@ -130,14 +187,59 @@ def maxmin_fill(flow_links, cap, active, *, tol: float = 1e-6,
 
     def body(st):
         rates, frozen, cap_rem, cnt, it = st
-        rates, frozen, cap_rem, cnt = maxmin_round(
-            flow_links, frozen, rates, cap_rem, cnt, tol=tol)
+        rates, frozen, cap_rem, cnt = round_fn(frozen, rates, cap_rem, cnt)
         return rates, frozen, cap_rem, cnt, it + 1
 
     init = (jnp.zeros(n_flows, dtype), 1.0 - active.astype(dtype),
             cap, cnt, jnp.int32(0))
     rates, _, _, _, rounds = lax.while_loop(cond, body, init)
     return jnp.maximum(rates, 1e-9), rounds
+
+
+def dense_round(incidence, frozen, rates, cap_rem, cnt, *, tol: float):
+    """``maxmin_round`` with carried counts over the (F, L) incidence:
+    each flow's tightest share is a row minimum of ``share`` where the
+    incidence is non-zero, and the newly frozen flows' per-link count
+    ``k`` a column sum.  The same float64 shares and the same int32
+    counts as the indexed round, so the same rates, bit for bit."""
+    with jax.named_scope("maxmin_round"):
+        share = _count_share(cap_rem, cnt)
+        tightest = jnp.min(jnp.where(incidence > 0, share, jnp.inf),
+                           axis=1)
+        b, newly, newf, rates = _freeze(tightest, frozen, rates, tol)
+        k = jnp.sum(jnp.where(newly[:, None], incidence, 0), axis=0)
+        cap_rem = jnp.maximum(cap_rem - b * k.astype(cap_rem.dtype), 0.0)
+        return rates, jnp.minimum(frozen + newf, 1.0), cap_rem, cnt - k
+
+
+def link_incidence(flow_links, n_caps: int):
+    """(F, n_caps - 1) int32: how often each flow crosses each link, a
+    link twice in a row counting twice as in ``link_counts``.  The
+    sentinel (index n_caps - 1) has no column: its capacity is
+    infinite, so its share never bounds a flow."""
+    return jax.nn.one_hot(flow_links, n_caps - 1, dtype=jnp.int32).sum(1)
+
+
+def dense_fill_fits(n_flows: int, n_caps: int, dtype) -> bool:
+    """Whether a solver call of ``n_flows`` flows over all its lanes and
+    ``n_caps`` capacities (sentinel included) in ``dtype`` takes the
+    dense round on a TPU: a count-carrying float64 fill whose
+    incidences hold at most ``DENSE_MAX_ENTRIES`` entries."""
+    return (jnp.dtype(dtype) == jnp.float64
+            and n_flows * (n_caps - 1) <= DENSE_MAX_ENTRIES)
+
+
+def with_fill_form(flow_links, n_caps: int, dtype, run, lanes: int = 1):
+    """``run(incidence)``, the lane's ``link_incidence`` built once,
+    where the fill takes the dense round: lowered for a TPU, under
+    ``dense_fill_fits`` for ``lanes`` such lanes.  Elsewhere
+    ``run(None)``, the indexed round; the choice is made as the program
+    is lowered."""
+    if not dense_fill_fits(lanes * flow_links.shape[0], n_caps, dtype):
+        return run(None)
+    return lax.platform_dependent(
+        tpu=lambda: run(link_incidence(flow_links, n_caps)),
+        default=lambda: run(None))
 
 
 def loss_factors(flow_links, rates, active, cap, q, wsq, wnd, ecn, *,

@@ -11,7 +11,10 @@
 - ``run_workloads`` batched scenarios == serial runs on fresh engines;
 - solvers never clobber the staged ``Flow.volume``;
 - the float64 filling's int32 link counts against a copy of the
-  float-sum round, and no float64 scatter in the float64 solver.
+  float-sum round, and no float64 scatter in the float64 solver;
+- the dense round over the incidence against the indexed count round,
+  and the solver on a batch renumbered to its links against the
+  fabric's ids: bit for bit.
 """
 from __future__ import annotations
 
@@ -440,3 +443,91 @@ def test_float64_epoch_solver_scatters_no_float64(batched):
             assert all(r.endswith("xi32>") for r in results), results
         else:
             assert any(r.endswith("xf32>") for r in results), results
+
+
+# ================================== dense round and compacted link ids
+
+def _indexed_and_dense(fl, cap, active, batched):
+    """(indexed, dense) float64 fills of these arrays, jitted, vmapped
+    over a leading lane axis when ``batched``."""
+    def dense(fl, cap, active):
+        inc = maxmin.link_incidence(fl, cap.shape[0])
+        return maxmin.dense_fill(inc, cap, active, tol=1e-12)
+
+    indexed = functools.partial(maxmin.maxmin_fill, tol=1e-12)
+    if batched:
+        indexed = jax.vmap(indexed, in_axes=(0, None, 0))
+        dense = jax.vmap(dense, in_axes=(0, None, 0))
+    args = (jnp.asarray(fl), jnp.asarray(cap), jnp.asarray(active))
+    return jax.jit(indexed)(*args), jax.jit(dense)(*args)
+
+
+@pytest.mark.parametrize("repeat", [False, True])
+@pytest.mark.parametrize("seed", range(3))
+def test_dense_fill_is_the_indexed_count_fill(seed, repeat):
+    """The dense round gives the indexed round's rates and round count
+    bit for bit, with inactive rows, all-sentinel padding rows and,
+    where ``repeat``, links crossed twice within a row."""
+    fl, cap, active = _fill_problem(30 + seed)
+    if repeat:
+        rows = np.flatnonzero(fl[:, 1] < len(cap) - 1)[::3]
+        fl[rows, 1] = fl[rows, 0]
+    assert np.all(fl[-16:] == len(cap) - 1) and not np.all(active)
+    with jax.enable_x64(True):
+        (want, n_want), (got, n) = _indexed_and_dense(fl, cap, active,
+                                                      False)
+        assert got.dtype == jnp.float64
+        assert int(n) == int(n_want) >= 10
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_dense_fill_is_the_indexed_count_fill_under_vmap():
+    """Two lanes whose round counts differ: each matches its indexed
+    fill bit for bit."""
+    fl0, cap, act0 = _fill_problem(40)
+    fl1, _, act1 = _fill_problem(41)
+    act1[32:] = False                       # a lane of few rounds
+    fl, active = np.stack([fl0, fl1]), np.stack([act0, act1])
+    with jax.enable_x64(True):
+        (want, n_want), (got, n) = _indexed_and_dense(fl, cap, active,
+                                                      True)
+        n, n_want = np.asarray(n), np.asarray(n_want)
+        assert n.tolist() == n_want.tolist() and n[0] != n[1]
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("lossy", [False, True])
+def test_simulate_on_compacted_links_is_bit_identical(lossy):
+    """Two float64 lanes over 300 of a fabric's 2,000 links, renumbered
+    to the links they cross (``JaxFlowSim._compact``): the same
+    completion times and loop counts as on the fabric's ids."""
+    rng = np.random.default_rng(7)
+    n_links = 2000
+    lanes = []
+    for seed in (50, 51):
+        fl, _, active = _fill_problem(seed)
+        fl = np.where(fl < 300, 1000 + 3 * fl, n_links).astype(np.int32)
+        vol = np.where(active, rng.uniform(2e7, 3e7, len(active)), 0.0)
+        lanes.append((fl, vol))
+    fl = np.stack([f for f, _ in lanes])
+    vol = np.stack([v for _, v in lanes])
+    cap = np.append(rng.uniform(1e9, 5e10, n_links), np.inf)
+    ids, ccap = JaxFlowSim(fattree.testbed())._compact(fl, cap)
+    assert ccap.shape == (512 + 1,) and ids.max() == 512
+    assert np.array_equal(ccap[ids[fl < n_links]], cap[fl[fl < n_links]])
+    loss = None
+    if lossy:
+        q = np.where(vol > 0, 1e-4, 0.0)
+        loss = (q, q * 4e-3, np.where(vol > 0, 64.0, 0.0), q > 0)
+    with jax.enable_x64(True):
+        def solve(links, caps):
+            args = [jnp.asarray(links), jnp.asarray(caps), jnp.asarray(vol)]
+            if loss is not None:
+                args.append(tuple(jnp.asarray(a, jnp.float64)
+                                  for a in loss))
+            return np.asarray(_solver(True, lossy)(*args))
+
+        want, got = solve(fl, cap), solve(ids, ccap)
+    assert got.dtype == np.float64
+    assert np.array_equal(got, want)
+    assert flowsim_jax._split(got)[1][:, 0].min() >= 10

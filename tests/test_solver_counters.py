@@ -6,9 +6,9 @@
   batch, and its completion times are bit for bit those of the solver
   loop without counters;
 - ``SOLVE_STATS`` adds them up, with each call's serial depth (its
-  slowest lane's epochs, the rounds it ran) and the lanes solved in
-  float64, keeps the set of distinct shapes, and
-  ``reset_solve_stats`` clears every key;
+  slowest lane's epochs, the rounds it ran), the lanes solved in
+  float64 and those solved by the dense round, keeps the set of
+  distinct shapes, and ``reset_solve_stats`` clears every key;
 - both solver flavours lower to ``jit__simulate`` (the name the
   benchmark's trace reduction looks for); the segment solver does not;
 - the packet engine's module imports no JAX.
@@ -137,10 +137,12 @@ def test_solve_stats_add_up_the_counts(sim):
     assert st["epochs_run"] == 3 + 3 + 3
     assert st["rounds_run"] == 6 + 6 + 6
     assert st["x64_lanes"] == 0
+    assert st["dense_lanes"] == 0
     flowsim_jax.reset_solve_stats()
     assert st == {"solve_s": 0.0, "calls": 0, "shapes": set(), "lanes": 0,
                   "epochs": 0, "rounds": 0, "lane_rounds_run": 0,
-                  "x64_lanes": 0, "epochs_run": 0, "rounds_run": 0}
+                  "x64_lanes": 0, "epochs_run": 0, "rounds_run": 0,
+                  "dense_lanes": 0}
 
 
 def test_x64_lanes_count_the_promoted_solves(sim):
@@ -155,6 +157,33 @@ def test_x64_lanes_count_the_promoted_solves(sim):
     st = flowsim_jax.SOLVE_STATS
     assert (st["lanes"], st["x64_lanes"]) == (5, 3)
     assert (st["epochs_run"], st["rounds_run"]) == (9, 18)
+
+
+@pytest.mark.parametrize("backend,lanes_fit,want", [
+    ("tpu", 2, 2 + 1), ("tpu", 0, 0), ("cpu", 2, 0), ("tpu", 1, 1)])
+def test_dense_lanes_count_the_lanes_of_dense_fills(sim, monkeypatch,
+                                                    backend, lanes_fit,
+                                                    want):
+    """A float64 call's lanes count as dense where the kernel takes the
+    dense round: on a TPU, with the call's lanes x F_PAD x 4 links
+    within the bound (``lanes_fit`` lanes fit it; 2 is the default
+    bound's case).  float32 calls never do."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if lanes_fit < 2:
+        monkeypatch.setattr(maxmin, "DENSE_MAX_ENTRIES",
+                            (lanes_fit + 1) * F_PAD * (len(CAP) - 1) - 1)
+    packed = [_pack(lane) for lane in LANES]
+    fl = np.stack([p[0] for p in packed])
+    vol = np.stack([p[1] for p in packed])
+    cap64 = CAP.astype(np.float64)
+    sim._dispatch(True, fl, CAP, vol, np.float32)
+    sim._dispatch(False, packed[1][0], CAP, packed[1][1], np.float32)
+    assert flowsim_jax.SOLVE_STATS["dense_lanes"] == 0
+    sim._dispatch(True, fl, cap64, vol.astype(np.float64), np.float64)
+    sim._dispatch(False, packed[1][0], cap64,
+                  packed[1][1].astype(np.float64), np.float64)
+    st = flowsim_jax.SOLVE_STATS
+    assert (st["lanes"], st["x64_lanes"], st["dense_lanes"]) == (6, 3, want)
 
 
 def test_maxmin_fill_counts_the_rounds_maxmin_rates_runs():

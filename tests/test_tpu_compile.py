@@ -1,6 +1,11 @@
 """The device solvers compile for a TPU v5e chip at the chip smoke run's
 shapes (``chip_smoke.py``), with no chip attached.
 
+The float64 epoch solver takes the dense filling round at
+``dsv3ep2k.route``'s packed shape (no gather or scatter in the loop)
+and the indexed round at the fleet's, or wherever the call's
+incidences would outgrow ``maxmin.DENSE_MAX_ENTRIES``.
+
 The TPU compiler is installed beside JAX and compiles for a described
 topology; nothing runs, so these tests say nothing about results or
 speed — only that the chip's compiler accepts each program and that
@@ -11,6 +16,7 @@ every test worker imports this file).
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.flowsim_jax import _seg_solver, _solver
+from repro.kernels import maxmin
 
 HBM_BYTES = 16e9
 
@@ -25,6 +32,9 @@ HBM_BYTES = 16e9
 FLEET = dict(batch=1, flows=2048, hops=32, caps=50177)
 # phase B (4,096-host matrix): the largest dynamic-segment bucket
 SEGMENTS = dict(lanes=80, flows=16, hops=64, caps=10369)
+# the benchmark's dsv3ep2k.route: dispatch and combine, 448 flows each,
+# over the 128 links they cross and the sentinel
+DSV3 = dict(batch=2, flows=512, hops=8, caps=129)
 
 
 @pytest.fixture(scope="module")
@@ -58,8 +68,13 @@ def _fits(compiled):
     assert total < HBM_BYTES, total
 
 
-def _epoch_args(sharding, dtype, lossy):
-    b, f, h, c = (FLEET[k] for k in ("batch", "flows", "hops", "caps"))
+def _indexed_ops(compiled):
+    """Gathers and scatters in a compiled program's HLO."""
+    return re.findall(r"\b(?:gather|scatter)\(", compiled.as_text())
+
+
+def _epoch_args(sharding, dtype, lossy, shape=FLEET):
+    b, f, h, c = (shape[k] for k in ("batch", "flows", "hops", "caps"))
     args = [_spec((b, f, h), jnp.int32, sharding),
             _spec((c,), dtype, sharding), _spec((b, f), dtype, sharding)]
     if lossy:
@@ -79,8 +94,32 @@ def test_fleet_epoch_solver_float32_lossless_compiles(one_chip):
 
 def test_epoch_solver_float64_compiles(one_chip):
     with jax.enable_x64(True):
-        _fits(_solver(True, True).lower(
-            *_epoch_args(one_chip, jnp.float64, lossy=True)).compile())
+        compiled = _solver(True, True).lower(
+            *_epoch_args(one_chip, jnp.float64, lossy=True)).compile()
+    _fits(compiled)
+    assert _indexed_ops(compiled)           # over the bound: indexed
+
+
+def test_dsv3_epoch_solver_float64_takes_the_dense_round(one_chip):
+    with jax.enable_x64(True):
+        compiled = _solver(True, False).lower(
+            *_epoch_args(one_chip, jnp.float64, lossy=False,
+                         shape=DSV3)).compile()
+    _fits(compiled)
+    assert not _indexed_ops(compiled)
+
+
+def test_epoch_solver_float64_bound_counts_the_lanes(one_chip):
+    """One lane more than ``DENSE_MAX_ENTRIES`` holds at dsv3's lane
+    shape puts the whole call on the indexed round."""
+    lanes = 1 + maxmin.DENSE_MAX_ENTRIES // (DSV3["flows"]
+                                             * (DSV3["caps"] - 1))
+    with jax.enable_x64(True):
+        compiled = _solver(True, False).lower(
+            *_epoch_args(one_chip, jnp.float64, lossy=False,
+                         shape=dict(DSV3, batch=lanes))).compile()
+    _fits(compiled)
+    assert _indexed_ops(compiled)
 
 
 def test_segment_solver_float64_compiles(one_chip):
